@@ -222,7 +222,7 @@ def _geometry_candidates(width: int, n_sub: int, quick: bool):
     geoms = [(1024, 2048), (2048, 2048), (2048, 4096)]
     modes = ["f32", "count"]
     if not quick:
-        geoms += [(512, 2048), (1024, 4096)]
+        geoms += [(1024, 4096)]
         modes.append("limb")
     seen, out = set(), []
     for blk, w_blk in geoms:
@@ -480,7 +480,7 @@ def run_fleet_ragged(quick: bool = True):
     kw = dict(n_sub_max=max(nsubs), width_max=max(widths), log2_te=16,
               signed=True)
 
-    dense_blk = 256
+    dense_blk = 1024
     dkeys, dvals, dts = pkt.densify(dense_blk)
     args_d = (jnp.asarray(dkeys), jnp.asarray(dvals), jnp.asarray(dts),
               jnp.asarray(params))
@@ -494,7 +494,7 @@ def run_fleet_ragged(quick: bool = True):
 
     rows, best = [], None
     for grouped in (False, True):
-        for blk in ((256, 512, 1024) if grouped else (256, 512)):
+        for blk in ((1024, 2048) if grouped else (1024,)):
             if grouped:
                 run_one = (lambda blk=blk: dispatch_ragged_grouped(
                     params, [pkt], blk=blk, interpret="auto",
@@ -773,6 +773,9 @@ def run_univmon_fleet(quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    print(f"compile cache: {enable_compile_cache()}")
     quick = "--quick" in sys.argv
     gate = "--no-gate" not in sys.argv
     baseline = load_baseline()

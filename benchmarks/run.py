@@ -26,6 +26,9 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma-separated subset of suites")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    print(f"compile cache: {enable_compile_cache()}")
     only = set(filter(None, args.only.split(",")))
     t0 = time.time()
     failures = []

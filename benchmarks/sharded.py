@@ -1,4 +1,4 @@
-"""Benchmark: sharded fragment fleet on a forced 8-device host mesh.
+"""Benchmark: sharded fragment fleet on a forced 8-device CPU mesh.
 
 A 200+-switch fat-tree (FatTree(14) -> 245 switches, ~10x the paper's
 testbed) replayed through ``DiSketchSystem(backend="fleet")`` twice —
@@ -8,6 +8,12 @@ count only takes effect via ``XLA_FLAGS`` *before* jax initialises, and
 the main bench process must keep its 1-device view so the committed
 gated headlines (``ragged_pkts_per_s`` etc.) are measured under the
 same runtime as their baselines.
+
+The child is pinned to the CPU (``JAX_PLATFORMS=cpu``, Pallas in
+interpret mode): on a TPU host the parent already holds the chip, and a
+child that asked for it would fail or hang.  This row is a CPU parity
+check of the mesh plumbing; the sharded fleet's run on real chips is
+``python chip_smoke.py --chips 4``.
 
 ``sharded_ok`` is a correctness gate (kernel_bench._MATCH_COLS): the
 sharded run must reproduce the single-device counters and fragment-
@@ -112,6 +118,7 @@ def run(quick: bool = True):
     if "xla_force_host_platform_device_count" not in \
             env.get("XLA_FLAGS", ""):
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
+    env["JAX_PLATFORMS"] = "cpu"
     code = _CHILD % {"src": os.path.join(_ROOT, "src"), "root": _ROOT,
                      "quick": quick}
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -309,6 +309,9 @@ class DiSketchSystem:
         re-packing.  Non-fleet backends fall back to per-epoch
         processing (exact per-epoch control).
 
+        Each replayed PEB was observed at the frozen n, so the replay
+        rescales it to the walking n (``equalize.next_n_observed``).
+
         ``events_by_epoch`` (one event sequence per window offset)
         injects churn: a mid-window "fail" at offset e masks the
         switch's epochs >= e AND marks its un-exported earlier epochs
@@ -367,8 +370,8 @@ class DiSketchSystem:
                 self._peb_width[sw] = self.fragments[sw].width
             if self.subepoching and not self.control_external:
                 for sw, peb in pebs.items():
-                    self.ns[sw] = equalize.next_n(self.ns[sw], peb,
-                                                  self.rho_target)
+                    self.ns[sw] = equalize.next_n_observed(
+                        self.ns[sw], peb, ns[sw], self.rho_target)
             self.n_log.append(dict(self.ns))
 
     # -- query plane --------------------------------------------------------

@@ -100,6 +100,19 @@ def next_n(n: int, peb: float, rho_target: float) -> int:
     return n
 
 
+def next_n_observed(n: int, peb: float, n_observed: int,
+                    rho_target: float) -> int:
+    """Eq. 6 step from a PEB observed while the fragment ran at
+    ``n_observed`` — the window-mode control, where every epoch of a
+    window ran at the frozen n while the control walks on.  The
+    observation is rescaled to the current ``n`` by the §4.2 error model
+    (a record's Eq. 4 bound scales as 1/n), so a window of E epochs
+    converges instead of doubling n E times on one stale reading;
+    ``converge_n`` iterates it.  ``n == n_observed`` is plain
+    ``next_n``."""
+    return next_n(n, peb * n_observed / n, rho_target)
+
+
 def converge_n(n: int, peb: float, rho_target: float) -> int:
     """Iterate the Eq. 6 control to its fixed point in one shot.
 
@@ -119,7 +132,7 @@ def converge_n(n: int, peb: float, rho_target: float) -> int:
         return n
     n0, peb0 = n, peb
     for _ in range(2 * N_MAX.bit_length()):
-        nn = next_n(n, peb0 * n0 / n, rho_target)
+        nn = next_n_observed(n, peb0, n0, rho_target)
         if nn == n:
             return n
         n = nn

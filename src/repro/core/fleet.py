@@ -49,11 +49,21 @@ from collections.abc import Mapping
 from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.sketch_update.kernel import MIN_BLK, abs_peak
 from . import equalize
 from .fragment import (EpochRecords, FragmentConfig, _ROLE_COL, _ROLE_SIGN,
                        _ROLE_SUB, frag_seed, level_seed_mix)
+
+#: CSR alignment of the packed packet stream, which is also the ragged
+#: kernel's packet tile: the smallest packet block the chip compiles.
+#: Interpret-mode tests may pass any multiple of 128.
+CSR_BLK = MIN_BLK
 
 
 @dataclass
@@ -99,7 +109,7 @@ class FleetPacket:
                            None if self.single_hop is None
                            else cat(self.single_hop))
 
-    def densify(self, blk: int = 256) -> Tuple[np.ndarray, np.ndarray,
+    def densify(self, blk: int = CSR_BLK) -> Tuple[np.ndarray, np.ndarray,
                                                np.ndarray]:
         """(n_frags, p_max) rectangles, value-0 padded, p_max % blk == 0.
 
@@ -231,7 +241,7 @@ def _bucket_blocks(nb: int, floor: int = 32) -> int:
     return -(-nb // q) * q
 
 
-def pack_csr(packets: Sequence[FleetPacket], blk: int = 256,
+def pack_csr(packets: Sequence[FleetPacket], blk: int = CSR_BLK,
              ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Vectorized CSR packing for the ragged fleet kernel.
 
@@ -318,10 +328,22 @@ def build_params(fragments: Dict[int, FragmentConfig], epoch: int,
     return params
 
 
+@functools.partial(jax.jit, static_argnums=2)
+def _assemble_groups(outs, rows, shape):
+    """Place every n_sub group's launch into one zero window stack of
+    ``shape`` (group ``g`` fills rows ``rows[g]``, its own subepoch and
+    column extents): one compiled program per window, where eager
+    ``.at[].set`` compiles about ten small programs per group."""
+    out = jnp.zeros(shape, jnp.float32)
+    for r, o in zip(rows, outs):
+        out = out.at[r, :o.shape[1], :o.shape[2]].set(o)
+    return out
+
+
 def dispatch_ragged_grouped(params: np.ndarray,
                             packets: Sequence[FleetPacket], *,
                             n_sub_max: int, width_max: int, log2_te: int,
-                            signed: bool, blk: int = 256,
+                            signed: bool, blk: int = CSR_BLK,
                             w_blk: Optional[int] = None,
                             interpret="auto", value_mode: str = "auto",
                             n_levels: int = 1,
@@ -343,15 +365,10 @@ def dispatch_ragged_grouped(params: np.ndarray,
     (``n_levels`` consecutive virtual level rows per fragment for
     UnivMon fleets), with the per-fragment ``n_sub``/``width`` columns
     identical across epochs and levels (``ns`` frozen — the
-    ``run_window`` contract).  Returns the stacked
-    ``(n_rows, n_sub_max, width_max)`` f32 counters — device-resident on
-    TPU (the window path computes PEBs/peaks on-device); assembled in
-    host memory on CPU, where "device" scatters would just be extra
-    copies of what is host memory anyway.
+    ``run_window`` contract).  Returns the stacked device-resident
+    ``(n_rows, n_sub_max, width_max)`` f32 counters, assembled from the
+    groups' launches by one program (``_assemble_groups``).
     """
-    import jax
-    import jax.numpy as jnp
-
     from ..kernels.sketch_update import fleet as FK
 
     e_count = len(packets)
@@ -375,8 +392,7 @@ def dispatch_ragged_grouped(params: np.ndarray,
               interpret=interpret, value_mode=value_mode, n_levels=L,
               with_mitigation=with_mitigation)
     groups = [np.flatnonzero(nsub_f == n) for n in np.unique(nsub_f)]
-    on_device = jax.default_backend() == "tpu"
-    out = None
+    outs, out_rows = [], []
     for frag_idx in groups:
         n_g = int(nsub_f[frag_idx[0]])
         w_g = int(width_f[frag_idx].max(initial=4))
@@ -392,20 +408,10 @@ def dispatch_ragged_grouped(params: np.ndarray,
             n_sub_max=n_g, width_max=w_g, **kw)
         if len(groups) == 1 and n_g == n_sub_max and w_g == width_max:
             return out_g
-        if out is None:
-            out = (jnp.zeros((n_rows, n_sub_max, width_max), jnp.float32)
-                   if on_device else
-                   np.zeros((n_rows, n_sub_max, width_max), np.float32))
-        if on_device:
-            # one eager full-stack copy per group (G <= log2(N_MAX));
-            # acceptable per window today — fold into a jitted donated
-            # scatter chain if window stacks ever dominate profile.
-            out = out.at[rows, :n_g, :w_g].set(out_g)
-        else:
-            out[rows, :n_g, :w_g] = np.asarray(out_g)
-    if out is None:
-        out = np.zeros((n_rows, n_sub_max, width_max), np.float32)
-    return out
+        outs.append(out_g)
+        out_rows.append(rows.astype(np.int32))
+    return _assemble_groups(tuple(outs), tuple(out_rows),
+                            (n_rows, n_sub_max, width_max))
 
 
 class _WindowBuffer:
@@ -436,18 +442,13 @@ class _WindowBuffer:
 
     def device(self):
         """The still-resident ``(E, F, n_sub_max, width_max)`` f32 stack
-        as a jax array (None once transferred).  On CPU the one-time
-        jnp conversion is cached — "device" memory is host memory there
-        anyway."""
+        as a jax array (None once transferred)."""
         if self._dev is None:
             return None
-        if isinstance(self._dev, np.ndarray) \
-                or tuple(self._dev.shape) != tuple(self._shape):
-            import jax.numpy as jnp
-
+        if tuple(self._dev.shape) != tuple(self._shape):
             # A mesh-sharded stack already has the right shape and must
             # NOT be reshaped (that would drop its NamedSharding).
-            self._dev = jnp.asarray(self._dev).reshape(self._shape)
+            self._dev = self._dev.reshape(self._shape)
         return self._dev
 
     def host(self) -> np.ndarray:
@@ -474,8 +475,6 @@ class _WindowBuffer:
         device array, or the already-transferred host copy *in place* so
         every existing record-plane view observes the reconstruction."""
         if self.resident:
-            import jax.numpy as jnp
-
             self._dev = self.device().at[e_idx, row_lo:row_hi].set(
                 jnp.asarray(counters, jnp.float32))
         else:
@@ -570,7 +569,7 @@ class FleetEpochRunner:
     """
 
     def __init__(self, fragments: Dict[int, FragmentConfig], log2_te: int,
-                 *, blk: int = 256, w_blk: Optional[int] = None,
+                 *, blk: int = CSR_BLK, w_blk: Optional[int] = None,
                  interpret="auto", keep_stacked: bool = False,
                  layout: str = "ragged", value_mode: str = "auto",
                  group_by_n_sub: bool = True,
@@ -849,7 +848,6 @@ class FleetEpochRunner:
         empty shards up to ``frags_per_shard``).  Built with
         ``make_array_from_single_device_arrays`` so no global host
         rectangle beyond the per-shard blocks is ever materialized."""
-        import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         L = self.n_levels
@@ -1040,8 +1038,6 @@ class FleetEpochRunner:
         loss per group per epoch stays exactly reconstructible
         (``recover``); until then the cells are masked like dead ones.
         """
-        import jax.numpy as jnp
-
         from ..kernels.sketch_update.fleet import PARAM_N_SUB
 
         e_count = len(packets)
@@ -1078,8 +1074,8 @@ class FleetEpochRunner:
             self._check_output_peak(peak)
         else:
             out = self._dispatch(params, packets, n_sub_max, width_max)
-            self._check_output_peak(
-                float(jnp.max(jnp.abs(out))) if out.size else 0.0)
+            self._check_output_peak(float(abs_peak(out)) if out.size
+                                    else 0.0)
             # §4.2 PEBs from the level-0 rows (::L is a no-op for
             # cs/cms) — computed before lost cells are zeroed (their
             # counters are genuine observations of epochs the switch did
@@ -1101,11 +1097,8 @@ class FleetEpochRunner:
                     np.arange(i * L, (i + 1) * L) + e * rows_per_epoch
                     for e, lost in enumerate(lost_sets)
                     for i in sorted(self._frag_pos[sw] for sw in lost)]
-                ).astype(np.int64)
-                if isinstance(out, np.ndarray):
-                    out[rows] = 0.0
-                else:
-                    out = out.at[rows].set(0.0)
+                ).astype(np.int32)
+                out = out.at[jnp.asarray(rows)].set(0.0)
 
             buf = _WindowBuffer(out, (e_count, rows_per_epoch, n_sub_max,
                                       width_max))
@@ -1163,15 +1156,11 @@ class FleetEpochRunner:
         equation stays consistent for any liveness pattern."""
         L = self.n_levels
         a = out.reshape(e_count, rows_per_epoch, n_sub_max, width_max)
-        host = isinstance(out, np.ndarray)
-        if not host:
-            import jax.numpy as jnp
         per_group = []
         for g in self.parity_groups:
             acc = None
             for i in g:
-                cell = a[:, i * L:(i + 1) * L]
-                cell = cell.astype(np.int32 if host else jnp.int32)
+                cell = a[:, i * L:(i + 1) * L].astype(jnp.int32)
                 acc = cell if acc is None else acc ^ cell
             per_group.append(np.asarray(acc))   # (E, L, S, W) int32
         return [[pg[e] for pg in per_group] for e in range(e_count)]

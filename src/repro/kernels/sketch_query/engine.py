@@ -58,7 +58,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ... import sanitize
@@ -424,11 +423,11 @@ def _sharded_gather_merge(mesh, kind: str, mitigate: bool, n_rows: int):
         return _masked_merge(raw[:, :n_rows], frag_sel,
                              kind=kind).sum(axis=0)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, "switch", None, None), row, row, row,
                   per_row, per_row, P(), per_row, P()),
-        out_specs=P(), check_rep=False))
+        out_specs=P(), check_vma=False))
 
 
 def _sharded_window_query(mesh, stack, params, ns, widths, sel2, mit_rows,
@@ -496,11 +495,11 @@ def _sharded_gather_merge_um(mesh, n_levels: int, n_rows: int):
         merged = _masked_merge(raw, sel, kind="um")       # (E*L, K)
         return merged.reshape(e_count, n_levels, -1).sum(axis=0)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, "switch", None, None), row, row, row,
                   per_row, per_row, P(), P()),
-        out_specs=P(), check_rep=False))
+        out_specs=P(), check_vma=False))
 
 
 def _sharded_um_query(mesh, stack, params, ns, widths, sel2, keys_pad, *,

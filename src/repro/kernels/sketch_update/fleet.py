@@ -93,9 +93,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ... import sanitize
-from .kernel import (LANE, LVL_FIELD_MASK, LVL_SHIFT, block_contrib,
-                     pow2_width_cap, resolve_interpret,
-                     resolve_value_mode, select_geometry)
+from .kernel import (LANE, LVL_FIELD_MASK, LVL_SHIFT, MIN_BLK,
+                     block_contrib, lane_tiles, pow2_width_cap,
+                     resolve_interpret, resolve_value_mode, select_geometry)
 
 # Columns of the per-fragment int32 parameter table.
 PARAM_COL_SEED = 0
@@ -109,31 +109,44 @@ PARAM_MIT = 7     # §4.4 single-hop mitigation enabled for this row
 N_PARAMS = 8
 
 
-def _frag_contrib(params, keys, vals, ts, *, wi, w_blk, n_sub_max,
+def _frag_contrib(param, keys, vals, ts, *, wi, w_blk, n_sub_max,
                   log2_te, signed, value_mode, with_levels=False,
                   with_mitigation=False):
-    """One fragment's packet-block contribution, parameters from its
-    table row.  ``with_levels``/``with_mitigation`` (static) gate the
-    extended monitored-mask terms so cs/cms fleets compile the exact
-    pre-UnivMon kernel body."""
+    """One fragment's packet-block contribution, parameters read through
+    ``param(column)`` from its row of the SMEM table.
+    ``with_levels``/``with_mitigation`` (static) gate the extended
+    monitored-mask terms so cs/cms fleets compile the exact pre-UnivMon
+    kernel body."""
     return block_contrib(
-        keys.astype(jnp.uint32), vals, ts.astype(jnp.uint32),
-        col_seed=params[PARAM_COL_SEED].astype(jnp.uint32),
-        sign_seed=params[PARAM_SIGN_SEED].astype(jnp.uint32),
-        sub_seed=params[PARAM_SUB_SEED].astype(jnp.uint32),
-        width=params[PARAM_WIDTH].astype(jnp.uint32),
-        n_mask=(params[PARAM_N_SUB] - 1).astype(jnp.uint32),
+        keys, vals, ts,
+        col_seed=param(PARAM_COL_SEED).astype(jnp.uint32),
+        sign_seed=param(PARAM_SIGN_SEED).astype(jnp.uint32),
+        sub_seed=param(PARAM_SUB_SEED).astype(jnp.uint32),
+        width=param(PARAM_WIDTH).astype(jnp.uint32),
+        n_mask=(param(PARAM_N_SUB) - 1).astype(jnp.uint32),
         shift=(jnp.uint32(log2_te)
-               - params[PARAM_LOG2_N_SUB].astype(jnp.uint32)),
+               - param(PARAM_LOG2_N_SUB).astype(jnp.uint32)),
         wi=wi, w_blk=w_blk, n_sub_rows=n_sub_max, signed=signed,
         value_mode=value_mode,
-        level=params[PARAM_LEVEL] if with_levels else 0,
-        mit=params[PARAM_MIT] if with_mitigation else 0)
+        level=param(PARAM_LEVEL) if with_levels else 0,
+        mit=param(PARAM_MIT) if with_mitigation else 0)
+
+
+def _row_params(params_ref, row):
+    """Accessor for one row of the flattened ``(n_rows * N_PARAMS,)``
+    int32 table held in SMEM (scalar-prefetched)."""
+    base = row * N_PARAMS
+    return lambda k: params_ref[base + k]
+
+
+def _has_values(vals):
+    return jnp.max(jnp.abs(vals)) > 0.0
 
 
 def fleet_update_kernel(params_ref, keys_ref, vals_ref, ts_ref, out_ref, *,
                         w_blk: int, n_sub_max: int, log2_te: int,
                         signed: bool, value_mode: str):
+    f = pl.program_id(0)    # fragment index
     wi = pl.program_id(1)   # width-block index
     pj = pl.program_id(2)   # packet-block index (sequential reduction)
 
@@ -141,20 +154,20 @@ def fleet_update_kernel(params_ref, keys_ref, vals_ref, ts_ref, out_ref, *,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    # This fragment's hash parameters, read in-kernel as traced scalars.
-    params = params_ref[...][0]                     # (N_PARAMS,) int32
-    vals = vals_ref[...][0].astype(jnp.float32)
+    # This fragment's hash parameters, read from SMEM as scalars.
+    param = _row_params(params_ref, f)
+    vals = vals_ref[0]
     # Dead-work skip: width blocks beyond this fragment's true width
     # write nothing, and all-zero value blocks (packet padding — most of
     # the dense rectangle under skew) contribute nothing.
-    live = ((wi * w_blk) < params[PARAM_WIDTH]) & jnp.any(vals != 0.0)
+    live = ((wi * w_blk) < param(PARAM_WIDTH)) & _has_values(vals)
 
     @pl.when(live)
     def _accum():
         out_ref[...] += _frag_contrib(
-            params, keys_ref[...][0], vals, ts_ref[...][0], wi=wi,
+            param, keys_ref[0], vals, ts_ref[0], wi=wi,
             w_blk=w_blk, n_sub_max=n_sub_max, log2_te=log2_te,
-            signed=signed, value_mode=value_mode)[None]
+            signed=signed, value_mode=value_mode).reshape(out_ref.shape)
 
 
 def fleet_update_pallas(keys, vals, ts, params, *, n_sub_max: int,
@@ -163,41 +176,45 @@ def fleet_update_pallas(keys, vals, ts, params, *, n_sub_max: int,
                         interpret: bool = False):
     """Lowered pallas_call over the (fragment, width, packet) grid.
 
-    ``keys``/``vals``/``ts``: (n_frags, p_max) with p_max % blk == 0;
-    ``params``: (n_frags, N_PARAMS) int32.  The packet axis is the inner
-    sequential reduction, so each (fragment, width-block) counter tile is
-    initialized once and revisited across packet blocks.
+    ``keys``/``vals``/``ts``: (n_frags, p_max / LANE, LANE) lane-major
+    rectangles with p_max % blk == 0; ``params``: (n_frags, N_PARAMS)
+    int32, scalar-prefetched into SMEM.  The packet axis is the inner
+    sequential reduction, so each (fragment, width-block) counter tile
+    is initialized once and revisited across packet blocks.  Returns
+    ``(n_frags, n_sub_max, padded_width / LANE, LANE)`` counter tiles.
     """
-    n_frags, p = keys.shape
-    assert p % blk == 0 and padded_width % w_blk == 0
+    n_frags, p_rows, _ = keys.shape
+    assert blk % LANE == 0 and (p_rows * LANE) % blk == 0
+    assert padded_width % w_blk == 0
     if isinstance(keys, jax.core.Tracer):
         # Counts jit cache misses only (the wrapper is also callable
         # eagerly, e.g. under eval_shape by the contract verifier).
         sanitize.note_trace("sketch_update.fleet_update_pallas")
-    grid = (n_frags, padded_width // w_blk, p // blk)
+    grid = (n_frags, padded_width // w_blk, p_rows * LANE // blk)
     j_rows = w_blk // LANE
     kernel = functools.partial(
         fleet_update_kernel, w_blk=w_blk, n_sub_max=n_sub_max,
         log2_te=log2_te, signed=signed, value_mode=value_mode)
+    pkt = pl.BlockSpec((1, blk // LANE, LANE),
+                       lambda f, i, j, prm: (f, j, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid,
+        in_specs=[pkt, pkt, pkt],
+        out_specs=pl.BlockSpec((1, n_sub_max, j_rows, LANE),
+                               lambda f, i, j, prm: (f, 0, i, 0)),
+    )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, N_PARAMS), lambda f, i, j: (f, 0)),
-            pl.BlockSpec((1, blk), lambda f, i, j: (f, j)),
-            pl.BlockSpec((1, blk), lambda f, i, j: (f, j)),
-            pl.BlockSpec((1, blk), lambda f, i, j: (f, j)),
-        ],
-        out_specs=pl.BlockSpec((1, n_sub_max, j_rows, LANE),
-                               lambda f, i, j: (f, 0, i, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (n_frags, n_sub_max, padded_width // LANE, LANE), jnp.float32),
         # Fragment and width axes touch disjoint counter tiles: parallel
         # (megacore); the packet axis is the sequential accumulation.
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(params, keys, vals, ts)
+    )(params.reshape(-1), keys, vals, ts)
 
 
 _fleet_update_jit = jax.jit(
@@ -232,34 +249,34 @@ def fleet_update(keys, vals, ts, params, *, n_sub_max: int, width_max: int,
         g_blk, g_w_blk = select_geometry(width_max, n_sub_max, value_mode)
         blk = g_blk if blk is None else blk
         w_blk = g_w_blk if w_blk is None else w_blk
-    n_frags, p = keys.shape
-    pad_p = (-p) % blk
-    if pad_p:
-        keys = jnp.pad(jnp.asarray(keys, jnp.uint32), ((0, 0), (0, pad_p)))
-        vals = jnp.pad(jnp.asarray(vals, jnp.float32), ((0, 0), (0, pad_p)))
-        ts = jnp.pad(jnp.asarray(ts, jnp.uint32), ((0, 0), (0, pad_p)))
+    n_frags, p = np.shape(keys)
+    pad = ((0, 0), (0, (-p) % blk))
+    keys = jnp.pad(jnp.asarray(keys, jnp.uint32), pad)
+    vals = jnp.pad(jnp.asarray(vals, jnp.float32), pad)
+    ts = jnp.pad(jnp.asarray(ts, jnp.uint32), pad)
     w_blk = min(w_blk, pow2_width_cap(width_max))
     pad_w = (-width_max) % w_blk
     out = _fleet_update_jit(
-        jnp.asarray(keys, jnp.uint32), jnp.asarray(vals, jnp.float32),
-        jnp.asarray(ts, jnp.uint32), jnp.asarray(params, jnp.int32),
+        keys.reshape(n_frags, -1, LANE), vals.reshape(n_frags, -1, LANE),
+        ts.reshape(n_frags, -1, LANE), jnp.asarray(params, jnp.int32),
         n_sub_max=n_sub_max, padded_width=width_max + pad_w,
         log2_te=log2_te, signed=signed, blk=blk, w_blk=w_blk,
         value_mode=value_mode, interpret=interpret)
     # Undo the kernel's factored (.., W/LANE, LANE) layout: free reshape.
-    return (out.reshape(out.shape[0], n_sub_max, width_max + pad_w)
+    return (out.reshape(n_frags, n_sub_max, width_max + pad_w)
             [:, :, :width_max])
 
 
 def fleet_ragged_kernel(block_frag_ref, params_ref, keys_ref, vals_ref,
                         ts_ref, out_ref, *, w_blk: int, n_sub_max: int,
                         log2_te: int, signed: bool, value_mode: str,
-                        with_levels: bool, with_mitigation: bool):
+                        n_levels: int, with_mitigation: bool):
     """Ragged CSR body: one packet block of the flat stream, applied to
     its owning row's counter tile (selected by the BlockSpec index maps
     from the scalar-prefetched ``block_frag`` map; with UnivMon level
     rows, the leading level grid axis fans the same packet block out to
     the fragment's ``n_levels`` tiles)."""
+    lvl = pl.program_id(0)  # UnivMon level row
     wi = pl.program_id(1)   # width-block index
     pj = pl.program_id(2)   # packet-block index (sequential reduction)
 
@@ -273,25 +290,26 @@ def fleet_ragged_kernel(block_frag_ref, params_ref, keys_ref, vals_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    params = params_ref[...][0]                     # (N_PARAMS,) int32
-    vals = vals_ref[...].astype(jnp.float32)
+    param = _row_params(params_ref, cur * n_levels + lvl)
+    vals = vals_ref[...]
     # Dead-work skip: width blocks beyond this fragment's true width and
     # all-zero value blocks (blk-alignment / shape-bucket padding).
-    live = ((wi * w_blk) < params[PARAM_WIDTH]) & jnp.any(vals != 0.0)
+    live = ((wi * w_blk) < param(PARAM_WIDTH)) & _has_values(vals)
+    with_levels = n_levels > 1
     if with_levels:
         # Level rows see a ~2^-level subsample: skip blocks with no key
         # at this row's level (the packer folded level_of into ts).
         lvl_pkt = ((ts_ref[...] >> np.uint32(LVL_SHIFT))
                    & np.uint32(LVL_FIELD_MASK)).astype(jnp.int32)
-        live = live & jnp.any(lvl_pkt >= params[PARAM_LEVEL])
+        live = live & (jnp.max(lvl_pkt) >= param(PARAM_LEVEL))
 
     @pl.when(live)
     def _accum():
         out_ref[...] += _frag_contrib(
-            params, keys_ref[...], vals, ts_ref[...], wi=wi, w_blk=w_blk,
+            param, keys_ref[...], vals, ts_ref[...], wi=wi, w_blk=w_blk,
             n_sub_max=n_sub_max, log2_te=log2_te, signed=signed,
             value_mode=value_mode, with_levels=with_levels,
-            with_mitigation=with_mitigation)[None]
+            with_mitigation=with_mitigation).reshape(out_ref.shape)
 
 
 def fleet_update_ragged_pallas(keys, vals, ts, params, block_frag, *,
@@ -303,20 +321,23 @@ def fleet_update_ragged_pallas(keys, vals, ts, params, block_frag, *,
                                interpret: bool = False):
     """Lowered pallas_call over the (level, width, packet-block) grid.
 
-    ``keys``/``vals``/``ts``: flat ``(n_blocks * blk,)`` CSR stream;
-    ``block_frag``: ``(n_blocks,)`` non-decreasing int32 block->*packet
-    row* map (``repro.core.fleet.pack_csr`` builds both).  ``params``
-    has ``n_levels`` virtual rows per packet row — table/output row
+    ``keys``/``vals``/``ts``: the flat CSR stream as lane-major
+    ``(n_blocks * blk / LANE, LANE)`` tiles; ``block_frag``:
+    ``(n_blocks,)`` non-decreasing int32 block->*packet row* map
+    (``repro.core.fleet.pack_csr`` builds both).  ``params`` has
+    ``n_levels`` virtual rows per packet row — table/output row
     ``bf[pj] * n_levels + l`` — so the packet stream is packed once per
-    fragment and the level axis fans it out in-grid.  The packet axis is
-    the inner sequential reduction, so each row's counter tile is
-    visited over a consecutive ``pj`` range and stays VMEM-resident
-    while its blocks stream through.
+    fragment and the level axis fans it out in-grid.  Both the map and
+    the flattened table are scalar-prefetched into SMEM.  The packet
+    axis is the inner sequential reduction, so each row's counter tile
+    is visited over a consecutive ``pj`` range and stays VMEM-resident
+    while its blocks stream through.  Returns
+    ``(n_rows, n_sub_max, padded_width / LANE, LANE)`` counter tiles.
     """
     n_rows = params.shape[0]
     nb = block_frag.shape[0]
-    assert keys.shape[0] == nb * blk and padded_width % w_blk == 0
-    assert n_rows % n_levels == 0
+    assert blk % LANE == 0 and keys.shape == (nb * blk // LANE, LANE)
+    assert padded_width % w_blk == 0 and n_rows % n_levels == 0
     if isinstance(keys, jax.core.Tracer):
         # Retrace probe: bumps only when _fleet_update_ragged_jit
         # misses its compile cache (see repro.sanitize).
@@ -326,20 +347,16 @@ def fleet_update_ragged_pallas(keys, vals, ts, params, block_frag, *,
     kernel = functools.partial(
         fleet_ragged_kernel, w_blk=w_blk, n_sub_max=n_sub_max,
         log2_te=log2_te, signed=signed, value_mode=value_mode,
-        with_levels=n_levels > 1, with_mitigation=with_mitigation)
+        n_levels=n_levels, with_mitigation=with_mitigation)
+    pkt = pl.BlockSpec((blk // LANE, LANE),
+                       lambda l, i, j, bf, prm: (j, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, N_PARAMS),
-                         lambda l, i, j, bf: (bf[j] * n_levels + l, 0)),
-            pl.BlockSpec((blk,), lambda l, i, j, bf: (j,)),
-            pl.BlockSpec((blk,), lambda l, i, j, bf: (j,)),
-            pl.BlockSpec((blk,), lambda l, i, j, bf: (j,)),
-        ],
+        in_specs=[pkt, pkt, pkt],
         out_specs=pl.BlockSpec(
             (1, n_sub_max, j_rows, LANE),
-            lambda l, i, j, bf: (bf[j] * n_levels + l, 0, i, 0)),
+            lambda l, i, j, bf, prm: (bf[j] * n_levels + l, 0, i, 0)),
     )
     return pl.pallas_call(
         kernel,
@@ -348,29 +365,35 @@ def fleet_update_ragged_pallas(keys, vals, ts, params, block_frag, *,
             (n_rows, n_sub_max, padded_width // LANE, LANE), jnp.float32),
         # Level and width blocks touch disjoint counter tiles: parallel
         # (megacore); the packet axis accumulates per row: sequential.
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_frag, params, keys, vals, ts)
+    )(block_frag, params.reshape(-1), keys, vals, ts)
 
 
 # Buffer donation of the per-window packet streams was evaluated and
 # rejected: XLA can only reuse a donated buffer by aliasing it to an
-# output of matching shape/dtype, and the 1-D uint32/f32 packet streams
-# never match the 3-D f32 counter stack — donation would just emit
+# output of matching shape/dtype, and the uint32/f32 packet streams
+# never match the f32 counter stack — donation would just emit
 # "donated buffers were not usable" warnings every window.  The streams
 # are transient Python references; they free as soon as the dispatch
 # consumes them.
-_fleet_update_ragged_jit = jax.jit(
-    fleet_update_ragged_pallas,
-    static_argnames=("n_sub_max", "padded_width", "log2_te", "signed",
-                     "blk", "w_blk", "value_mode", "n_levels",
-                     "with_mitigation", "interpret"))
+@functools.partial(jax.jit, static_argnames=(
+    "n_sub_max", "width_max", "padded_width", "log2_te", "signed", "blk",
+    "w_blk", "value_mode", "n_levels", "with_mitigation", "interpret"))
+def _fleet_update_ragged_jit(keys, vals, ts, params, block_frag, *,
+                             width_max: int, **kw):
+    """The ragged launch and the reshape/slice that undoes its factored
+    (.., W/LANE, LANE) layout, compiled as one program."""
+    out = fleet_update_ragged_pallas(keys, vals, ts, params, block_frag,
+                                     **kw)
+    return (out.reshape(out.shape[0], kw["n_sub_max"], kw["padded_width"])
+            [:, :, :width_max])
 
 
 def fleet_update_ragged(keys, vals, ts, params, block_frag, *,
                         n_sub_max: int, width_max: int, log2_te: int,
-                        signed: bool = True, blk: int = 256,
+                        signed: bool = True, blk: int = MIN_BLK,
                         w_blk: Optional[int] = None,
                         value_mode: str = "auto", n_levels: int = 1,
                         with_mitigation: bool = False, interpret="auto"):
@@ -385,9 +408,9 @@ def fleet_update_ragged(keys, vals, ts, params, block_frag, *,
         virtual level rows (``n_rows = n_packet_rows * n_levels``).
       block_frag: (n_blocks,) int32 non-decreasing block->packet-row
         map; every packet row must own at least one block.
-      blk: must match the packer's block size (the CSR alignment knob —
-        kept small so per-fragment padding stays <= blk, unlike the
-        compute-geometry ``blk`` of the dense paths).
+      blk: must match the packer's block size (the CSR alignment, which
+        is also the kernel's packet tile: a multiple of
+        ``kernel.MIN_BLK`` on the chip, of LANE in interpret mode).
       value_mode: contraction path ("auto" resolves from concrete
         values — see ``kernel.resolve_value_mode``).
       n_levels: UnivMon level rows per packet row (1 = cs/cms).
@@ -404,16 +427,14 @@ def fleet_update_ragged(keys, vals, ts, params, block_frag, *,
         _, w_blk = select_geometry(width_max, n_sub_max, value_mode)
     w_blk = min(w_blk, pow2_width_cap(width_max))
     pad_w = (-width_max) % w_blk
-    out = _fleet_update_ragged_jit(
-        jnp.asarray(keys, jnp.uint32), jnp.asarray(vals, jnp.float32),
-        jnp.asarray(ts, jnp.uint32), jnp.asarray(params, jnp.int32),
+    return _fleet_update_ragged_jit(
+        lane_tiles(keys, jnp.uint32), lane_tiles(vals, jnp.float32),
+        lane_tiles(ts, jnp.uint32), jnp.asarray(params, jnp.int32),
         jnp.asarray(block_frag, jnp.int32), n_sub_max=n_sub_max,
-        padded_width=width_max + pad_w, log2_te=log2_te, signed=signed,
-        blk=blk, w_blk=w_blk, value_mode=value_mode, n_levels=n_levels,
+        width_max=width_max, padded_width=width_max + pad_w,
+        log2_te=log2_te, signed=signed, blk=blk, w_blk=w_blk,
+        value_mode=value_mode, n_levels=n_levels,
         with_mitigation=with_mitigation, interpret=interpret)
-    # Undo the kernel's factored (.., W/LANE, LANE) layout: free reshape.
-    return (out.reshape(out.shape[0], n_sub_max, width_max + pad_w)
-            [:, :, :width_max])
 
 
 def fleet_update_loop(keys, vals, ts, params, *, n_sub_max: int,

@@ -64,9 +64,9 @@ import jax
 import jax.numpy as jnp
 
 from ... import sanitize
-from .kernel import (check_output_peak, pow2_width_cap, resolve_interpret,
-                     resolve_value_mode, select_geometry,
-                     sketch_update_pallas)
+from .kernel import (abs_peak, check_output_peak, lane_tiles,
+                     pow2_width_cap, resolve_interpret, resolve_value_mode,
+                     select_geometry, sketch_update_pallas)
 from .ref import sketch_update_ref
 
 
@@ -77,15 +77,12 @@ def _pad_to(x, m):
     return jnp.pad(x, (0, p))
 
 
-_abs_peak = jax.jit(lambda o: jnp.max(jnp.abs(o)))
-
-
 def _guard_peak(out, check_overflow: bool):
     """Output-side exactness guard (shared contract with the fleet
     runner's peak check).  Skipped under an outer trace, where the peak
     is abstract."""
     if check_overflow and not isinstance(out, jax.core.Tracer):
-        peak = float(_abs_peak(out)) if out.size else 0.0
+        peak = float(abs_peak(out)) if out.size else 0.0
         check_output_peak(peak)
     return out
 
@@ -100,9 +97,9 @@ def _sketch_update_jit(keys, vals, ts, *, width: int, n_sub: int,
                        value_mode: str, level: int, mitigation: bool,
                        interpret: bool):
     sanitize.note_trace("sketch_update._sketch_update_jit")
-    keys = _pad_to(keys.astype(jnp.uint32), blk)
-    vals = _pad_to(vals.astype(jnp.float32), blk)
-    ts = _pad_to(ts.astype(jnp.uint32), blk)
+    keys = lane_tiles(_pad_to(keys.astype(jnp.uint32), blk), jnp.uint32)
+    vals = lane_tiles(_pad_to(vals.astype(jnp.float32), blk), jnp.float32)
+    ts = lane_tiles(_pad_to(ts.astype(jnp.uint32), blk), jnp.uint32)
     w_blk = min(w_blk, pow2_width_cap(width))
     pad_w = (-width) % w_blk
     out = sketch_update_pallas(

@@ -9,6 +9,7 @@ and "data" are both batch axes, so no model code changes.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -33,11 +34,17 @@ def make_switch_mesh(n_devices: int | None = None, *, devices=None):
     first ``n_devices`` of ``jax.devices()`` when the product is smaller
     than the device count, so this works under
     ``--xla_force_host_platform_device_count=N`` without slicing here.
+
+    The axis is ``AxisType.Auto``: the fleet commits its window stacks
+    with explicit ``NamedSharding``s and runs the query merge under
+    ``shard_map``, and every other op on a sharded stack (the parity
+    patch's ``.at[].set``, epoch gathers) is left to the compiler's
+    sharding propagation.
     """
-    if devices is not None:
-        return jax.make_mesh((len(devices),), ("switch",), devices=devices)
-    n = len(jax.devices()) if n_devices is None else int(n_devices)
-    return jax.make_mesh((n,), ("switch",))
+    n = len(devices) if devices is not None else (
+        len(jax.devices()) if n_devices is None else int(n_devices))
+    return jax.make_mesh((n,), ("switch",), devices=devices,
+                         axis_types=(AxisType.Auto,))
 
 
 def switch_axis_size(mesh) -> int:

@@ -375,9 +375,11 @@ class VersionedControlPlane:
             if sw in self.system.dead:
                 continue
             n = int(base.get(sw, self.agents[sw].n))
+            n_ran = int(frozen.get(sw, n))
             for pebs in windows:
                 if sw in pebs:
-                    n = equalize.next_n(n, pebs[sw], self.rho)
+                    n = equalize.next_n_observed(n, pebs[sw], n_ran,
+                                                 self.rho)
             intent[sw] = n
         if new_dead:
             # §6 re-equalization: jump survivors to the converged

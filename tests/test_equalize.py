@@ -39,6 +39,18 @@ def test_next_n_control_loop():
     assert E.next_n(E.N_MAX, peb=1e9, rho_target=1.0) == E.N_MAX  # cap
 
 
+def test_window_replay_rescales_frozen_observations():
+    """Window mode replays E PEBs that were all observed at the frozen
+    n: the walk rescales each to the current n and settles in the band
+    instead of doubling E times on one stale reading."""
+    frozen, peb, target = 1, 10.0, 1.0
+    n = frozen
+    for _ in range(8):                     # one 8-epoch window
+        n = E.next_n_observed(n, peb, frozen, target)
+    assert n == 8 and target / 2 <= peb * frozen / n <= 2 * target
+    assert E.next_n_observed(4, 10.0, 4, 1.0) == E.next_n(4, 10.0, 1.0)
+
+
 def test_control_loop_converges():
     """Simulate rho ~ V/(n^2 w): the loop reaches a fixed point with
     peb in [target/2, 2*target]."""

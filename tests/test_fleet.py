@@ -224,7 +224,7 @@ def test_ragged_kernel_matches_dense_and_loop(signed):
     zero-length one and a hot fragment spanning many blocks)."""
     _, _, _, params, widths, nsubs = _fleet_inputs(5, 700)
     pkt = _ragged_packet([700, 3, 0, 130, 257], seed=4)
-    blk = 64
+    blk = 128
     kw = dict(n_sub_max=16, width_max=1000, log2_te=LOG2_TE, signed=signed)
     fkeys, fvals, fts, block_frag = pack_csr([pkt], blk)
     out_ragged = np.asarray(FK.fleet_update_ragged(
@@ -281,7 +281,7 @@ def test_grouped_dispatch_matches_single_launch():
     from repro.core.fleet import dispatch_ragged_grouped
 
     _, _, _, params, widths, nsubs = _fleet_inputs(5, 700)
-    blk = 64
+    blk = 128
     kw = dict(n_sub_max=16, width_max=1000, log2_te=LOG2_TE, signed=True,
               interpret=True)
     pkts = [_ragged_packet([700, 3, 0, 130, 257], seed=4),

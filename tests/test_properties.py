@@ -208,10 +208,10 @@ def test_masked_merge_matches_numpy_oracle(mask_bits, kind, seed):
 
 
 @settings(deadline=None, max_examples=15)
-@given(st.integers(0, 2**12 - 1),          # (E=2) x (F=6) liveness bitmask
-       st.sampled_from(["cs", "cms"]),
-       st.sampled_from([2, 4, 8]),
-       st.integers(0, 2**31 - 1))
+@given(mask_bits=st.integers(0, 2**12 - 1),  # (E=2) x (F=6) liveness bitmask
+       kind=st.sampled_from(["cs", "cms"]),
+       n_shards=st.sampled_from([2, 4, 8]),
+       seed=st.integers(0, 2**31 - 1))
 def test_sharded_merge_matches_host_oracle(mask_bits, kind, n_shards,
                                            seed, multidevice):
     """The cross-device fleet merge (PR 10), for ANY fragment->shard
@@ -270,14 +270,14 @@ def test_sharded_merge_matches_host_oracle(mask_bits, kind, n_shards,
 @given(st.integers(100, 100000), st.sampled_from([1, 2, 4, 8, 16, 64]),
        st.sampled_from(["count", "limb", "f32"]))
 def test_select_geometry_respects_budget(width, n_sub, mode):
-    """Any auto-selected geometry fits the VMEM budget, is 128-aligned,
-    and never exceeds the padded width."""
+    """Any auto-selected geometry fits the VMEM budget, is tile-aligned,
+    and never exceeds the padded width (floored at one 1024 tile)."""
     from repro.kernels.sketch_update.kernel import (VMEM_BUDGET_BYTES,
                                                     select_geometry,
                                                     vmem_bytes)
     blk, w_blk = select_geometry(width, n_sub, mode)
-    assert blk % 128 == 0 and w_blk % 128 == 0
-    assert w_blk <= max(1 << int(np.ceil(np.log2(max(width, 128)))), 128)
+    assert blk % 1024 == 0 and w_blk % 1024 == 0     # chip (8, 128) tiles
+    assert w_blk <= max(1 << int(np.ceil(np.log2(max(width, 128)))), 1024)
     assert vmem_bytes(blk, w_blk, n_sub, mode) <= VMEM_BUDGET_BYTES
 
 

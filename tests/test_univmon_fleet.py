@@ -96,7 +96,7 @@ def test_ragged_um_kernel_matches_loop_oracle(mitigation):
     once per fragment."""
     pkt, folded, params, widths, nsubs = _ragged_um_inputs(
         mitigation=mitigation)
-    blk = 64
+    blk = 128
     kw = dict(n_sub_max=8, width_max=300, log2_te=LOG2_TE, signed=True)
     fkeys, fvals, fts, block_frag = pack_csr([folded], blk)
     out = np.asarray(FK.fleet_update_ragged(

@@ -131,6 +131,7 @@ def _check_eval_shapes(findings: List[Finding]) -> None:
     import jax
     import numpy as np
 
+    from repro.core.fleet import CSR_BLK as csr_blk
     from repro.kernels.sketch_update import fleet as FK
     from repro.kernels.sketch_update.kernel import (LANE, pow2_width_cap,
                                                     select_geometry,
@@ -144,9 +145,9 @@ def _check_eval_shapes(findings: List[Finding]) -> None:
         blk, w_blk = select_geometry(width, n_sub, "f32")
         w_blk = min(w_blk, pow2_width_cap(width))
         pad_w = (-width) % w_blk
-        p = 4 * blk
-        k, v, t = shapes(((p,), np.uint32), ((p,), np.float32),
-                         ((p,), np.uint32))
+        p = 4 * blk // LANE
+        k, v, t = shapes(((p, LANE), np.uint32), ((p, LANE), np.float32),
+                         ((p, LANE), np.uint32))
         fn = functools.partial(
             sketch_update_pallas, hash_width=width,
             padded_width=width + pad_w, n_sub=n_sub, log2_te=16,
@@ -174,11 +175,13 @@ def _check_eval_shapes(findings: List[Finding]) -> None:
         pad_w = (-width_max) % w_blk
         padded = width_max + pad_w
         n_rows = n_frags * n_levels
-        p = 2 * blk
+        p = 2 * blk // LANE
+        tile = (n_sub_max, padded // LANE, LANE)
         if n_levels == 1:
             k, v, t, prm = shapes(
-                ((n_frags, p), np.uint32), ((n_frags, p), np.float32),
-                ((n_frags, p), np.uint32),
+                ((n_frags, p, LANE), np.uint32),
+                ((n_frags, p, LANE), np.float32),
+                ((n_frags, p, LANE), np.uint32),
                 ((n_frags, FK.N_PARAMS), np.int32))
             fn = functools.partial(
                 FK.fleet_update_pallas, n_sub_max=n_sub_max,
@@ -192,13 +195,13 @@ def _check_eval_shapes(findings: List[Finding]) -> None:
                     f"fleet_update_pallas({n_frags} frags) failed "
                     f"abstract eval: {e!r}"))
                 continue
-            want = (n_frags, n_sub_max, padded // LANE, LANE)
+            want = (n_frags, *tile)
         else:
-            csr_blk = 256
             nb = 2 * n_frags
             k, v, t, prm, bf = shapes(
-                ((nb * csr_blk,), np.uint32), ((nb * csr_blk,), np.float32),
-                ((nb * csr_blk,), np.uint32),
+                ((nb * csr_blk // LANE, LANE), np.uint32),
+                ((nb * csr_blk // LANE, LANE), np.float32),
+                ((nb * csr_blk // LANE, LANE), np.uint32),
                 ((n_rows, FK.N_PARAMS), np.int32), ((nb,), np.int32))
             fn = functools.partial(
                 FK.fleet_update_ragged_pallas, n_sub_max=n_sub_max,
@@ -213,7 +216,7 @@ def _check_eval_shapes(findings: List[Finding]) -> None:
                     f"fleet_update_ragged_pallas({n_rows} rows) failed "
                     f"abstract eval: {e!r}"))
                 continue
-            want = (n_rows, n_sub_max, padded // LANE, LANE)
+            want = (n_rows, *tile)
         if tuple(out.shape) != want or out.dtype != np.float32:
             findings.append(Finding(
                 "eval-shape", _SRC, 1,
@@ -238,6 +241,7 @@ def _check_sharded(findings: List[Finding]) -> None:
     import jax
     import numpy as np
 
+    from repro.core.fleet import CSR_BLK as csr_blk
     from repro.kernels.sketch_query import shard_padded_rows
     from repro.kernels.sketch_update import fleet as FK
     from repro.kernels.sketch_update.kernel import (
@@ -279,12 +283,11 @@ def _check_sharded(findings: List[Finding]) -> None:
                     f"{used} B > budget {VMEM_BUDGET_BYTES} B"))
             rows_shard = r_pad // n_shards
             padded = width_max + (-width_max) % w_blk
-            csr_blk = 256
             nb = 2 * max(rows_shard // n_levels, 1)
             k, v, t, prm, bf = shapes(
-                ((nb * csr_blk,), np.uint32),
-                ((nb * csr_blk,), np.float32),
-                ((nb * csr_blk,), np.uint32),
+                ((nb * csr_blk // LANE, LANE), np.uint32),
+                ((nb * csr_blk // LANE, LANE), np.float32),
+                ((nb * csr_blk // LANE, LANE), np.uint32),
                 ((rows_shard, FK.N_PARAMS), np.int32),
                 ((nb,), np.int32))
             fn = functools.partial(
