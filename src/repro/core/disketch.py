@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from . import equalize, query, sketches
 from .fragment import EpochRecords, FragmentConfig, process_epoch
 
@@ -75,63 +76,66 @@ class DiSketchSystem:
                  backend: str = "loop",
                  fleet_kwargs: Optional[Dict] = None,
                  mesh=None):
-        self.kind = kind
-        self.rho_target = rho_target
-        self.log2_te = log2_te
-        self.fragments: Dict[int, FragmentConfig] = {
-            sw: FragmentConfig(frag_id=sw, kind=kind, memory_bytes=mem,
-                               counter_bytes=counter_bytes,
-                               mitigation=mitigation, n_levels=n_levels,
-                               base_seed=seed)
-            for sw, mem in switch_memories.items()
-        }
-        # rho_-1 undefined: start every fragment at n_0 = 1 (§4.2).
-        self.ns: Dict[int, int] = {sw: 1 for sw in switch_memories}
-        self.records: Dict[int, Dict[int, EpochRecords]] = {}  # epoch -> sw
-        self.peb_log: List[Dict[int, float]] = []
-        self.n_log: List[Dict[int, int]] = []
-        # -- churn state (net.simulator.FailureSchedule drives this) -----
-        # Switches whose sketch resource is currently reclaimed.  A dead
-        # switch keeps forwarding traffic (disaggregation uses residual
-        # resources, §1) — it just stops counting: its packets become
-        # value-0 no-ops on the fleet, it is skipped by the loop backend,
-        # masked from every query path, and held out of the §4.2 control.
-        self.dead: set = set()
-        self._dead_at: Dict[int, frozenset] = {}   # epoch -> dead set
-        # Resource resizes (shrinks AND grows) arriving mid-window are
-        # deferred to the next dispatch boundary (widths are frozen per
-        # window); factors multiply while pending.
-        self._pending_resize: Dict[int, float] = {}
-        # Width each switch had when its most recent PEB was observed —
-        # a later resize makes that observation *stale*, and the §6
-        # re-equalization must converge against the width-clamped bound
-        # (see ``_reequalize_survivors``), not the raw stale number.
-        self._peb_width: Dict[int, int] = {}
-        # Re-equalization clamps surfaced to ``observability`` (the
-        # "intended vs applied under actual residual memory" record).
-        self.clamp_log: List[Dict] = []
-        # External control mode (``runtime.control.VersionedControlPlane``
-        # sets this): the system stops self-applying the Eq. 6 / §6
-        # control — ``ns`` holds whatever config the switches *actually
-        # applied*, and the (possibly lossy) control plane owns intent.
-        self.control_external = False
-        # Observability accounting of the last query window (stamped by
-        # query_flows / query_entropy; see ``observability``).
-        self.last_observability: Optional[Dict] = None
-        if backend not in ("loop", "fleet"):
-            raise ValueError(f"unknown backend {backend!r}")
-        if mesh is not None and backend != "fleet":
-            raise ValueError(
-                "mesh sharding requires backend='fleet' (the loop "
-                "backend is per-switch host numpy)")
-        self.backend = backend
-        self.fleet: Optional["FleetEpochRunner"] = None
-        if backend == "fleet":
-            from .fleet import FleetEpochRunner
-            kw = dict(fleet_kwargs or {})
-            if mesh is not None:
-                kw.setdefault("mesh", mesh)
-            self.fleet = FleetEpochRunner(self.fragments, log2_te, **kw)
+        with obs.span("system.init", fragments=len(switch_memories)):
+            self.kind = kind
+            self.rho_target = rho_target
+            self.log2_te = log2_te
+            self.fragments: Dict[int, FragmentConfig] = {
+                sw: FragmentConfig(frag_id=sw, kind=kind, memory_bytes=mem,
+                                   counter_bytes=counter_bytes,
+                                   mitigation=mitigation, n_levels=n_levels,
+                                   base_seed=seed)
+                for sw, mem in switch_memories.items()
+            }
+            # rho_-1 undefined: start every fragment at n_0 = 1 (§4.2).
+            self.ns: Dict[int, int] = {sw: 1 for sw in switch_memories}
+            self.records: Dict[int, Dict[int, EpochRecords]] = {}  # epoch -> sw
+            self.peb_log: List[Dict[int, float]] = []
+            self.n_log: List[Dict[int, int]] = []
+            # -- churn state (net.simulator.FailureSchedule drives this) -----
+            # Switches whose sketch resource is currently reclaimed.  A dead
+            # switch keeps forwarding traffic (disaggregation uses residual
+            # resources, §1) — it just stops counting: its packets become
+            # value-0 no-ops on the fleet, it is skipped by the loop backend,
+            # masked from every query path, and held out of the §4.2 control.
+            self.dead: set = set()
+            self._dead_at: Dict[int, frozenset] = {}   # epoch -> dead set
+            # Resource resizes (shrinks AND grows) arriving mid-window are
+            # deferred to the next dispatch boundary (widths are frozen per
+            # window); factors multiply while pending.
+            self._pending_resize: Dict[int, float] = {}
+            # Width each switch had when its most recent PEB was observed —
+            # a later resize makes that observation *stale*, and the §6
+            # re-equalization must converge against the width-clamped bound
+            # (see ``_reequalize_survivors``), not the raw stale number.
+            self._peb_width: Dict[int, int] = {}
+            # Re-equalization clamps surfaced to ``observability`` (the
+            # "intended vs applied under actual residual memory" record).
+            self.clamp_log: List[Dict] = []
+            # External control mode (``runtime.control.VersionedControlPlane``
+            # sets this): the system stops self-applying the Eq. 6 / §6
+            # control — ``ns`` holds whatever config the switches *actually
+            # applied*, and the (possibly lossy) control plane owns intent.
+            self.control_external = False
+            # Observability accounting of the last query window (stamped by
+            # query_flows / query_entropy; see ``observability``).
+            self.last_observability: Optional[Dict] = None
+            # query_flows calls so far: the request number of its span
+            self.queries = 0
+            if backend not in ("loop", "fleet"):
+                raise ValueError(f"unknown backend {backend!r}")
+            if mesh is not None and backend != "fleet":
+                raise ValueError(
+                    "mesh sharding requires backend='fleet' (the loop "
+                    "backend is per-switch host numpy)")
+            self.backend = backend
+            self.fleet: Optional["FleetEpochRunner"] = None
+            if backend == "fleet":
+                from .fleet import FleetEpochRunner
+                kw = dict(fleet_kwargs or {})
+                if mesh is not None:
+                    kw.setdefault("mesh", mesh)
+                self.fleet = FleetEpochRunner(self.fragments, log2_te, **kw)
 
     # -- churn control plane -------------------------------------------------
 
@@ -331,48 +335,50 @@ class DiSketchSystem:
             return
         from .fleet import pack_streams
 
-        e_count = len(streams_list)
-        if events_by_epoch is not None and len(events_by_epoch) != e_count:
-            raise ValueError("events_by_epoch must have one entry per epoch "
-                             f"({len(events_by_epoch)} != {e_count})")
-        self._apply_pending_resizes()
-        for ev in (events_by_epoch[0] if events_by_epoch else ()):
-            self.apply_event(ev)
-        ns = (dict(self.ns) if self.subepoching
-              else {sw: 1 for sw in self.fragments})
-        dead_sets = [frozenset(self.dead)]
-        fail_pts: List[Tuple[int, int]] = []
-        for e in range(1, e_count):
-            for ev in (events_by_epoch[e] if events_by_epoch else ()):
-                if ev.kind == "fail" and ev.switch not in self.dead:
-                    fail_pts.append((e, ev.switch))
-                self.apply_event(ev, defer_resize=True)
-            dead_sets.append(frozenset(self.dead))
-        lost_sets: List[set] = [set() for _ in range(e_count)]
-        for e, sw in fail_pts:
-            for e2 in range(e):
-                if sw not in dead_sets[e2]:
-                    lost_sets[e2].add(sw)
-        if packets is None:
-            packets = [pack_streams(st, self.fleet.frag_order)
-                       for st in streams_list]
-        recs_list, pebs_list = self.fleet.run_window(
-            epoch0, ns, packets,
-            dead_by_epoch=dead_sets, lost_by_epoch=lost_sets)
-        for e, (recs, pebs) in enumerate(zip(recs_list, pebs_list)):
-            if dead_sets[e]:
-                self._dead_at[epoch0 + e] = dead_sets[e]
-            else:
-                self._dead_at.pop(epoch0 + e, None)
-            self.records[epoch0 + e] = recs
-            self.peb_log.append(pebs)
-            for sw in pebs:
-                self._peb_width[sw] = self.fragments[sw].width
-            if self.subepoching and not self.control_external:
-                for sw, peb in pebs.items():
-                    self.ns[sw] = equalize.next_n_observed(
-                        self.ns[sw], peb, ns[sw], self.rho_target)
-            self.n_log.append(dict(self.ns))
+        with obs.span("system.run_window", epoch0=epoch0,
+                      epochs=len(streams_list)):
+            e_count = len(streams_list)
+            if events_by_epoch is not None and len(events_by_epoch) != e_count:
+                raise ValueError("events_by_epoch must have one entry per epoch "
+                                 f"({len(events_by_epoch)} != {e_count})")
+            self._apply_pending_resizes()
+            for ev in (events_by_epoch[0] if events_by_epoch else ()):
+                self.apply_event(ev)
+            ns = (dict(self.ns) if self.subepoching
+                  else {sw: 1 for sw in self.fragments})
+            dead_sets = [frozenset(self.dead)]
+            fail_pts: List[Tuple[int, int]] = []
+            for e in range(1, e_count):
+                for ev in (events_by_epoch[e] if events_by_epoch else ()):
+                    if ev.kind == "fail" and ev.switch not in self.dead:
+                        fail_pts.append((e, ev.switch))
+                    self.apply_event(ev, defer_resize=True)
+                dead_sets.append(frozenset(self.dead))
+            lost_sets: List[set] = [set() for _ in range(e_count)]
+            for e, sw in fail_pts:
+                for e2 in range(e):
+                    if sw not in dead_sets[e2]:
+                        lost_sets[e2].add(sw)
+            if packets is None:
+                packets = [pack_streams(st, self.fleet.frag_order)
+                           for st in streams_list]
+            recs_list, pebs_list = self.fleet.run_window(
+                epoch0, ns, packets,
+                dead_by_epoch=dead_sets, lost_by_epoch=lost_sets)
+            for e, (recs, pebs) in enumerate(zip(recs_list, pebs_list)):
+                if dead_sets[e]:
+                    self._dead_at[epoch0 + e] = dead_sets[e]
+                else:
+                    self._dead_at.pop(epoch0 + e, None)
+                self.records[epoch0 + e] = recs
+                self.peb_log.append(pebs)
+                for sw in pebs:
+                    self._peb_width[sw] = self.fragments[sw].width
+                if self.subepoching and not self.control_external:
+                    for sw, peb in pebs.items():
+                        self.ns[sw] = equalize.next_n_observed(
+                            self.ns[sw], peb, ns[sw], self.rho_target)
+                self.n_log.append(dict(self.ns))
 
     # -- query plane --------------------------------------------------------
 
@@ -467,45 +473,49 @@ class DiSketchSystem:
         """
         if failures not in ("oblivious", "mask", "recover"):
             raise ValueError(f"unknown failure policy {failures!r}")
-        self.last_observability = self.observability(epochs)
-        keys = np.asarray(keys, dtype=np.uint32)
-        out = np.zeros(len(keys))
-        by_path: Dict[Tuple[int, ...], List[int]] = {}
-        for i, p in enumerate(paths):
-            by_path.setdefault(tuple(p), []).append(i)
-        device_ok = (merge == "fragment" and self.fleet is not None
-                     and self.fleet.has_device_window(epochs))
-        if failures == "recover" and self.fleet is not None and not device_ok:
-            # the device path recovers inside window_query; the record
-            # path needs the stacks patched before materialization
-            self.fleet.recover(epochs)
-            failures = "mask"
-        # um frequency estimates come from level 0 (the full-stream
-        # level); the record plane needs level=None for non-um kinds.
-        level = 0 if self.kind == "um" else None
-        for path, idxs in by_path.items():
-            idxs = np.asarray(idxs)
-            if device_ok:
-                out[idxs] = self.fleet.window_query(
-                    epochs, keys[idxs], path=path, level=0,
-                    single_hop=len(path) == 1, failures=failures)
-                continue
-            recs = self._records_for(path, epochs, failures=failures)
-            scale = 1.0
-            if failures != "oblivious":
-                # query_window skips empty (blind) epochs; extrapolate
-                # O_Q from the observed ones (§4.3 blind-spot fill,
-                # lifted from subepoch slots to whole epochs).
-                n_obs, scale = query.window_observability(recs)
-                if not n_obs:
-                    raise ValueError(
-                        f"no epoch in {list(epochs)} has a live fragment on "
-                        f"path {path}; the window is unobservable")
-            sh = np.full(len(idxs), len(path) == 1)
-            out[idxs] = query.query_window(
-                recs, keys[idxs], self.kind,
-                single_hop=sh, level=level, merge=merge) * scale
-        return out
+        self.queries += 1
+        with obs.span("query.flows", request=self.queries,
+                      keys=len(keys)) as sp:
+            self.last_observability = self.observability(epochs)
+            keys = np.asarray(keys, dtype=np.uint32)
+            out = np.zeros(len(keys))
+            by_path: Dict[Tuple[int, ...], List[int]] = {}
+            for i, p in enumerate(paths):
+                by_path.setdefault(tuple(p), []).append(i)
+            sp.set_metadata(paths=len(by_path))
+            device_ok = (merge == "fragment" and self.fleet is not None
+                         and self.fleet.has_device_window(epochs))
+            if failures == "recover" and self.fleet is not None and not device_ok:
+                # the device path recovers inside window_query; the record
+                # path needs the stacks patched before materialization
+                self.fleet.recover(epochs)
+                failures = "mask"
+            # um frequency estimates come from level 0 (the full-stream
+            # level); the record plane needs level=None for non-um kinds.
+            level = 0 if self.kind == "um" else None
+            for path, idxs in by_path.items():
+                idxs = np.asarray(idxs)
+                if device_ok:
+                    out[idxs] = self.fleet.window_query(
+                        epochs, keys[idxs], path=path, level=0,
+                        single_hop=len(path) == 1, failures=failures)
+                    continue
+                recs = self._records_for(path, epochs, failures=failures)
+                scale = 1.0
+                if failures != "oblivious":
+                    # query_window skips empty (blind) epochs; extrapolate
+                    # O_Q from the observed ones (§4.3 blind-spot fill,
+                    # lifted from subepoch slots to whole epochs).
+                    n_obs, scale = query.window_observability(recs)
+                    if not n_obs:
+                        raise ValueError(
+                            f"no epoch in {list(epochs)} has a live fragment on "
+                            f"path {path}; the window is unobservable")
+                sh = np.full(len(idxs), len(path) == 1)
+                out[idxs] = query.query_window(
+                    recs, keys[idxs], self.kind,
+                    single_hop=sh, level=level, merge=merge) * scale
+            return out
 
     def query_entropy(self, keys: np.ndarray,
                       paths: Sequence[Tuple[int, ...]],

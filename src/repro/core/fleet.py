@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..kernels.sketch_update.kernel import MIN_BLK, abs_peak
 from . import equalize
 from .fragment import (EpochRecords, FragmentConfig, _ROLE_COL, _ROLE_SIGN,
@@ -260,30 +261,33 @@ def pack_csr(packets: Sequence[FleetPacket], blk: int = CSR_BLK,
     blocks map to the last row).
     """
     assert len(packets) >= 1
-    n_rows = sum(p.n_frags for p in packets)
-    lens = (np.concatenate([p.seg_lengths() for p in packets])
-            .astype(np.int64))
-    nblk = np.maximum(1, -(-lens // blk))
-    row_blk_off = np.concatenate([[0], np.cumsum(nblk)])
-    nb_live = int(row_blk_off[-1])
-    nb = _bucket_blocks(nb_live)
-    p_tot = nb * blk
-    keys = np.zeros(p_tot, np.uint32)
-    vals = np.zeros(p_tot, np.float32)
-    ts = np.zeros(p_tot, np.uint32)
-    src_keys = np.concatenate([p.keys for p in packets])
-    src_vals = np.concatenate([p.values for p in packets])
-    src_ts = np.concatenate([p.ts for p in packets])
-    row_src_off = np.concatenate([[0], np.cumsum(lens)])
-    dst = (np.arange(len(src_keys), dtype=np.int64)
-           - np.repeat(row_src_off[:-1], lens)
-           + np.repeat(row_blk_off[:-1] * blk, lens))
-    keys[dst] = src_keys
-    vals[dst] = src_vals
-    ts[dst] = src_ts
-    block_frag = np.full(nb, max(n_rows - 1, 0), np.int32)
-    block_frag[:nb_live] = np.repeat(np.arange(n_rows, dtype=np.int32),
-                                     nblk)
+    with obs.span("fleet.pack_csr") as sp:
+        n_rows = sum(p.n_frags for p in packets)
+        lens = (np.concatenate([p.seg_lengths() for p in packets])
+                .astype(np.int64))
+        nblk = np.maximum(1, -(-lens // blk))
+        row_blk_off = np.concatenate([[0], np.cumsum(nblk)])
+        nb_live = int(row_blk_off[-1])
+        nb = _bucket_blocks(nb_live)
+        p_tot = nb * blk
+        keys = np.zeros(p_tot, np.uint32)
+        vals = np.zeros(p_tot, np.float32)
+        ts = np.zeros(p_tot, np.uint32)
+        src_keys = np.concatenate([p.keys for p in packets])
+        src_vals = np.concatenate([p.values for p in packets])
+        src_ts = np.concatenate([p.ts for p in packets])
+        row_src_off = np.concatenate([[0], np.cumsum(lens)])
+        dst = (np.arange(len(src_keys), dtype=np.int64)
+               - np.repeat(row_src_off[:-1], lens)
+               + np.repeat(row_blk_off[:-1] * blk, lens))
+        keys[dst] = src_keys
+        vals[dst] = src_vals
+        ts[dst] = src_ts
+        block_frag = np.full(nb, max(n_rows - 1, 0), np.int32)
+        block_frag[:nb_live] = np.repeat(np.arange(n_rows, dtype=np.int32),
+                                         nblk)
+        sp.set_metadata(packets=len(src_keys), slots=p_tot,
+                        slots_live=nb_live * blk)
     return keys, vals, ts, block_frag
 
 
@@ -401,17 +405,30 @@ def dispatch_ragged_grouped(params: np.ndarray,
         rows = ((np.arange(e_count)[:, None] * n_frags
                  + frag_idx[None, :]).ravel()[:, None] * L
                 + np.arange(L)[None, :]).ravel()
-        keys, vals, ts, block_frag = pack_csr(
-            [p.select(frag_idx) for p in packets], blk)
-        out_g = FK.fleet_update_ragged(
-            keys, vals, ts, params[rows], block_frag,
-            n_sub_max=n_g, width_max=w_g, **kw)
+        with obs.span("fleet.select"):
+            selected = [p.select(frag_idx) for p in packets]
+        keys, vals, ts, block_frag = pack_csr(selected, blk)
+        out_g = _launch_ragged(keys, vals, ts, params[rows], block_frag,
+                               n_sub_max=n_g, width_max=w_g, **kw)
         if len(groups) == 1 and n_g == n_sub_max and w_g == width_max:
             return out_g
         outs.append(out_g)
         out_rows.append(rows.astype(np.int32))
-    return _assemble_groups(tuple(outs), tuple(out_rows),
-                            (n_rows, n_sub_max, width_max))
+    with obs.span("fleet.launch",
+                  h2d_bytes=sum(r.nbytes for r in out_rows)):
+        return _assemble_groups(tuple(outs), tuple(out_rows),
+                                (n_rows, n_sub_max, width_max))
+
+
+def _launch_ragged(keys, vals, ts, params, block_frag, **kw):
+    """``fleet_update_ragged`` under a ``fleet.launch`` span that counts
+    the bytes of its host arguments."""
+    from ..kernels.sketch_update import fleet as FK
+
+    h2d = sum(a.nbytes for a in (keys, vals, ts, params, block_frag))
+    with obs.span("fleet.launch", h2d_bytes=h2d):
+        return FK.fleet_update_ragged(keys, vals, ts, params, block_frag,
+                                      **kw)
 
 
 class _WindowBuffer:
@@ -765,11 +782,12 @@ class FleetEpochRunner:
         # Fold per-packet UnivMon level ids / §4.4 flags into the high
         # ts bits (no-op for plain cs/cms fleets — the cached epoch
         # packets are shared across systems and must stay untouched).
-        packets = [fold_packet_flags(p, self.log2_te,
-                                     n_levels=self.n_levels,
-                                     level_seed=self.level_seed,
-                                     mitigation=self.mitigation)
-                   for p in packets]
+        with obs.span("fleet.prepare"):
+            packets = [fold_packet_flags(p, self.log2_te,
+                                         n_levels=self.n_levels,
+                                         level_seed=self.level_seed,
+                                         mitigation=self.mitigation)
+                       for p in packets]
         kw = dict(n_sub_max=n_sub_max, width_max=width_max,
                   log2_te=self.log2_te,
                   signed=self.kind in ("cs", "um"),
@@ -788,8 +806,7 @@ class FleetEpochRunner:
                 params, packets, n_sub_max=n_sub_max, width_max=width_max,
                 **kw)
         keys, vals, ts, block_frag = pack_csr(packets, self.blk)
-        return FK.fleet_update_ragged(keys, vals, ts, params, block_frag,
-                                      **kw)
+        return _launch_ragged(keys, vals, ts, params, block_frag, **kw)
 
     # --- mesh-sharded dispatch (docs/sharding.md) ------------------------
 
@@ -1040,110 +1057,118 @@ class FleetEpochRunner:
         """
         from ..kernels.sketch_update.fleet import PARAM_N_SUB
 
-        e_count = len(packets)
-        assert e_count >= 1
-        for packet in packets:
-            assert packet.frag_order == self.frag_order
-        if self.layout != "ragged":
-            raise ValueError("window dispatch requires layout='ragged'")
-        fleet_set = set(self.frag_order)
-        dead_sets = [set(d) & fleet_set for d in dead_by_epoch] \
-            if dead_by_epoch is not None else [set()] * e_count
-        lost_sets = [set(s) & fleet_set for s in lost_by_epoch] \
-            if lost_by_epoch is not None else [set()] * e_count
-        assert len(dead_sets) == e_count and len(lost_sets) == e_count
-        if any(dead_sets):
-            packets = [mask_fragment_values(
-                p, sorted(self._frag_pos[sw] for sw in dead))
-                for p, dead in zip(packets, dead_sets)]
-        self._check_input_mass(packets)
-        n_frags = len(self.frag_order)
-        L = self.n_levels
-        rows_per_epoch = n_frags * L
-        params = np.concatenate([
-            build_params(self.fragments, epoch0 + e, ns, self.frag_order)
-            for e in range(e_count)])
-        n_arr = params[:rows_per_epoch:L, PARAM_N_SUB].astype(np.int64)
-        n_sub_max = int(params[:, PARAM_N_SUB].max(initial=1))
-        width_max = int(self.widths.max(initial=4))
+        with obs.span("fleet.run_window", epochs=len(packets)) as sp:
+            e_count = len(packets)
+            assert e_count >= 1
+            for packet in packets:
+                assert packet.frag_order == self.frag_order
+            if self.layout != "ragged":
+                raise ValueError("window dispatch requires layout='ragged'")
+            fleet_set = set(self.frag_order)
+            dead_sets = [set(d) & fleet_set for d in dead_by_epoch] \
+                if dead_by_epoch is not None else [set()] * e_count
+            lost_sets = [set(s) & fleet_set for s in lost_by_epoch] \
+                if lost_by_epoch is not None else [set()] * e_count
+            assert len(dead_sets) == e_count and len(lost_sets) == e_count
+            n_frags = len(self.frag_order)
+            L = self.n_levels
+            rows_per_epoch = n_frags * L
+            sp.set_metadata(rows=e_count * rows_per_epoch)
+            with obs.span("fleet.prepare"):
+                if any(dead_sets):
+                    packets = [mask_fragment_values(
+                        p, sorted(self._frag_pos[sw] for sw in dead))
+                        for p, dead in zip(packets, dead_sets)]
+                self._check_input_mass(packets)
+                params = np.concatenate([
+                    build_params(self.fragments, epoch0 + e, ns,
+                                 self.frag_order)
+                    for e in range(e_count)])
+                n_arr = params[:rows_per_epoch:L, PARAM_N_SUB].astype(np.int64)
+                n_sub_max = int(params[:, PARAM_N_SUB].max(initial=1))
+                width_max = int(self.widths.max(initial=4))
 
-        if self.mesh is not None:
-            buf, pebs_all, parity_by_epoch, peak = self._run_window_mesh(
-                params, packets, lost_sets, n_arr, e_count,
-                n_sub_max, width_max)
-            self._check_output_peak(peak)
-        else:
-            out = self._dispatch(params, packets, n_sub_max, width_max)
-            self._check_output_peak(float(abs_peak(out)) if out.size
-                                    else 0.0)
-            # §4.2 PEBs from the level-0 rows (::L is a no-op for
-            # cs/cms) — computed before lost cells are zeroed (their
-            # counters are genuine observations of epochs the switch did
-            # sketch).
-            pebs_all = np.asarray(equalize.peb_fleet_device(
-                out[::L], np.tile(n_arr, e_count),
-                np.tile(self.widths, e_count),
-                self.kind)).reshape(e_count, n_frags)
-            # XOR parity per (epoch, group) over the un-zeroed stack:
-            # exact integers below 2^24 make the f32->int32 conversion
-            # lossless, and XOR (unlike a sum) can neither overflow nor
-            # round.
-            parity_by_epoch = None
-            if self.parity_groups is not None:
-                parity_by_epoch = self._window_parity(
-                    out, e_count, rows_per_epoch, n_sub_max, width_max)
-            if any(lost_sets):
-                rows = np.concatenate([
-                    np.arange(i * L, (i + 1) * L) + e * rows_per_epoch
-                    for e, lost in enumerate(lost_sets)
-                    for i in sorted(self._frag_pos[sw] for sw in lost)]
-                ).astype(np.int32)
-                out = out.at[jnp.asarray(rows)].set(0.0)
-
-            buf = _WindowBuffer(out, (e_count, rows_per_epoch, n_sub_max,
-                                      width_max))
-        recs_list: List[WindowRecords] = []
-        pebs_list: List[Dict[int, float]] = []
-        # snapshot the config dict: a later shrink must not re-slice
-        # this window's records with the new width
-        frags_now = dict(self.fragments)
-        for e in range(e_count):
-            ep = epoch0 + e
-            recs_list.append(WindowRecords(buf, e, ep, frags_now,
-                                           self.frag_order, n_arr,
-                                           n_levels=L))
-            pebs_list.append({sw: float(pebs_all[e, i])
-                              for i, sw in enumerate(self.frag_order)
-                              if sw not in dead_sets[e]})
-            # Point/window queries are served straight from the resident
-            # buffer (kernels.sketch_query) — no keep_stacked required,
-            # and no eager host() transfer: forcing the transfer here is
-            # exactly what window mode exists to avoid.  Host stacks
-            # materialize lazily (``_host_stack``) only if something
-            # transfers the buffer first.
-            self._window_bufs[ep] = (buf, e)
-            self._params_log[ep] = \
-                params[e * rows_per_epoch:(e + 1) * rows_per_epoch]
-            # drop any stale per-epoch retention from a previous run of
-            # the same epoch — its counters pair with the OLD seeds
-            self.stacked.pop(ep, None)
-            self._lost.pop(ep, None)
-            self._parity.pop(ep, None)
-            self._unexported.pop(ep, None)
-            if parity_by_epoch is not None:
-                self._parity[ep] = parity_by_epoch[e]
-            invalid = dead_sets[e] | lost_sets[e]
-            if invalid:
-                live = np.ones(rows_per_epoch, bool)
-                for sw in invalid:
-                    i = self._frag_pos[sw]
-                    live[i * L:(i + 1) * L] = False
-                self._row_live[ep] = live
-                self._lost[ep] = {self._frag_pos[sw]
-                                  for sw in lost_sets[e]}
+            if self.mesh is not None:
+                buf, pebs_all, parity_by_epoch, peak = self._run_window_mesh(
+                    params, packets, lost_sets, n_arr, e_count,
+                    n_sub_max, width_max)
+                self._check_output_peak(peak)
             else:
-                self._row_live.pop(ep, None)
-        return recs_list, pebs_list
+                out = self._dispatch(params, packets, n_sub_max, width_max)
+                peak = abs_peak(out) if out.size else 0.0
+                with obs.span("fleet.sync"):
+                    peak = float(peak)
+                self._check_output_peak(peak)
+                # §4.2 PEBs from the level-0 rows (::L is a no-op for
+                # cs/cms) — computed before lost cells are zeroed (their
+                # counters are genuine observations of epochs the switch did
+                # sketch).
+                pebs_dev = equalize.peb_fleet_device(
+                    out[::L], np.tile(n_arr, e_count),
+                    np.tile(self.widths, e_count), self.kind)
+                with obs.span("fleet.sync"):
+                    pebs_all = np.asarray(pebs_dev).reshape(e_count, n_frags)
+                # XOR parity per (epoch, group) over the un-zeroed stack:
+                # exact integers below 2^24 make the f32->int32 conversion
+                # lossless, and XOR (unlike a sum) can neither overflow nor
+                # round.
+                parity_by_epoch = None
+                if self.parity_groups is not None:
+                    parity_by_epoch = self._window_parity(
+                        out, e_count, rows_per_epoch, n_sub_max, width_max)
+                if any(lost_sets):
+                    rows = np.concatenate([
+                        np.arange(i * L, (i + 1) * L) + e * rows_per_epoch
+                        for e, lost in enumerate(lost_sets)
+                        for i in sorted(self._frag_pos[sw] for sw in lost)]
+                    ).astype(np.int32)
+                    out = out.at[jnp.asarray(rows)].set(0.0)
+
+                buf = _WindowBuffer(out, (e_count, rows_per_epoch, n_sub_max,
+                                          width_max))
+            with obs.span("fleet.records"):
+                recs_list: List[WindowRecords] = []
+                pebs_list: List[Dict[int, float]] = []
+                # snapshot the config dict: a later shrink must not re-slice
+                # this window's records with the new width
+                frags_now = dict(self.fragments)
+                for e in range(e_count):
+                    ep = epoch0 + e
+                    recs_list.append(WindowRecords(buf, e, ep, frags_now,
+                                                   self.frag_order, n_arr,
+                                                   n_levels=L))
+                    pebs_list.append({sw: float(pebs_all[e, i])
+                                      for i, sw in enumerate(self.frag_order)
+                                      if sw not in dead_sets[e]})
+                    # Point/window queries are served straight from the resident
+                    # buffer (kernels.sketch_query) — no keep_stacked required,
+                    # and no eager host() transfer: forcing the transfer here is
+                    # exactly what window mode exists to avoid.  Host stacks
+                    # materialize lazily (``_host_stack``) only if something
+                    # transfers the buffer first.
+                    self._window_bufs[ep] = (buf, e)
+                    self._params_log[ep] = \
+                        params[e * rows_per_epoch:(e + 1) * rows_per_epoch]
+                    # drop any stale per-epoch retention from a previous run of
+                    # the same epoch — its counters pair with the OLD seeds
+                    self.stacked.pop(ep, None)
+                    self._lost.pop(ep, None)
+                    self._parity.pop(ep, None)
+                    self._unexported.pop(ep, None)
+                    if parity_by_epoch is not None:
+                        self._parity[ep] = parity_by_epoch[e]
+                    invalid = dead_sets[e] | lost_sets[e]
+                    if invalid:
+                        live = np.ones(rows_per_epoch, bool)
+                        for sw in invalid:
+                            i = self._frag_pos[sw]
+                            live[i * L:(i + 1) * L] = False
+                        self._row_live[ep] = live
+                        self._lost[ep] = {self._frag_pos[sw]
+                                          for sw in lost_sets[e]}
+                    else:
+                        self._row_live.pop(ep, None)
+            return recs_list, pebs_list
 
     def _window_parity(self, out, e_count: int, rows_per_epoch: int,
                        n_sub_max: int, width_max: int,
@@ -1471,11 +1496,12 @@ class FleetEpochRunner:
         """
         from . import query as Q
 
-        keys = np.asarray(keys, np.uint32)
-        base = self._row_sel(path, level)
-        epochs, sel_by_e, scale = self._liveness_sels(epochs, base,
-                                                      failures)
-        device_groups, host_epochs = self._route_epochs(epochs)
+        with obs.span("query.prep"):
+            keys = np.asarray(keys, np.uint32)
+            base = self._row_sel(path, level)
+            epochs, sel_by_e, scale = self._liveness_sels(epochs, base,
+                                                          failures)
+            device_groups, host_epochs = self._route_epochs(epochs)
         out = np.zeros(len(keys))
         for stack, es in device_groups:
             sel = base if sel_by_e is None else \

@@ -60,7 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ... import sanitize
+from ... import obs, sanitize
 from ...core import hashing as H
 from ..sketch_update.fleet import (PARAM_COL_SEED, PARAM_MIT, PARAM_N_SUB,
                                    PARAM_SIGN_SEED, PARAM_SUB_SEED,
@@ -217,30 +217,40 @@ def fleet_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
     f32 ULPs of ``repro.core.query.fleet_query_window`` on the host copy
     of the same stack (exact-selection argument in the module doc).
     """
-    keys = np.asarray(keys, dtype=np.uint32)
-    n_keys = len(keys)
-    params, ns, widths = _prep_window_params(stack, params_by_epoch,
-                                             allow_row_pad=mesh is not None)
-    n_rows = params.shape[1]
-    if frag_sel is None:
-        frag_sel = np.ones(n_rows, bool)
-    frag_sel = np.asarray(frag_sel, bool)
-    sel2 = np.atleast_2d(frag_sel)
-    if not sel2.any(axis=1).all():
-        bad = np.flatnonzero(~sel2.any(axis=1))
-        raise ValueError(
-            "fleet_window_query_device: no on-path fragment selected "
-            f"(epoch offsets {bad.tolist()} of {len(params_by_epoch)}) — "
-            "an all-masked merge has no survivor and would poison the "
-            "window sum; drop these epochs (blind-epoch extrapolation) "
-            "or widen the selection")
-    if n_keys == 0:
-        return np.zeros(n_keys)
-    mit_rows = params[0, :, PARAM_MIT] != 0
-    mitigate = bool(single_hop) and bool(mit_rows.any())
-    kb = key_bucket(n_keys)
-    keys_pad = np.zeros(kb, np.uint32)
-    keys_pad[:n_keys] = keys
+    with obs.span("query.prep"):
+        keys = np.asarray(keys, dtype=np.uint32)
+        n_keys = len(keys)
+        params, ns, widths = _prep_window_params(
+            stack, params_by_epoch, allow_row_pad=mesh is not None)
+        n_rows = params.shape[1]
+        if frag_sel is None:
+            frag_sel = np.ones(n_rows, bool)
+        frag_sel = np.asarray(frag_sel, bool)
+        sel2 = np.atleast_2d(frag_sel)
+        if not sel2.any(axis=1).all():
+            bad = np.flatnonzero(~sel2.any(axis=1))
+            raise ValueError(
+                "fleet_window_query_device: no on-path fragment selected "
+                f"(epoch offsets {bad.tolist()} of {len(params_by_epoch)}) "
+                "— an all-masked merge has no survivor and would poison "
+                "the window sum; drop these epochs (blind-epoch "
+                "extrapolation) or widen the selection")
+        if n_keys == 0:
+            return np.zeros(n_keys)
+        mit_rows = params[0, :, PARAM_MIT] != 0
+        mitigate = bool(single_hop) and bool(mit_rows.any())
+        kb = key_bucket(n_keys)
+        keys_pad = np.zeros(kb, np.uint32)
+        keys_pad[:n_keys] = keys
+        if mesh is None:
+            host_args = (params[:, :, PARAM_COL_SEED].astype(np.uint32),
+                         params[:, :, PARAM_SIGN_SEED].astype(np.uint32),
+                         params[:, :, PARAM_SUB_SEED].astype(np.uint32),
+                         ns.astype(np.int32), widths.astype(np.int32),
+                         frag_sel, mit_rows, keys_pad)
+            # a device-resident stack crosses nothing
+            h2d = sum(a.nbytes for a in host_args) + (
+                stack.nbytes if isinstance(stack, np.ndarray) else 0)
     if mesh is not None:
         est = _sharded_window_query(mesh, stack, params, ns, widths, sel2,
                                     mit_rows, keys_pad, kind=kind,
@@ -253,18 +263,14 @@ def fleet_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
     # eager device-array slice would dispatch a dynamic_slice whose
     # start index is itself an implicit host->device transfer.
     with sanitize.transfer_guard():
-        out = _gather_merge(
-            jnp.asarray(stack),
-            jnp.asarray(params[:, :, PARAM_COL_SEED].astype(np.uint32)),
-            jnp.asarray(params[:, :, PARAM_SIGN_SEED].astype(np.uint32)),
-            jnp.asarray(params[:, :, PARAM_SUB_SEED].astype(np.uint32)),
-            jnp.asarray(ns.astype(np.int32)),
-            jnp.asarray(widths.astype(np.int32)),
-            jnp.asarray(frag_sel), jnp.asarray(mit_rows),
-            jnp.asarray(keys_pad), kind=kind, mitigate=mitigate)
+        with obs.span("query.launch", h2d_bytes=h2d, keys=n_keys):
+            out = _gather_merge(
+                jnp.asarray(stack), *(jnp.asarray(a) for a in host_args),
+                kind=kind, mitigate=mitigate)
         # KB floats across the boundary — the only counters-derived
         # bytes that ever leave the device on this path
-        est = jax.device_get(out)
+        with obs.span("query.sync"):
+            est = jax.device_get(out)
     return est[:n_keys].astype(np.float64)
 
 

@@ -13,6 +13,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.disketch import SwitchStream
 from ..runtime.fault_tolerance import HeartbeatMonitor
 from .traffic import Workload
@@ -329,14 +330,17 @@ class Replayer:
             frag_order = tuple(range(self.n_switches))
         frag_order = tuple(frag_order)
         key = (epoch, frag_order)
-        pkt = self._packets.get(key)
-        if pkt is None:
-            pkt = pack_streams(self._streams[epoch], frag_order)
-            self._packets[key] = pkt
-            while len(self._packets) > self.packet_cache:
-                self._packets.popitem(last=False)
-        else:
-            self._packets.move_to_end(key)
+        with obs.span("replay.epoch_packet") as sp:
+            pkt = self._packets.get(key)
+            hit = pkt is not None
+            if pkt is None:
+                pkt = pack_streams(self._streams[epoch], frag_order)
+                self._packets[key] = pkt
+                while len(self._packets) > self.packet_cache:
+                    self._packets.popitem(last=False)
+            else:
+                self._packets.move_to_end(key)
+            sp.set_metadata(hit=int(hit), packets=len(pkt.keys))
         return pkt
 
 
