@@ -448,10 +448,18 @@ class DiSketchSystem:
         On the fleet backend with ``merge="fragment"``, windows whose
         counter stacks are still device-resident (processed via
         ``run_window`` and not yet materialized) are answered by the
-        on-device query plane — only the per-path ``(K,)`` estimate
-        vectors cross the host boundary.  Everything else (the default
-        subepoch merge, loop backend, materialized windows) goes through
-        the per-record composite query over ``self.records``.
+        on-device query plane — only the ``(K,)`` estimates cross the
+        host boundary.  All paths of the request go in one batched
+        gather/merge per resident stack and key chunk, each key merged
+        over its own path's rows (``FleetEpochRunner.window_query_paths``);
+        under a device mesh, or when churn masking touches a queried
+        epoch, each path takes its own ``window_query`` call.  Everything
+        else (the default subepoch merge, loop backend, materialized
+        windows) goes through the per-record composite query over
+        ``self.records``.  The ``query.flows`` span counts the device
+        launches (``device_calls``), the keys the batched call answered
+        (``batched_keys``) and the paths sent one by one to the device
+        (``fallback_paths``).
 
         UnivMon frequency estimates come from level 0 (the level that
         sees the full stream) on both planes; §4.4 mitigation's
@@ -478,23 +486,34 @@ class DiSketchSystem:
                       keys=len(keys)) as sp:
             self.last_observability = self.observability(epochs)
             keys = np.asarray(keys, dtype=np.uint32)
-            out = np.zeros(len(keys))
-            by_path: Dict[Tuple[int, ...], List[int]] = {}
-            for i, p in enumerate(paths):
-                by_path.setdefault(tuple(p), []).append(i)
-            sp.set_metadata(paths=len(by_path))
+            ids: Dict[Tuple[int, ...], int] = {}
+            path_id = np.fromiter(
+                (ids.setdefault(tuple(p), len(ids)) for p in paths),
+                np.int32, len(keys))
+            uniq = list(ids)
+            sp.set_metadata(paths=len(uniq))
             device_ok = (merge == "fragment" and self.fleet is not None
                          and self.fleet.has_device_window(epochs))
-            if failures == "recover" and self.fleet is not None and not device_ok:
-                # the device path recovers inside window_query; the record
-                # path needs the stacks patched before materialization
+            if failures == "recover" and self.fleet is not None:
+                # patch the stacks before either plane reads them
                 self.fleet.recover(epochs)
                 failures = "mask"
+            launches = self.fleet.query_launches if self.fleet else 0
+            if device_ok and self.fleet.batches_paths(epochs, failures):
+                out = self.fleet.window_query_paths(epochs, keys, uniq,
+                                                    path_id, level=0)
+                sp.set_metadata(
+                    device_calls=self.fleet.query_launches - launches,
+                    batched_keys=len(keys), fallback_paths=0)
+                return out
+            out = np.zeros(len(keys))
             # um frequency estimates come from level 0 (the full-stream
             # level); the record plane needs level=None for non-um kinds.
             level = 0 if self.kind == "um" else None
-            for path, idxs in by_path.items():
-                idxs = np.asarray(idxs)
+            order = np.argsort(path_id, kind="stable")
+            bounds = np.searchsorted(path_id[order], np.arange(len(uniq) + 1))
+            for j, path in enumerate(uniq):
+                idxs = order[bounds[j]:bounds[j + 1]]
                 if device_ok:
                     out[idxs] = self.fleet.window_query(
                         epochs, keys[idxs], path=path, level=0,
@@ -515,6 +534,10 @@ class DiSketchSystem:
                 out[idxs] = query.query_window(
                     recs, keys[idxs], self.kind,
                     single_hop=sh, level=level, merge=merge) * scale
+            sp.set_metadata(
+                device_calls=(self.fleet.query_launches - launches
+                              if self.fleet else 0),
+                batched_keys=0, fallback_paths=len(uniq) if device_ok else 0)
             return out
 
     def query_entropy(self, keys: np.ndarray,

@@ -740,6 +740,8 @@ class FleetEpochRunner:
         # the queried epochs had a live on-path fragment, and the
         # blind-epoch extrapolation scale that was applied.
         self.last_observability: Optional[Dict] = None
+        # Device gather/merge launches of every window query so far.
+        self.query_launches = 0
 
     # Exactness bound.  Counters are f32 accumulations: exact while
     # every intermediate magnitude stays below 2^24.  For unsigned (cms)
@@ -1503,6 +1505,8 @@ class FleetEpochRunner:
                                                           failures)
             device_groups, host_epochs = self._route_epochs(epochs)
         out = np.zeros(len(keys))
+        if len(keys):
+            self.query_launches += len(device_groups)
         for stack, es in device_groups:
             sel = base if sel_by_e is None else \
                 np.stack([sel_by_e[e] for e in es])
@@ -1518,6 +1522,52 @@ class FleetEpochRunner:
                 None, keys, self.kind, frag_sel=sel,
                 single_hop=single_hop)
         return out * scale if scale != 1.0 else out
+
+    def batches_paths(self, epochs: Sequence[int], failures: str) -> bool:
+        """True when ``window_query_paths`` can answer a multi-path
+        request over ``epochs``: every epoch device-resident, no mesh,
+        and no churn mask touching a queried epoch (``failures`` other
+        than ``"oblivious"`` and a liveness entry for one of them)."""
+        return (self.mesh is None and self.has_device_window(epochs)
+                and (failures == "oblivious"
+                     or not any(e in self._row_live for e in epochs)))
+
+    def window_query_paths(self, epochs: Sequence[int], keys: np.ndarray,
+                           paths: Sequence[Sequence[int]],
+                           path_id: np.ndarray,
+                           level: int = 0) -> np.ndarray:
+        """``window_query`` of keys on many paths in one batched call per
+        resident stack and key chunk (``sketch_query.
+        fleet_window_query_paths``): key ``i`` is merged over the rows of
+        ``paths[path_id[i]]`` at UnivMon ``level``, with the §4.4 average
+        where its path is single-hop.  Bit-identical to one
+        ``window_query(path=p, single_hop=len(p) == 1)`` per path; only
+        for requests ``batches_paths`` admits."""
+        from ..kernels.sketch_query import (fleet_window_query_paths,
+                                            key_chunk)
+
+        with obs.span("query.prep"):
+            keys = np.asarray(keys, np.uint32)
+            on_path = [sorted({self._frag_pos[sw] for sw in p
+                               if sw in self._frag_pos}) for p in paths]
+            path_rows = np.full((len(paths), max(map(len, on_path),
+                                                 default=1)), -1, np.int32)
+            for j, frags in enumerate(on_path):
+                path_rows[j, :len(frags)] = np.asarray(
+                    frags, np.int32) * self.n_levels + level
+            n = len(list(epochs))
+            self.last_observability = {"epochs": n, "observable_epochs": n,
+                                       "scale": 1.0}
+            device_groups, host_epochs = self._route_epochs(epochs)
+            assert not host_epochs, "window_query_paths needs resident epochs"
+        if len(keys):
+            self.query_launches += len(device_groups) * -(
+                -len(keys) // key_chunk(len(keys)))
+        return fleet_window_query_paths(
+            [(stack, [self._params_log[e] for e in es])
+             for stack, es in device_groups],
+            keys, path_rows, path_id, self.kind,
+            single_hop=np.array([len(p) == 1 for p in paths], bool))
 
     def um_level_window_query(self, epochs: Sequence[int],
                               keys: np.ndarray,

@@ -23,6 +23,14 @@ This module is the TPU twin: one jitted fused pass that
      the merge to the queried flows' on-path fragments, §4.3 Step 1);
   4. sums the per-epoch estimates over the window (O_Q = Sum(O)).
 
+A request whose keys lie on many paths goes through
+``fleet_window_query_paths`` instead: one launch per resident stack and
+key chunk for every path at once.  The §4.3 Step-1 selection is then per
+key, as row indices (``path_rows[path_id]``), so the gather reads only
+each key's L on-path rows — ``(E, L, K)`` counters, not the ``(E, R, K)``
+a mask would keep — and the merge runs over those L slots with the key's
+own on-path count.
+
 Only the key batch and the small per-epoch seed tables cross *into* the
 device, and only the ``(K,)`` estimate vector crosses *back* — the
 counter stack never moves.  A hand-written Pallas kernel buys nothing
@@ -39,7 +47,9 @@ midpoint average and the final window sum accumulate f32 rounding —
 within a few ULPs (<< 1e-6 relative), which is the documented contract.
 
 Key batches are padded to power-of-two buckets so a replay's varying
-query sizes trigger O(log K) compiles instead of one per batch size.
+query sizes trigger O(log K) compiles instead of one per batch size; the
+multi-path entry pads larger requests to whole chunks of ``KEY_CHUNK``
+keys, so one compiled shape serves them all.
 
 UnivMon rides the same engine: the window stack's rows are virtual
 (fragment, level) pairs whose per-level mixed seeds were baked into the
@@ -70,39 +80,54 @@ from ..sketch_update.fleet import (PARAM_COL_SEED, PARAM_MIT, PARAM_N_SUB,
 #: power of two — O(log K) compiled variants across a replay).
 KEY_BUCKET_MIN = 8
 
+#: Keys per launch of a multi-path request (``fleet_window_query_paths``):
+#: larger requests are padded to whole chunks, so one compiled shape
+#: serves every large request.
+KEY_CHUNK = 1 << 15
+
 
 def key_bucket(n_keys: int) -> int:
     """Power-of-two key-batch bucket, floored at ``KEY_BUCKET_MIN``."""
     return max(KEY_BUCKET_MIN, 1 << max(int(n_keys) - 1, 0).bit_length())
 
 
-def _gather_raw(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
-                mit_rows, keys, *, signed: bool, mitigate: bool):
-    """Shared gather: (E, R, S, W) stack + (K,) keys -> (E, R, K) raw
-    per-row estimates (signed, §4.4-averaged, x n scaled)."""
-    e_count, n_rows = stack.shape[:2]
+def _raw_at(stack, rows, col_seeds, sign_seeds, sub_seeds, ns, widths,
+            use, keys, *, signed: bool):
+    """The gather at given rows: ``rows`` and every per-row argument
+    broadcast to ``(E, X, K)`` (X fleet rows, or a key's path slots);
+    ``use`` is None (no §4.4 average) or the bool mask of the elements
+    that take it.  Returns the (E, X, K) raw estimates (signed,
+    §4.4-averaged, x n scaled)."""
     k = keys[None, None, :]                               # (1, 1, K)
-    col = H.hash_mod(k, col_seeds[:, :, None], widths[None, :, None],
-                     xp=jnp)                              # (E, R, K)
-    sub = H.hash_pow2(k, sub_seeds[:, :, None], ns[None, :, None], xp=jnp)
-    e_idx = jnp.arange(e_count)[:, None, None]
-    r_idx = jnp.arange(n_rows)[None, :, None]
-    raw = stack[e_idx, r_idx, sub, col]                   # (E, R, K)
-    if mitigate:
+    col = H.hash_mod(k, col_seeds, widths, xp=jnp)        # (E, X, K)
+    sub = H.hash_pow2(k, sub_seeds, ns, xp=jnp)
+    e_idx = jnp.arange(stack.shape[0])[:, None, None]
+    raw = stack[e_idx, rows, sub, col]                    # (E, X, K)
+    if use is not None:
         # §4.4: single-hop flows carry a second subepoch record at
         # sub + n/2 on mitigation rows; average the two (counters are
         # exact f32 integers, so the /2 midpoint is within the same
         # rounding contract as the CS median midpoint).
-        sub2 = (sub + (ns[None, :, None] >> 1)) & (ns[None, :, None] - 1)
-        raw2 = stack[e_idx, r_idx, sub2, col]
-        use = (mit_rows & (ns >= 2))[None, :, None]
+        sub2 = (sub + (ns >> 1)) & (ns - 1)
+        raw2 = stack[e_idx, rows, sub2, col]
         raw = jnp.where(use, 0.5 * (raw + raw2), raw)
     if signed:
-        raw = raw * H.hash_sign(k, sign_seeds[:, :, None],
-                                xp=jnp).astype(jnp.float32)
+        raw = raw * H.hash_sign(k, sign_seeds, xp=jnp).astype(jnp.float32)
     # Proportional scaling to the epoch (x n, §1): n is a power of two,
     # so the product stays exact in f32.
-    return raw * ns[None, :, None].astype(jnp.float32)
+    return raw * ns.astype(jnp.float32)
+
+
+def _gather_raw(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
+                mit_rows, keys, *, signed: bool, mitigate: bool):
+    """Shared gather over every row: (E, R, S, W) stack + (K,) keys ->
+    (E, R, K) raw per-row estimates."""
+    ns = ns[None, :, None]
+    use = (mit_rows[None, :, None] & (ns >= 2)) if mitigate else None
+    return _raw_at(stack, jnp.arange(stack.shape[1])[None, :, None],
+                   col_seeds[:, :, None], sign_seeds[:, :, None],
+                   sub_seeds[:, :, None], ns, widths[None, :, None], use,
+                   keys, signed=signed)
 
 
 def _masked_merge(raw, frag_sel, *, kind: str):
@@ -272,6 +297,199 @@ def fleet_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
         with obs.span("query.sync"):
             est = jax.device_get(out)
     return est[:n_keys].astype(np.float64)
+
+
+def key_chunk(n_keys: int) -> int:
+    """Keys per launch of ``fleet_window_query_paths``: ``KEY_CHUNK``
+    for a request of more keys (padded to whole chunks), else the
+    request's pow2 ``key_bucket``."""
+    return min(KEY_CHUNK, key_bucket(n_keys))
+
+
+def _slot_merge(raw, valid, *, kind: str):
+    """§4.3 merge across each key's path slots (axis 1 of the (E, L, K)
+    raw estimates), ``valid`` the (L, K) mask of the slots that hold an
+    on-path row: min for CMS, else the median of the valid slots."""
+    masked = jnp.where(valid[None], raw, jnp.inf)
+    if kind == "cms":
+        return jnp.min(masked, axis=1)                    # (E, K)
+    # An odd-even transposition network of min/max sorts the L slots
+    # elementwise (exact selections; +inf-masked slots end on top), so
+    # ranks (m-1)//2 and m//2 are the two middle on-path values (m = the
+    # key's on-path row count), picked by a select per slot.
+    srt = [masked[:, slot] for slot in range(masked.shape[1])]
+    for rnd in range(len(srt)):
+        for i in range(rnd % 2, len(srt) - 1, 2):
+            srt[i], srt[i + 1] = (jnp.minimum(srt[i], srt[i + 1]),
+                                  jnp.maximum(srt[i], srt[i + 1]))
+    m = jnp.sum(valid, axis=0, dtype=jnp.int32)           # (K,)
+    lo_rank, hi_rank = (m - 1) // 2, m // 2
+    lo = hi = srt[0]
+    for slot in range(1, len(srt)):
+        lo = jnp.where(lo_rank == slot, srt[slot], lo)
+        hi = jnp.where(hi_rank == slot, srt[slot], hi)
+    return 0.5 * (lo + hi)
+
+
+@functools.partial(jax.jit, static_argnames=("kind", "mitigate"))
+def _gather_merge_rows(stack, row_tab, path_rows, path_hop, keys, path_id,
+                       *, kind: str, mitigate: bool):
+    """Fused device pass over each key's own on-path rows: (E, R, S, W)
+    stack + (K,) keys of many paths -> (K,) window estimates.
+
+    ``row_tab`` is the (R, 3E + 4) uint32 per-row table (``_row_table``);
+    ``path_rows`` the (P, L) int32 fleet rows of each path, -1 in unused
+    slots; ``path_hop`` the (P,) bool single-hop flag of each path;
+    ``path_id`` the (K,) int32 path of each key.  Each key's rows, seeds,
+    ``n`` and width come from one row gather of its path's (L, columns)
+    block, so the stack gather reads (E, L, K) counters, L the longest
+    path, never the (E, R, K) of every row.
+    """
+    sanitize.note_trace("sketch_query._gather_merge_rows")
+    e_count = stack.shape[0]
+    n_paths, n_slots = path_rows.shape
+    valid = path_rows >= 0
+    ptab = jnp.concatenate(
+        [row_tab[jnp.maximum(path_rows, 0)],              # (P, L, C)
+         valid[..., None].astype(jnp.uint32),
+         (valid & path_hop[:, None])[..., None].astype(jnp.uint32)],
+        axis=-1)
+    n_cols = ptab.shape[-1]
+    per_key = ptab.reshape(n_paths, n_slots * n_cols)[path_id]
+    cols = per_key.T.reshape(n_slots, n_cols, -1).transpose(1, 0, 2)
+    col_seeds, sign_seeds, sub_seeds = (
+        cols[i * e_count:(i + 1) * e_count] for i in range(3))  # (E, L, K)
+    ns, widths, mit, rows, valid, hop = (
+        cols[3 * e_count + i] for i in range(6))          # (L, K) each
+    ns = ns.astype(jnp.int32)[None]
+    use = ((mit != 0) & (hop != 0))[None] & (ns >= 2) if mitigate else None
+    raw = _raw_at(stack, rows.astype(jnp.int32)[None], col_seeds,
+                  sign_seeds, sub_seeds, ns, widths.astype(jnp.int32)[None],
+                  use, keys, signed=kind in ("cs", "um"))
+    return _slot_merge(raw, valid != 0, kind=kind).sum(axis=0)  # (K,)
+
+
+def _row_table(params, ns, widths) -> np.ndarray:
+    """(R, 3E + 4) uint32 table of one window stack's rows: the E column,
+    sign and subepoch seeds, then ``n``, width, the §4.4 mitigation flag
+    and the row's own index."""
+    e_count, n_rows = params.shape[:2]
+    per_epoch = [params[:, :, c].T for c in (PARAM_COL_SEED, PARAM_SIGN_SEED,
+                                             PARAM_SUB_SEED)]
+    per_row = [ns, widths, params[0, :, PARAM_MIT] != 0, np.arange(n_rows)]
+    return np.concatenate(per_epoch + [np.stack(per_row, axis=1)],
+                          axis=1).astype(np.uint32)
+
+
+def fleet_window_query_paths(stacks, keys: np.ndarray,
+                             path_rows: np.ndarray, path_id: np.ndarray,
+                             kind: str,
+                             single_hop: Optional[np.ndarray] = None,
+                             ) -> np.ndarray:
+    """Window point-query of keys on many paths at once: one gather/merge
+    launch per resident stack and key chunk, each key merged over its
+    own path's rows only (§4.3 Step 1 as per-key row indices).
+
+    Args:
+      stacks: the request's ``(stack, params_by_epoch)`` pairs, as
+        ``fleet_window_query_device`` takes them one at a time; the
+        estimates are summed over them in this order.
+      keys: (K,) uint32 key batch.
+      path_rows: (P, L) int fleet rows of each path (fragments, or a
+        UnivMon level's rows), -1 in a path's unused slots.  Every path
+        needs at least one row — raises ``ValueError`` otherwise.
+      path_id: (K,) int index into ``path_rows`` of each key's path.
+      kind: "cs" | "cms" | "um".
+      single_hop: optional (P,) bool: the §4.4 second-subepoch average
+        applies to that path's keys on PARAM_MIT rows.
+
+    Keys are padded to whole launches of ``key_chunk(K)`` keys and paths
+    to ``key_bucket(P)``; keys, paths and the path table cross to the
+    device once and serve every stack.  Returns the (K,) float64 window
+    estimates, bit-identical to one ``fleet_window_query_device`` call
+    per path with that path's row selection.
+    """
+    with obs.span("query.prep"):
+        keys = np.asarray(keys, dtype=np.uint32)
+        n_keys = len(keys)
+        path_rows = np.asarray(path_rows, np.int32)
+        n_paths, n_slots = path_rows.shape
+        hop = (np.zeros(n_paths, bool) if single_hop is None
+               else np.asarray(single_hop, bool))
+        path_id = np.asarray(path_id)
+        if len(path_id) != n_keys or (
+                n_keys and not 0 <= path_id.min() <= path_id.max() < n_paths):
+            raise ValueError(
+                f"fleet_window_query_paths: path_id must give each of the "
+                f"{n_keys} keys one of the {n_paths} paths")
+        empty = ~(path_rows >= 0).any(axis=1)
+        if empty.any():
+            raise ValueError(
+                "fleet_window_query_paths: no on-path fragment selected "
+                f"for paths {np.flatnonzero(empty).tolist()} — an "
+                "all-masked merge has no survivor and would poison the "
+                "window sum")
+        if n_keys == 0:
+            return np.zeros(0)
+        chunk = key_chunk(n_keys)
+        n_chunks = -(-n_keys // chunk)
+        keys_pad = np.zeros(n_chunks * chunk, np.uint32)
+        keys_pad[:n_keys] = keys
+        pid_pad = np.zeros(n_chunks * chunk, np.int32)
+        pid_pad[:n_keys] = path_id
+        pb = key_bucket(n_paths)
+        rows_pad = np.full((pb, n_slots), -1, np.int32)
+        rows_pad[:n_paths] = path_rows
+        hop_pad = np.zeros(pb, bool)
+        hop_pad[:n_paths] = hop
+        plans = []
+        for stack, params_by_epoch in stacks:
+            params, ns, widths = _prep_window_params(stack, params_by_epoch)
+            if path_rows.max() >= params.shape[1]:
+                raise ValueError(
+                    f"fleet_window_query_paths: path rows exceed the "
+                    f"stack's {params.shape[1]} rows")
+            mit_rows = params[0, :, PARAM_MIT] != 0
+            plans.append((stack, _row_table(params, ns, widths),
+                          bool(hop.any()) and bool(mit_rows.any())))
+    outs = []
+    keys_dev: list = [None] * n_chunks
+    pid_dev: list = [None] * n_chunks
+    paths_dev = None
+    # Explicit crossings only (jnp.asarray in, one jax.device_get out),
+    # as in fleet_window_query_device.
+    with sanitize.transfer_guard():
+        for stack, row_tab, mitigate in plans:
+            tab_dev = None
+            for c in range(n_chunks):
+                real = min(chunk, n_keys - c * chunk)
+                with obs.span("query.launch", keys=real,
+                              paths=n_paths) as sp:
+                    sent = [stack] if isinstance(stack, np.ndarray) else []
+                    if paths_dev is None:
+                        sent += [rows_pad, hop_pad]
+                        paths_dev = (jnp.asarray(rows_pad),
+                                     jnp.asarray(hop_pad))
+                    if tab_dev is None:
+                        sent.append(row_tab)
+                        tab_dev = jnp.asarray(row_tab)
+                    if keys_dev[c] is None:
+                        part = slice(c * chunk, (c + 1) * chunk)
+                        sent += [keys_pad[part], pid_pad[part]]
+                        keys_dev[c] = jnp.asarray(keys_pad[part])
+                        pid_dev[c] = jnp.asarray(pid_pad[part])
+                    outs.append(_gather_merge_rows(
+                        jnp.asarray(stack), tab_dev, *paths_dev,
+                        keys_dev[c], pid_dev[c], kind=kind,
+                        mitigate=mitigate))
+                    sp.set_metadata(h2d_bytes=sum(a.nbytes for a in sent))
+        with obs.span("query.sync"):
+            ests = jax.device_get(outs)
+    out = np.zeros(n_keys)
+    for g in range(len(plans)):
+        out += np.concatenate(
+            ests[g * n_chunks:(g + 1) * n_chunks])[:n_keys].astype(np.float64)
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("n_levels",))

@@ -40,13 +40,19 @@ span                       what it covers (counters)
                            peak, the PEB vector)
 ``fleet.records``          the window's per-epoch records and PEBs
 ``query.flows``            one ``query_flows`` call (``request``, the
-                           system's sequence number; ``keys``; ``paths``)
+                           system's sequence number; ``keys``; ``paths``;
+                           ``device_calls``, its device launches;
+                           ``batched_keys``, the keys one batched call
+                           answered; ``fallback_paths``, the paths sent
+                           one by one to the device)
 ``query.prep``             host work before a device query call: row
                            selection, liveness, routing, parameters, key
                            padding
 ``query.launch``           transfers and enqueue of one gather/merge
-                           (``h2d_bytes``: host arrays only; ``keys``)
-``query.sync``             the blocking read of its estimates
+                           (``h2d_bytes``: host arrays only; ``keys``;
+                           ``paths`` where it serves many)
+``query.sync``             the blocking read of its estimates (of every
+                           launch of a batched request at once)
 =========================  ==============================================
 """
 from __future__ import annotations

@@ -153,27 +153,89 @@ def test_system_init_span(traced):
 
 def test_query_spans(traced):
     spans = traced["spans"]
-    flows, = _named(spans, "repro.query.flows")
-    assert flows["stats"] == {"request": 1, "keys": traced["n_keys"],
-                              "paths": traced["paths"]}
     stacks = N_EPOCHS // WINDOW
+    n_keys, n_paths = traced["n_keys"], traced["paths"]
+    flows, = _named(spans, "repro.query.flows")
+    # one batched launch per resident window stack (one key chunk)
+    assert flows["stats"] == {"request": 1, "keys": n_keys,
+                              "paths": n_paths, "device_calls": stacks,
+                              "batched_keys": n_keys, "fallback_paths": 0}
     launches = _named(spans, "repro.query.launch")
-    # one device call per path and resident window stack
-    assert len(launches) == traced["paths"] * stacks
-    assert len(_named(spans, "repro.query.sync")) == len(launches)
-    # window_query's routing, then the call's own preparation
-    assert len(_named(spans, "repro.query.prep")) == \
-        traced["paths"] * (1 + stacks)
+    assert len(launches) == stacks
+    # the estimates of every launch come back in one read
+    assert len(_named(spans, "repro.query.sync")) == 1
+    # window_query_paths' routing, then the engine's preparation
+    assert len(_named(spans, "repro.query.prep")) == 2
     # every key is asked once of every stack
-    assert sum(s["stats"]["keys"] for s in launches) == \
-        stacks * traced["n_keys"]
-    # resident stacks cross nothing: the bytes are the padded keys and
-    # the (E, R) seeds and (R,) rows of each call
-    for s in launches:
-        kb = max(8, 1 << (s["stats"]["keys"] - 1).bit_length())
-        assert s["stats"]["h2d_bytes"] == (4 * kb + 3 * 4 * WINDOW * N_HOPS
-                                           + 2 * 4 * N_HOPS + 2 * N_HOPS)
+    assert [s["stats"]["keys"] for s in launches] == [n_keys] * stacks
+    assert all(s["stats"]["paths"] == n_paths for s in launches)
+    # resident stacks cross nothing: the first launch sends the padded
+    # path table, keys and key paths; each stack its (R, 3E + 4) rows
+    kb = max(8, 1 << (n_keys - 1).bit_length())
+    pb = max(8, 1 << (n_paths - 1).bit_length())
+    n_slots = N_HOPS                      # the longest path: every hop
+    row_tab = 4 * N_HOPS * (3 * WINDOW + 4)
+    assert [s["stats"]["h2d_bytes"] for s in launches] == (
+        [pb * (4 * n_slots + 1) + row_tab + 2 * 4 * kb]
+        + [row_tab] * (stacks - 1))
     assert np.isfinite(traced["est"]).all()
+
+
+def test_request_route_counters(tmp_path):
+    """A 192-path request over 4 resident stacks: one batched launch per
+    stack and key chunk, every key batched; the same request on a
+    churned window sends each path to its own calls."""
+    import itertools
+    from types import SimpleNamespace
+
+    import jax
+
+    from repro.core.disketch import SwitchStream
+    from repro.kernels.sketch_query import KEY_CHUNK
+
+    n_sw, n_epochs, window = 6, 8, 2
+
+    def streams(e):
+        out = {}
+        for sw in range(n_sw):
+            r = np.random.default_rng(100 * e + sw)
+            n = 150 + 40 * sw
+            out[sw] = SwitchStream(r.integers(0, 500, n).astype(np.uint32),
+                                   r.integers(1, 5, n).astype(np.int64),
+                                   r.integers(0, 1 << 12, n).astype(np.int64))
+        return out
+
+    def system(fail_at=None):
+        s = DiSketchSystem({sw: 2048 for sw in range(n_sw)}, "cs",
+                           rho_target=2.0, log2_te=12, backend="fleet",
+                           fleet_kwargs=FLEET_KW)
+        for e0 in range(0, n_epochs, window):
+            ev = None
+            if e0 == fail_at:
+                ev = [(), [SimpleNamespace(kind="fail", switch=2,
+                                           factor=1.0)]]
+            s.run_window(e0, [streams(e) for e in range(e0, e0 + window)],
+                         events_by_epoch=ev)
+        return s
+
+    all_paths = [p for n in (3, 4) for p in
+                 itertools.permutations(range(n_sw), n)][:192]
+    n_keys = KEY_CHUNK + 1
+    keys = np.arange(n_keys, dtype=np.uint32) * np.uint32(40503)
+    paths = [all_paths[i % 192] for i in range(n_keys)]
+    epochs = list(range(n_epochs))
+    clean, churned = system(), system(fail_at=4)
+    with jax.profiler.trace(str(tmp_path)):
+        clean.query_flows(keys, paths, epochs, merge="fragment")
+        churned.query_flows(keys[:192], paths[:192], epochs,
+                            merge="fragment")
+    flows = [s["stats"] for s in _named(_spans(str(tmp_path)),
+                                        "repro.query.flows")]
+    assert flows == [
+        {"request": 1, "keys": n_keys, "paths": 192, "device_calls": 4 * 2,
+         "batched_keys": n_keys, "fallback_paths": 0},
+        {"request": 1, "keys": 192, "paths": 192, "device_calls": 192 * 4,
+         "batched_keys": 0, "fallback_paths": 192}]
 
 
 def test_span_outside_profiler_is_a_no_op():

@@ -9,14 +9,17 @@ without §4.3 path restriction) matches the numpy oracles
 ``query.query_window(merge="fragment")`` on the unpacked records) within
 1e-6 relative on integer-exact counters.
 """
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
 from repro.core import query as Q
 from repro.core.disketch import DiSketchSystem
-from repro.kernels.sketch_query import (KEY_BUCKET_MIN,
+from repro.kernels.sketch_query import (KEY_BUCKET_MIN, KEY_CHUNK,
                                         fleet_window_query_device,
-                                        key_bucket)
+                                        fleet_window_query_paths,
+                                        key_bucket, key_chunk)
 from repro.kernels.sketch_update import fleet as FK
 from repro.net.simulator import Replayer
 from repro.net.traffic import cov_list, linear_path_workload
@@ -254,3 +257,145 @@ def test_engine_masked_merge_matches_numpy(kind):
     with pytest.raises(ValueError, match="fragment"):
         fleet_window_query_device(stack, list(params), keys, kind,
                                   frag_sel=np.zeros(n_frags, bool))
+
+
+# --- one batched launch per stack for every path of a request ----------
+
+#: Paths of every length 1-5 over the 5-hop fleet, in no row order,
+#: three of them single-hop (the §4.4 average on mitigation rows).
+MIXED_PATHS = [(0, 1, 2, 3, 4), (1,), (2, 3), (0, 2, 4), (3,), (4, 1, 0),
+               (1, 2, 3, 4), (4,)]
+
+
+@pytest.fixture(scope="module", params=["cs", "cms", "um"])
+def three_stacks(request):
+    """A mitigating fleet whose 6 epochs sit in 3 resident stacks."""
+    wl, rep, mems = _small_workload(n_epochs=6)
+    sysw = DiSketchSystem(mems, request.param, rho_target=4.0,
+                          log2_te=wl.log2_te, backend="fleet",
+                          mitigation=True, fleet_kwargs=FLEET_KW)
+    rep.run(sysw, window=2)
+    params = np.stack([sysw.fleet._params_log[e] for e in range(6)])
+    assert (params[..., FK.PARAM_MIT] != 0).all()
+    assert (params[..., FK.PARAM_N_SUB] >= 2).any()
+    return sysw
+
+
+def _per_path(sysw, keys, paths, epochs):
+    """The per-path loop: one window_query per distinct path."""
+    out = np.zeros(len(keys))
+    for p in set(paths):
+        idx = np.array([i for i, q in enumerate(paths) if q == p])
+        out[idx] = sysw.fleet.window_query(epochs, keys[idx], path=p,
+                                           single_hop=len(p) == 1)
+    return out
+
+
+@pytest.mark.parametrize("n_keys", [0, 1, 37, KEY_CHUNK, KEY_CHUNK + 1])
+def test_batched_paths_match_per_path_loop(three_stacks, n_keys):
+    """query_flows answers every path of a request in one batched launch
+    per resident stack and key chunk, bit-identical to the per-path
+    loop: cs, cms and um, paths of 1-5 hops, single-hop keys on §4.4
+    rows, 0 / 1 / a few keys, one chunk and a chunk plus one."""
+    sysw = three_stacks
+    epochs = list(range(6))
+    keys = (np.arange(n_keys, dtype=np.uint32) * np.uint32(2654435761)
+            ) ^ np.uint32(0x9E3779B9)
+    rng = np.random.RandomState(n_keys)
+    paths = [MIXED_PATHS[i]
+             for i in rng.randint(0, len(MIXED_PATHS), n_keys)]
+    calls = sysw.fleet.query_launches
+    got = sysw.query_flows(keys, paths, epochs, merge="fragment")
+    chunks = -(-n_keys // key_chunk(n_keys))
+    assert sysw.fleet.query_launches - calls == 3 * chunks
+    assert got.shape == (n_keys,)
+    assert np.array_equal(got, _per_path(sysw, keys, paths, epochs))
+    assert sysw.fleet._window_bufs[0][0]._host is None
+
+
+def test_key_chunks():
+    assert key_chunk(0) == key_chunk(1) == KEY_BUCKET_MIN
+    assert key_chunk(KEY_CHUNK - 1) == key_chunk(KEY_CHUNK) == KEY_CHUNK
+    assert key_chunk(10 * KEY_CHUNK + 1) == KEY_CHUNK
+
+
+@pytest.mark.parametrize("kind", ["cs", "cms"])
+def test_engine_paths_match_per_path_calls(kind):
+    """Unit-level: fleet_window_query_paths over two synthetic stacks ==
+    the sum of one fleet_window_query_device call per path and stack,
+    bit for bit — unequal path lengths in unsorted slots, the §4.4
+    average on the single-hop paths' mitigation rows only; a path with
+    no row raises."""
+    rng = np.random.RandomState(11)
+    e_count, n_rows, n_sub, width = 3, 6, 4, 96
+    stacks = []
+    for g in range(2):
+        stack = rng.randint(0, 300, (e_count, n_rows, n_sub, width)
+                            ).astype(np.float32)
+        params = np.zeros((e_count, n_rows, FK.N_PARAMS), np.int32)
+        for e in range(e_count):
+            params[e, :, FK.PARAM_COL_SEED] = 11 + 31 * (e + 3 * g) + np.arange(n_rows)
+            params[e, :, FK.PARAM_SIGN_SEED] = -22 - 31 * e + np.arange(n_rows)
+            params[e, :, FK.PARAM_SUB_SEED] = 33 + 31 * e + np.arange(n_rows)
+        params[:, :, FK.PARAM_WIDTH] = width
+        params[:, :, FK.PARAM_N_SUB] = [1, 2, 4, 4, 2, 4]
+        params[:, :, FK.PARAM_MIT] = [1, 0, 1, 1, 0, 1]
+        stacks.append((stack, list(params)))
+    paths = [[5, 0, 3], [2], [1], [4, 1], [0, 1, 2, 3, 4, 5], [3, 2]]
+    path_rows = np.full((len(paths), 6), -1, np.int32)
+    for j, p in enumerate(paths):
+        path_rows[j, :len(p)] = p
+    hop = np.array([len(p) == 1 for p in paths])
+    keys = rng.randint(0, 1 << 30, 61).astype(np.uint32)
+    path_id = rng.randint(0, len(paths), len(keys))
+    got = fleet_window_query_paths(stacks, keys, path_rows, path_id, kind,
+                                   single_hop=hop)
+    want = np.zeros(len(keys))
+    for j, p in enumerate(paths):
+        idx = np.flatnonzero(path_id == j)
+        sel = np.isin(np.arange(n_rows), p)
+        for stack, params in stacks:
+            want[idx] += fleet_window_query_device(
+                stack, params, keys[idx], kind, frag_sel=sel,
+                single_hop=bool(hop[j]))
+    assert np.array_equal(got, want)
+    with pytest.raises(ValueError, match="path_id"):
+        fleet_window_query_paths(stacks, keys, path_rows,
+                                 path_id + len(paths), kind)
+    path_rows[3] = -1
+    with pytest.raises(ValueError, match="fragment"):
+        fleet_window_query_paths(stacks, keys, path_rows, path_id, kind)
+
+
+def test_churned_request_falls_back_per_path():
+    """Churn masking that touches a queried epoch sends each path to its
+    own window_query (liveness masks and blind-epoch scaling there);
+    the answers still equal the per-path loop's, and an untouched
+    window of the same fleet is batched again."""
+    wl, rep, mems = _small_workload(n_epochs=4)
+    sysw = DiSketchSystem(mems, "cs", rho_target=4.0, log2_te=wl.log2_te,
+                          backend="fleet", fleet_kwargs=FLEET_KW)
+    for e0 in (0, 2):
+        ev = [(), [SimpleNamespace(kind="fail", switch=2, factor=1.0)]] \
+            if e0 == 2 else None
+        sysw.run_window(e0, [rep.epoch_stream(e) for e in (e0, e0 + 1)],
+                        events_by_epoch=ev)
+    keys = wl.keys[:90]
+    paths = [MIXED_PATHS[i % len(MIXED_PATHS)] for i in range(len(keys))]
+    churned = [0, 1, 2, 3]
+    assert not sysw.fleet.batches_paths(churned, "mask")
+    assert sysw.fleet.batches_paths(churned, "oblivious")
+    assert sysw.fleet.batches_paths([0, 1], "mask")
+    # switch 2 is masked at epochs 2-3; (2, 3) keeps switch 3 there
+    got = sysw.query_flows(keys, paths, churned, merge="fragment")
+    assert np.array_equal(got, _per_path(sysw, keys, paths, churned))
+    for eps in ([0, 1], churned):
+        a = sysw.query_flows(keys, paths, eps, merge="fragment",
+                             failures="oblivious")
+        b = np.zeros(len(keys))
+        for p in set(paths):
+            idx = np.array([i for i, q in enumerate(paths) if q == p])
+            b[idx] = sysw.fleet.window_query(eps, keys[idx], path=p,
+                                             single_hop=len(p) == 1,
+                                             failures="oblivious")
+        assert np.array_equal(a, b)

@@ -165,6 +165,27 @@ def test_query_merge_compiles(one_chip, kind, mitigate):
     assert compiled.memory_analysis() is not None
 
 
+@pytest.mark.parametrize("kind,mitigate", [("cs", False), ("cs", True),
+                                           ("cms", False), ("um", False)])
+def test_query_rows_merge_compiles(one_chip, kind, mitigate):
+    """The batched multi-path merge at the §6.1 query's shape: one
+    2^15-key chunk over 192 paths (bucket 256) of 5 on-path rows."""
+    from repro.kernels.sketch_query.engine import (KEY_CHUNK,
+                                                   _gather_merge_rows)
+
+    rows = F * 16 if kind == "um" else F
+    width = UM_WIDTH if kind == "um" else WIDE_WIDTH
+    compiled = _gather_merge_rows.lower(
+        _sds((E, rows, 2, width), jnp.float32, one_chip),
+        _sds((rows, 3 * E + 4), jnp.uint32, one_chip),
+        _sds((256, 5), jnp.int32, one_chip),
+        _sds((256,), jnp.bool_, one_chip),
+        _sds((KEY_CHUNK,), jnp.uint32, one_chip),
+        _sds((KEY_CHUNK,), jnp.int32, one_chip),
+        kind=kind, mitigate=mitigate).compile()
+    assert compiled.memory_analysis() is not None
+
+
 def test_um_query_merge_compiles(one_chip):
     from repro.kernels.sketch_query.engine import _gather_merge_um
 

@@ -37,6 +37,7 @@ KERNEL_BOUNDARY_FUNCS: Dict[str, Set[str]] = {
         # query entry points: host params in, (K,)-sized estimates out
         "_prep_window_params",
         "fleet_window_query_device",
+        "fleet_window_query_paths",
         "um_window_query_device",
         "um_gsum_device",
         # sharded twins: host params sharded in, only (K,) estimates
