@@ -195,6 +195,26 @@ def gate_failures(headline: dict, baseline: dict) -> list:
     return fails
 
 
+def _grouped_rect(params, packets, *, n_sub_max: int, width_max: int,
+                  **kw) -> np.ndarray:
+    """``dispatch_ragged_grouped``'s group launches laid into the
+    ``(rows, n_sub_max, width_max)`` rectangle the oracles return, for
+    comparison only (the engine keeps each group at its own extent)."""
+    from repro.core.fleet import dispatch_ragged_grouped
+
+    outs, layout, _, _ = dispatch_ragged_grouped(params, packets, **kw)
+    flat = np.concatenate([np.asarray(o).ravel() for o in outs])
+    e_count, n_rows = layout.base.shape
+    rect = np.zeros((e_count * n_rows, n_sub_max, width_max), np.float32)
+    for e in range(e_count):
+        for r in range(n_rows):
+            start, (_, n, stride) = layout.cell(e, r, r + 1)
+            w = int(layout.widths[r])
+            rect[e * n_rows + r, :n, :w] = \
+                flat[start:start + n * stride].reshape(n, stride)[:, :w]
+    return rect
+
+
 def _time_call(fn, budget_s: float = 0.25, batches: int = 3) -> float:
     """Steady-state seconds/call, robust to a noisy shared machine: warm
     up (compile), then take the *fastest* of ``batches`` fixed-budget
@@ -395,11 +415,14 @@ def run_fleet(quick: bool = True):
         if ok and (best is None or t < best[0]):
             best = (t, rows[-1])
     # the production path: ragged CSR grouped by n_sub
+    group_kw = dict(log2_te=16, signed=True)
     for blk in (1024, 2048):
         run_one = (lambda blk=blk: dispatch_ragged_grouped(
             params, [pkt], blk=blk, value_mode="f32", interpret="auto",
-            **kw))
-        ok = bool(np.array_equal(np.asarray(run_one()), out_loop))
+            **group_kw)[0])
+        ok = bool(np.array_equal(_grouped_rect(
+            params, [pkt], blk=blk, value_mode="f32", interpret="auto",
+            **kw), out_loop))
         t = _time_call(lambda: jax.block_until_ready(run_one()))
         rows.append({"bench": "fleet_tune", "layout": "ragged_grouped",
                      "blk": blk, "w_blk": 0, "value_mode": "f32",
@@ -498,7 +521,9 @@ def run_fleet_ragged(quick: bool = True):
             if grouped:
                 run_one = (lambda blk=blk: dispatch_ragged_grouped(
                     params, [pkt], blk=blk, interpret="auto",
-                    value_mode="f32", **kw))
+                    value_mode="f32", log2_te=16, signed=True)[0])
+                got = _grouped_rect(params, [pkt], blk=blk,
+                                    interpret="auto", value_mode="f32", **kw)
             else:
                 fk, fv, ft, bf = pack_csr([pkt], blk)
                 args = (jnp.asarray(fk), jnp.asarray(fv), jnp.asarray(ft),
@@ -507,7 +532,8 @@ def run_fleet_ragged(quick: bool = True):
                            FK.fleet_update_ragged(*args, blk=blk,
                                                   value_mode="f32",
                                                   interpret="auto", **kw))
-            ok = bool(np.array_equal(np.asarray(run_one()), out_dense))
+                got = np.asarray(run_one())
+            ok = bool(np.array_equal(got, out_dense))
             t = _time_call(lambda: jax.block_until_ready(run_one()))
             rows.append({"bench": "ragged_tune", "grouped": grouped,
                          "blk": blk, "ragged_matches_dense": ok,
@@ -712,9 +738,9 @@ def run_univmon_fleet(quick: bool = True):
     # loop, both on the pre-folded packet, neither paying host-side
     # record unpacking or the overflow sync.
     dispatch_kw = dict(n_levels=n_levels, value_mode="f32",
-                       interpret="auto", **kw)
+                       interpret="auto", log2_te=log2_te, signed=True)
     t_fleet = _time_call(lambda: jax.block_until_ready(
-        dispatch_ragged_grouped(params, [folded], **dispatch_kw)))
+        dispatch_ragged_grouped(params, [folded], **dispatch_kw)[0]))
     t_loop = _time_call(lambda: FK.fleet_update_loop(
         dense_keys, dense_vals, dense_ts, params, backend="pallas",
         interpret="auto", check_overflow=False, **kw))
@@ -725,8 +751,9 @@ def run_univmon_fleet(quick: bool = True):
     sysw.run_window(0, [streams] * 4, packets=[packet] * 4)
     epochs = [0, 1, 2, 3]
     params_w = [sysw.fleet._params_log[e] for e in epochs]
-    host = [sysw.fleet._window_bufs[0][0].host()[e] for e in epochs]
-    stack4 = np.stack(host).astype(np.float32)
+    buf = sysw.fleet._window_bufs[0][0]
+    stack4 = buf.view(epochs)               # the device buffer's cells
+    host = [buf.host_epoch(e) for e in epochs]
     rows, best = [], None
     for n_keys in ((1024, 4096) if quick else (1024, 4096, 16384)):
         keys = rng.randint(0, 1 << 20, n_keys).astype(np.uint32)

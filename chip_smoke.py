@@ -194,9 +194,7 @@ def check_served(kind, mems, rho, wl, rep) -> None:
     t_run = time.perf_counter() - t0
     fleet = system.fleet
     epochs = list(range(wl.n_epochs))
-    bufs = {id(fleet._window_bufs[e][0]): fleet._window_bufs[e][0]
-            for e in epochs}
-    stack_bytes = sum(int(np.prod(b._shape)) * 4 for b in bufs.values())
+    stack_bytes = fleet.stack_bytes
     _, keys, _, paths = full_path_queries(wl)
     t0 = time.perf_counter()
     est_dev = system.query_flows(keys, paths, epochs, merge="fragment")
@@ -271,6 +269,7 @@ def four_chips(seed: int, stats: CompileStats) -> None:
     from repro.launch.mesh import make_switch_mesh
     from repro.net.simulator import FailureSchedule, Replayer
     from repro.net.topology import FatTree
+    from repro.kernels.sketch_update import fleet as FK
     from repro.net.traffic import gen_workload
 
     require(len(jax.devices()) >= 4, "--chips 4 needs four devices")
@@ -303,32 +302,33 @@ def four_chips(seed: int, stats: CompileStats) -> None:
             system = build(mems, kind, rho, wl, "fleet", mesh=m,
                            fleet_kwargs={"parity_groups": groups})
             devices = []
+            launch = FK.fleet_update_ragged
             if m is not None:
-                dispatch = system.fleet._dispatch
-
-                def recorded(*a, _d=dispatch, _log=devices, **k):
-                    out = _d(*a, **k)
+                def recorded(*a, _l=launch, _log=devices, **k):
+                    out = _l(*a, **k)
                     _log.append(sorted(str(d) for d in out.devices()))
                     return out
 
-                system.fleet._dispatch = recorded
+                FK.fleet_update_ragged = recorded
             sched = FailureSchedule(topo.n_switches,
                                     downs={victim: (down_at, None)})
             t0 = time.perf_counter()
             rep.run(system, window=WINDOW, failures=sched)
             t_run = time.perf_counter() - t0
+            FK.fleet_update_ragged = launch
             if m is not None:
                 buf = system.fleet._window_bufs[0][0].device()
-                held = sorted((s.index[1].start or 0, str(s.device))
+                held = sorted((s.index[0].start or 0, str(s.device))
                               for s in buf.addressable_shards)
-                log(f"mesh[{kind}]: window-stack shards (first row, "
+                log(f"mesh[{kind}]: window-buffer blocks (first counter, "
                     f"device): {held}")
-                log(f"mesh[{kind}]: devices that ran the shard kernels of "
-                    f"window 0: {devices[:4]}")
+                log(f"mesh[{kind}]: devices that ran the group launches "
+                    f"of window 0: {devices[:8]}")
             recovered = system.fleet.recover()
             est = system.query_flows(keys, paths, epochs,
                                      merge="fragment", failures="recover")
-            stacks = [system.fleet._host_stack(e) for e in epochs]
+            stacks = [system.fleet.cell_counters(e, sw) for e in epochs
+                      for sw in system.fleet.frag_order]
             runs[name] = (system.n_log, recovered, est, stacks)
             log(f"mesh[{kind}/{name}]: Replayer.run(window={WINDOW}) "
                 f"{t_run:.1f} s, recovered cells "

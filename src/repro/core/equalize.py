@@ -8,8 +8,6 @@ mirroring the paper's ASIC/CPU split (Fig. 10).
 """
 from __future__ import annotations
 
-import functools
-
 import numpy as np
 
 from .fragment import EpochRecords
@@ -57,38 +55,24 @@ def peb_fleet(stacked: np.ndarray, ns: np.ndarray, widths: np.ndarray,
     return (row * live).sum(axis=1) / np.asarray(ns, np.float64)
 
 
-@functools.lru_cache(maxsize=None)
-def _peb_fleet_device_jit(kind: str):
-    import jax
+def peb_jnp(stacked, ns, widths, kind: str):
+    """jnp twin of ``peb_fleet``, traced inside the program that reads
+    each window's PEBs off its device-resident counters
+    (``core.fleet._join_groups``), so the window runner transfers only
+    the PEB vector, never the counters.  f32 accumulation differs from
+    the float64 host path by ~1e-7 relative — irrelevant to the
+    factor-of-two Eq. 6 control thresholds."""
     import jax.numpy as jnp
 
-    def peb(stacked, ns, widths):
-        c = stacked.astype(jnp.float32)
-        n_sub_max = c.shape[1]
-        w = widths.astype(jnp.float32)[:, None]
-        if kind in ("cs", "um"):
-            row = jnp.sqrt((c * c).sum(axis=-1) / w)
-        else:
-            row = jnp.abs(c).sum(axis=-1) / w
-        live = jnp.arange(n_sub_max)[None, :] < ns[:, None]
-        return (row * live).sum(axis=1) / ns.astype(jnp.float32)
-
-    return jax.jit(peb)
-
-
-def peb_fleet_device(stacked, ns, widths, kind: str):
-    """jnp twin of ``peb_fleet`` for device-resident (window) outputs.
-
-    Same Eq. 4/5 math, but computed where the stacked f32 counters
-    already live, so the epoch-window runner transfers only the
-    ``(n_rows,)`` PEB vector instead of the whole counter stack.  f32
-    accumulation differs from the float64 host path by ~1e-7 relative —
-    irrelevant to the factor-of-two Eq. 6 control thresholds.
-    """
-    import jax.numpy as jnp
-
-    return _peb_fleet_device_jit(kind)(stacked, jnp.asarray(ns),
-                                       jnp.asarray(widths))
+    c = stacked.astype(jnp.float32)
+    n_sub_max = c.shape[1]
+    w = widths.astype(jnp.float32)[:, None]
+    if kind in ("cs", "um"):
+        row = jnp.sqrt((c * c).sum(axis=-1) / w)
+    else:
+        row = jnp.abs(c).sum(axis=-1) / w
+    live = jnp.arange(n_sub_max)[None, :] < ns[:, None]
+    return (row * live).sum(axis=1) / ns.astype(jnp.float32)
 
 
 def next_n(n: int, peb: float, rho_target: float) -> int:

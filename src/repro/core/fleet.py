@@ -49,14 +49,17 @@ from collections.abc import Mapping
 from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import contextlib
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs
-from ..kernels.sketch_update.kernel import MIN_BLK, abs_peak
+from ..kernels.sketch_query import MAX_FLAT, FlatStack
+from ..kernels.sketch_update.kernel import MIN_BLK
 from . import equalize
 from .fragment import (EpochRecords, FragmentConfig, _ROLE_COL, _ROLE_SIGN,
                        _ROLE_SUB, frag_seed, level_seed_mix)
@@ -332,23 +335,86 @@ def build_params(fragments: Dict[int, FragmentConfig], epoch: int,
     return params
 
 
-@functools.partial(jax.jit, static_argnums=2)
-def _assemble_groups(outs, rows, shape):
-    """Place every n_sub group's launch into one zero window stack of
-    ``shape`` (group ``g`` fills rows ``rows[g]``, its own subepoch and
-    column extents): one compiled program per window, where eager
-    ``.at[].set`` compiles about ten small programs per group."""
-    out = jnp.zeros(shape, jnp.float32)
-    for r, o in zip(rows, outs):
-        out = out.at[r, :o.shape[1], :o.shape[2]].set(o)
-    return out
+@dataclass(frozen=True)
+class LaunchGroup:
+    """One subepoch group of a window's launches: its fragments (positions
+    among the fragments laid out), their common ``n`` and the group's
+    width (its widest fragment's)."""
+
+    frags: np.ndarray
+    n: int
+    width: int
+
+
+@dataclass(frozen=True)
+class WindowLayout:
+    """Where a window's counters lie in its flat f32 buffer.
+
+    Each subepoch group's launch keeps its own ``(rows, n, width)``
+    extent and the groups follow one another, so the counter of (epoch
+    ``e``, row ``r``, subepoch ``s``, column ``c``) is
+    ``flat[base[e, r] + s * stride[r] + c]``, with ``stride[r]`` the
+    width of the row's group.  The L level rows of one (epoch, fragment)
+    cell are consecutive.  A window's buffer is bounded by its groups'
+    rectangles, never by E·F·n_max·w_max; offsets fit int32, since a
+    buffer holds fewer than 2^31 counters (``MAX_FLAT``, checked by
+    ``window_layout``).
+    """
+
+    base: np.ndarray            # (E, R) int64 cell offsets
+    stride: np.ndarray          # (R,) int64 counters between subepochs
+    ns: np.ndarray              # (R,) int64 subepoch counts
+    widths: np.ndarray          # (R,) int64 columns
+    size: int                   # counters of the buffer
+    groups: Tuple[LaunchGroup, ...] = ()
+
+    @property
+    def counter_bytes(self) -> int:
+        """Bytes of the window's own counters: 4 · Σ n·width."""
+        return 4 * self.base.shape[0] * int((self.ns * self.widths).sum())
+
+    def cell(self, e_idx: int, row_lo: int, row_hi: int):
+        """Offset and ``(rows, n, stride)`` extent of rows
+        ``[row_lo, row_hi)`` of one epoch — the level rows of one
+        fragment, consecutive in the buffer."""
+        return (int(self.base[e_idx, row_lo]),
+                (row_hi - row_lo, int(self.ns[row_lo]),
+                 int(self.stride[row_lo])))
+
+
+def window_layout(ns_f: np.ndarray, widths_f: np.ndarray, e_count: int,
+                  n_levels: int = 1) -> WindowLayout:
+    """The layout of a window whose fragments (``ns_f``/``widths_f``, in
+    fleet order) are launched grouped by subepoch count: one launch per
+    distinct ``n``, in ascending ``n``, whose rows are (epoch, fragment,
+    level) in that order (``dispatch_ragged_grouped``)."""
+    L = n_levels
+    ns_f = np.asarray(ns_f, np.int64)
+    widths_f = np.asarray(widths_f, np.int64)
+    base = np.zeros((e_count, len(ns_f) * L), np.int64)
+    stride = np.zeros(len(ns_f) * L, np.int64)
+    groups, off = [], 0
+    for n in np.unique(ns_f):
+        frags = np.flatnonzero(ns_f == n)
+        w = int(widths_f[frags].max(initial=4))
+        rows = (frags[:, None] * L + np.arange(L)).ravel()
+        launch_row = np.arange(e_count * len(rows)).reshape(e_count, -1)
+        base[:, rows] = off + launch_row * (int(n) * w)
+        stride[rows] = w
+        groups.append(LaunchGroup(frags, int(n), w))
+        off += e_count * len(rows) * int(n) * w
+    if off > MAX_FLAT:
+        raise ValueError(f"a window buffer of {off} counters does not fit "
+                         "int32 offsets; use a shorter window")
+    return WindowLayout(base, stride, np.repeat(ns_f, L),
+                        np.repeat(widths_f, L), off, tuple(groups))
 
 
 def dispatch_ragged_grouped(params: np.ndarray,
-                            packets: Sequence[FleetPacket], *,
-                            n_sub_max: int, width_max: int, log2_te: int,
-                            signed: bool, blk: int = CSR_BLK,
-                            w_blk: Optional[int] = None,
+                            packets: Sequence[FleetPacket],
+                            layout: Optional[WindowLayout] = None, *,
+                            frag_lo: int = 0, log2_te: int, signed: bool,
+                            blk: int = CSR_BLK, w_blk: Optional[int] = None,
                             interpret="auto", value_mode: str = "auto",
                             n_levels: int = 1,
                             with_mitigation: bool = False):
@@ -369,17 +435,21 @@ def dispatch_ragged_grouped(params: np.ndarray,
     (``n_levels`` consecutive virtual level rows per fragment for
     UnivMon fleets), with the per-fragment ``n_sub``/``width`` columns
     identical across epochs and levels (``ns`` frozen — the
-    ``run_window`` contract).  Returns the stacked device-resident
-    ``(n_rows, n_sub_max, width_max)`` f32 counters, assembled from the
-    groups' launches by one program (``_assemble_groups``).
+    ``run_window`` contract).  ``layout`` (``window_layout``) lays out
+    the fragments launched — by default every fragment of ``params``; a
+    mesh shard's, at packet positions ``frag_lo + group.frags``.
+    Launches go to the default device.
+    Returns ``(outs, layout, h2d_bytes, packets)``: each group's
+    device-resident ``(rows, n, width)`` f32 counters in
+    ``layout.groups`` order, the layout, the bytes sent and the
+    packets launched.
     """
     from ..kernels.sketch_update import fleet as FK
 
     e_count = len(packets)
     n_frags = packets[0].n_frags
     L = n_levels
-    n_rows = params.shape[0]
-    assert n_rows == e_count * n_frags * L
+    assert params.shape[0] == e_count * n_frags * L
     nsub_f = params[:n_frags * L:L, FK.PARAM_N_SUB].astype(np.int64)
     width_f = params[:n_frags * L:L, FK.PARAM_WIDTH].astype(np.int64)
     assert (params[:, FK.PARAM_N_SUB].reshape(e_count, n_frags, L)
@@ -391,65 +461,79 @@ def dispatch_ragged_grouped(params: np.ndarray,
     assert (params[:, FK.PARAM_WIDTH].reshape(e_count, n_frags, L)
             == width_f[None, :, None]).all(), \
         "grouped dispatch requires widths frozen"
-
+    if layout is None:
+        layout = window_layout(nsub_f, width_f, e_count, L)
     kw = dict(log2_te=log2_te, signed=signed, blk=blk, w_blk=w_blk,
               interpret=interpret, value_mode=value_mode, n_levels=L,
               with_mitigation=with_mitigation)
-    groups = [np.flatnonzero(nsub_f == n) for n in np.unique(nsub_f)]
-    outs, out_rows = [], []
-    for frag_idx in groups:
-        n_g = int(nsub_f[frag_idx[0]])
-        w_g = int(width_f[frag_idx].max(initial=4))
+    outs, h2d, n_packets = [], 0, 0
+    for g in layout.groups:
+        frag_idx = frag_lo + g.frags
         # all L level rows of each group fragment, epoch-major — aligned
         # with the packet rows pack_csr emits for the selected segments
         rows = ((np.arange(e_count)[:, None] * n_frags
                  + frag_idx[None, :]).ravel()[:, None] * L
                 + np.arange(L)[None, :]).ravel()
         with obs.span("fleet.select"):
-            selected = [p.select(frag_idx) for p in packets]
+            selected = (packets if len(frag_idx) == n_frags
+                        else [p.select(frag_idx) for p in packets])
         keys, vals, ts, block_frag = pack_csr(selected, blk)
-        out_g = _launch_ragged(keys, vals, ts, params[rows], block_frag,
-                               n_sub_max=n_g, width_max=w_g, **kw)
-        if len(groups) == 1 and n_g == n_sub_max and w_g == width_max:
-            return out_g
-        outs.append(out_g)
-        out_rows.append(rows.astype(np.int32))
-    with obs.span("fleet.launch",
-                  h2d_bytes=sum(r.nbytes for r in out_rows)):
-        return _assemble_groups(tuple(outs), tuple(out_rows),
-                                (n_rows, n_sub_max, width_max))
+        prm = params[rows]
+        sent = sum(a.nbytes for a in (keys, vals, ts, prm, block_frag))
+        h2d += sent
+        n_packets += sum(len(p.keys) for p in selected)
+        with obs.span("fleet.launch", h2d_bytes=sent):
+            outs.append(FK.fleet_update_ragged(
+                keys, vals, ts, prm, block_frag, n_sub_max=g.n,
+                width_max=g.width, **kw))
+    return outs, layout, h2d, n_packets
 
 
-def _launch_ragged(keys, vals, ts, params, block_frag, **kw):
-    """``fleet_update_ragged`` under a ``fleet.launch`` span that counts
-    the bytes of its host arguments."""
-    from ..kernels.sketch_update import fleet as FK
-
-    h2d = sum(a.nbytes for a in (keys, vals, ts, params, block_frag))
-    with obs.span("fleet.launch", h2d_bytes=h2d):
-        return FK.fleet_update_ragged(keys, vals, ts, params, block_frag,
-                                      **kw)
+@functools.partial(jax.jit, static_argnames=("kind", "n_levels", "size"))
+def _join_groups(outs, widths, *, kind: str, n_levels: int, size: int):
+    """One program per window and shard: the subepoch groups' launches
+    joined into one flat buffer of ``size`` counters (a zero tail pads a
+    mesh shard to the longest shard), with the counter peak and the §4.2
+    PEB of every (epoch, fragment) read off the groups on the way (level
+    0 rows; ``widths`` gives each group's fragment widths in launch
+    order).  The PEBs come out in group, then launch order."""
+    parts = [o.reshape(-1) for o in outs]
+    used = sum(p.shape[0] for p in parts)
+    if size > used:
+        parts.append(jnp.zeros(size - used, jnp.float32))
+    flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    peak = functools.reduce(jnp.maximum,
+                            [jnp.max(jnp.abs(o)) for o in outs])
+    pebs = [equalize.peb_jnp(o[::n_levels],
+                             jnp.full(w.shape, o.shape[1], jnp.int32), w,
+                             kind)
+            for o, w in zip(outs, widths)]
+    return flat, peak, jnp.concatenate(pebs)
 
 
 class _WindowBuffer:
-    """Device-resident stacked counters for one epoch window.
+    """Device-resident counters of one epoch window, in one flat f32
+    buffer laid out by a ``WindowLayout``.
 
-    Holds the raw ``(E, F, n_sub_max, width_max)`` f32 device array; the
-    host transfer + int64 conversion happens exactly once, on first
+    The host transfer + int64 conversion happens exactly once, on first
     ``host()`` call, after which the device buffer is released.  While
-    the buffer is still ``resident``, ``device()`` exposes the stack to
-    the batched on-device query plane (``kernels.sketch_query``) — point
-    and window queries then never trigger the transfer at all.
+    the buffer is still ``resident``, ``view()`` exposes it to the
+    batched on-device query plane (``kernels.sketch_query``) — point and
+    window queries then never trigger the transfer at all.  On a mesh
+    the buffer is one equal block per shard (``shard_len`` counters
+    each, the longest shard's; ``layout.base`` holds global offsets) and
+    ``rows_per_shard`` rows of the fleet's padded row space per block.
     """
 
-    def __init__(self, dev, shape: Tuple[int, ...],
-                 logical_rows: Optional[int] = None):
+    def __init__(self, dev, layout: WindowLayout,
+                 shard_len: Optional[int] = None,
+                 rows_per_shard: Optional[int] = None):
         self._dev = dev
-        self._shape = shape
-        # Mesh-sharded stacks carry trailing pad rows (fragments padded
-        # so rows divide the switch axis); ``host()`` slices the pad off
-        # so the record plane and host oracle only ever see real rows.
-        self._rows = logical_rows
+        self.layout = layout
+        self.shard_len = shard_len
+        self.rows_per_shard = rows_per_shard
+        self.stack_bytes = 4 * int(dev.size)
+        self.counter_bytes = layout.counter_bytes
         self._host: Optional[np.ndarray] = None
 
     @property
@@ -458,45 +542,69 @@ class _WindowBuffer:
         return self._dev is not None
 
     def device(self):
-        """The still-resident ``(E, F, n_sub_max, width_max)`` f32 stack
-        as a jax array (None once transferred)."""
-        if self._dev is None:
-            return None
-        if tuple(self._dev.shape) != tuple(self._shape):
-            # A mesh-sharded stack already has the right shape and must
-            # NOT be reshaped (that would drop its NamedSharding).
-            self._dev = self._dev.reshape(self._shape)
+        """The still-resident flat f32 buffer (None once transferred)."""
         return self._dev
 
+    def view(self, e_idx: Sequence[int]) -> FlatStack:
+        """The resident buffer's cells of epochs ``e_idx`` for the device
+        query plane; on a mesh with offsets into each row's own shard
+        block and the fleet's pad rows (base 0) appended."""
+        lay = self.layout
+        base = lay.base[np.asarray(e_idx, np.int64)]
+        if self.shard_len is None:
+            return FlatStack(self._dev, base, lay.stride)
+        n_rows = lay.base.shape[1]
+        shard = np.arange(n_rows) // self.rows_per_shard
+        n_pad = (int(self._dev.size) // self.shard_len
+                 * self.rows_per_shard - n_rows)
+        base = np.pad(base - shard * self.shard_len, ((0, 0), (0, n_pad)))
+        stride = np.pad(lay.stride, (0, n_pad), constant_values=1)
+        return FlatStack(self._dev, base, stride)
+
     def host(self) -> np.ndarray:
+        """The flat int64 counters on the host (one transfer)."""
         if self._host is None:
-            arr = (np.asarray(self._dev).astype(np.int64)
-                   .reshape(self._shape))
-            if self._rows is not None and self._rows != self._shape[1]:
-                arr = np.ascontiguousarray(arr[:, :self._rows])
-            self._host = arr
+            self._host = np.asarray(self._dev).astype(np.int64)
             self._dev = None
         return self._host
 
-    def epoch_view(self, e_idx: int) -> np.ndarray:
-        """Host copy/view of one epoch's (R, S, W) slice without forcing
-        the full-window transfer while still resident."""
-        if self.resident:
-            return np.asarray(self.device()[e_idx])
-        return self.host()[e_idx]
+    def host_epoch(self, e_idx: int) -> FlatStack:
+        """One epoch's cells of the host counters, for the host oracle."""
+        return FlatStack(self.host(), self.layout.base[e_idx],
+                         self.layout.stride)
+
+    def cell(self, e_idx: int, row_lo: int, row_hi: int) -> np.ndarray:
+        """Rows ``[row_lo, row_hi)`` of one epoch (one fragment's level
+        rows) as ``(rows, n, width)`` counters: read from the device
+        while resident (that cell alone), else a view of the host copy."""
+        start, shape = self.layout.cell(e_idx, row_lo, row_hi)
+        width = int(self.layout.widths[row_lo])
+        src = self._host if self._host is not None else self._dev
+        seg = src[start:start + int(np.prod(shape))]
+        if self._host is None:
+            seg = np.asarray(seg)
+        return seg.reshape(shape)[:, :, :width]
 
     def patch(self, e_idx: int, row_lo: int, row_hi: int,
-              counters: np.ndarray) -> None:
-        """Overwrite rows ``[row_lo, row_hi)`` of one epoch with exact
-        integer counters (XOR-parity recovery): patches the resident
-        device array, or the already-transferred host copy *in place* so
-        every existing record-plane view observes the reconstruction."""
+              counters) -> None:
+        """Overwrite rows ``[row_lo, row_hi)`` of one epoch (one
+        fragment's level rows) with exact integer ``(rows, n, width)``
+        counters (or a scalar): patches the resident device buffer, or
+        the already-transferred host copy *in place* so every existing
+        record-plane view observes it."""
+        start, shape = self.layout.cell(e_idx, row_lo, row_hi)
+        width = int(self.layout.widths[row_lo])
+        size = int(np.prod(shape))
+        vals = np.broadcast_to(np.asarray(counters),
+                               shape[:2] + (width,))
         if self.resident:
-            self._dev = self.device().at[e_idx, row_lo:row_hi].set(
-                jnp.asarray(counters, jnp.float32))
+            seg = self._dev[start:start + size].reshape(shape)
+            seg = seg.at[:, :, :width].set(jnp.asarray(vals, jnp.float32))
+            self._dev = self._dev.at[start:start + size].set(
+                seg.reshape(-1))
         else:
-            self._host[e_idx, row_lo:row_hi] = np.asarray(counters,
-                                                          np.int64)
+            self._host[start:start + size].reshape(shape)[:, :, :width] = \
+                vals.astype(np.int64)
 
 
 class WindowRecords(Mapping):
@@ -505,7 +613,7 @@ class WindowRecords(Mapping):
     The query plane consumes ``records[epoch][sw]``; materializing the
     records triggers the window's single host transfer (shared through
     ``_WindowBuffer``) and builds counters as *views* of the window
-    stack — no per-fragment copies.  Epochs nobody queries never leave
+    buffer — no per-fragment copies.  Epochs nobody queries never leave
     the device.
     """
 
@@ -524,17 +632,15 @@ class WindowRecords(Mapping):
 
     def _materialize(self) -> Dict[int, EpochRecords]:
         if self._recs is None:
-            stack = self._buf.host()[self._e]
+            self._buf.host()
             L = self._levels
             self._recs = {}
             for i, sw in enumerate(self._order):
                 cfg = self._fragments[sw]
-                n = int(self._n[i])
-                counters = (stack[i * L:(i + 1) * L, :n, :cfg.width]
-                            if cfg.kind == "um"
-                            else stack[i, :n, :cfg.width])
+                cell = self._buf.cell(self._e, i * L, (i + 1) * L)
                 self._recs[sw] = EpochRecords(
-                    cfg.frag_id, self._epoch, n, counters, cfg.kind,
+                    cfg.frag_id, self._epoch, int(self._n[i]),
+                    cell if cfg.kind == "um" else cell[0], cfg.kind,
                     cfg.mitigation, cfg.base_seed)
         return self._recs
 
@@ -551,6 +657,16 @@ class WindowRecords(Mapping):
         return sw in self._fragments
 
 
+def _zero_cells(flat, layout: WindowLayout, cells, n_levels: int):
+    """``flat`` with the (epoch, fragment position) ``cells`` of its
+    layout zeroed, in one scatter on the buffer's device."""
+    L = n_levels
+    spans = [layout.cell(e, f * L, (f + 1) * L) for e, f in cells]
+    idx = np.concatenate([np.arange(start, start + int(np.prod(shape)))
+                          for start, shape in spans])
+    return flat.at[jnp.asarray(idx, jnp.int32)].set(0.0)
+
+
 class FleetEpochRunner:
     """Batched replacement for the per-switch loop in ``run_epoch``.
 
@@ -561,14 +677,18 @@ class FleetEpochRunner:
     into one super-dispatch with frozen ``ns`` and device-resident
     counters; window epochs are queryable via
     ``point_query``/``window_query`` straight from the resident device
-    stack, no retention flag needed.  ``keep_stacked=True`` additionally
-    retains per-epoch *host* stacks from ``run_epoch`` so the batched
-    query ops also cover per-epoch dispatches (for window epochs, host
-    stacks are cached lazily on first host-path access —
-    ``run_window`` itself never forces the transfer).  Window stacks
-    stay device-resident until the record plane or a host-path query
-    materializes them; on accelerator deployments, materialize windows
-    you are finished querying to release their HBM.
+    buffer, no retention flag needed.  A window's counters are one flat
+    buffer per device whose n_sub groups keep their own extents
+    (``WindowLayout``); on a ``switch`` mesh each shard's update runs on
+    its own chip and its block of the buffer stays there.
+    ``keep_stacked=True`` additionally retains per-epoch *host* counters
+    from ``run_epoch`` so the batched query ops also cover per-epoch
+    dispatches (for window epochs, host counters are cached lazily on
+    first host-path access — ``run_window`` itself never forces the
+    transfer).  Window buffers stay device-resident until the record
+    plane or a host-path query materializes them; on accelerator
+    deployments, materialize windows you are finished querying to
+    release their HBM (``stack_bytes`` counts what is resident).
     ``interpret="auto"`` (default) compiles on
     TPU and interprets on CPU; ``value_mode="auto"`` picks the cheapest
     exact bf16/f32 contraction path per dispatch from the packed values
@@ -589,7 +709,6 @@ class FleetEpochRunner:
                  *, blk: int = CSR_BLK, w_blk: Optional[int] = None,
                  interpret="auto", keep_stacked: bool = False,
                  layout: str = "ragged", value_mode: str = "auto",
-                 group_by_n_sub: bool = True,
                  parity_groups: Optional[Sequence[Sequence[int]]] = None,
                  mesh=None):
         from ..kernels.sketch_update.kernel import (LVL_FIELD_MASK,
@@ -644,7 +763,6 @@ class FleetEpochRunner:
         self.keep_stacked = keep_stacked
         self.layout = layout
         self.value_mode = value_mode
-        self.group_by_n_sub = group_by_n_sub
         self.frag_order: Tuple[int, ...] = tuple(sorted(fragments))
         self.widths = np.array([fragments[sw].width
                                 for sw in self.frag_order], np.int64)
@@ -676,10 +794,11 @@ class FleetEpochRunner:
         # domain — they are *in flight*, not dead, and flip back live
         # when the cell's export message arrives (``deliver_cell``).
         self._unexported: Dict[int, set] = {}
-        # epoch -> per-group (n_levels, n_sub_max, width_max) int32 XOR
-        # parity over the group members' rows (computed from the same
-        # window dispatch, before lost cells are zeroed).
-        self._parity: Dict[int, List[np.ndarray]] = {}
+        # epoch -> ({parity group: (E, L, n, w) int32 device XOR parity
+        # over its members' cells}, epoch index within the window),
+        # computed from the same window dispatch before lost cells are
+        # zeroed.
+        self._parity: Dict[int, Tuple[Dict[int, object], int]] = {}
         self._frag_pos = {sw: i for i, sw in enumerate(self.frag_order)}
         self.parity_groups: Optional[List[np.ndarray]] = None
         self._group_of: Dict[int, int] = {}
@@ -743,6 +862,21 @@ class FleetEpochRunner:
         # Device gather/merge launches of every window query so far.
         self.query_launches = 0
 
+    def _resident_bufs(self) -> List[_WindowBuffer]:
+        bufs = {id(b): b for b, _ in self._window_bufs.values()}
+        return [b for b in bufs.values() if b.resident]
+
+    @property
+    def stack_bytes(self) -> int:
+        """Bytes of the resident window buffers (with each group's and
+        each mesh shard's padding)."""
+        return sum(b.stack_bytes for b in self._resident_bufs())
+
+    @property
+    def counter_bytes(self) -> int:
+        """Bytes of the resident windows' own counters, 4 · Σ n·width."""
+        return sum(b.counter_bytes for b in self._resident_bufs())
+
     # Exactness bound.  Counters are f32 accumulations: exact while
     # every intermediate magnitude stays below 2^24.  For unsigned (cms)
     # counters the final value is the peak, so a cheap output check
@@ -775,179 +909,150 @@ class FleetEpochRunner:
 
         check_output_peak(peak)
 
-    def _dispatch(self, params: np.ndarray, packets: Sequence[FleetPacket],
-                  n_sub_max: int, width_max: int):
-        """One device launch over the param table's rows; returns the
-        still-on-device (n_rows, n_sub_max, width_max) f32 stack."""
-        from ..kernels.sketch_update import fleet as FK
+    def _dispatch_window(self, params: np.ndarray,
+                         packets: Sequence[FleetPacket],
+                         lost_sets: Sequence[set]):
+        """The update of one window (E = 1 for a single epoch): every
+        shard's grouped launches on its own device — the default device
+        without a mesh — each issued before anything is read.  On each
+        shard's device its groups are joined into the shard's flat buffer
+        with the counter peak and the §4.2 PEBs, the XOR parity of its
+        parity groups is taken, and then its lost cells are zeroed.  The
+        host reads every shard's peak and PEBs once, in one
+        ``fleet.sync``; no counter block crosses to the host.
 
-        # Fold per-packet UnivMon level ids / §4.4 flags into the high
-        # ts bits (no-op for plain cs/cms fleets — the cached epoch
-        # packets are shared across systems and must stay untouched).
+        Returns ``(buf, pebs (E, F), parity, peak)``: ``parity`` maps a
+        parity group's index to its (E, L, n, w) int32 device parity, or
+        is None without parity groups.
+        """
+        from ..kernels.sketch_update.fleet import PARAM_N_SUB, PARAM_WIDTH
+
         with obs.span("fleet.prepare"):
+            # Fold per-packet UnivMon level ids / §4.4 flags into the high
+            # ts bits (no-op for plain cs/cms fleets — the cached epoch
+            # packets are shared across systems and must stay untouched).
             packets = [fold_packet_flags(p, self.log2_te,
                                          n_levels=self.n_levels,
                                          level_seed=self.level_seed,
                                          mitigation=self.mitigation)
                        for p in packets]
-        kw = dict(n_sub_max=n_sub_max, width_max=width_max,
-                  log2_te=self.log2_te,
-                  signed=self.kind in ("cs", "um"),
+        e_count = len(packets)
+        n_frags = len(self.frag_order)
+        L = self.n_levels
+        ns_f = params[:n_frags * L:L, PARAM_N_SUB].astype(np.int64)
+        widths_f = params[:n_frags * L:L, PARAM_WIDTH].astype(np.int64)
+        bounds = self._shard_frag_bounds or [(0, n_frags)]
+        layouts = [window_layout(ns_f[lo:hi], widths_f[lo:hi], e_count, L)
+                   for lo, hi in bounds]
+        shard_len = max(lay.size for lay in layouts)
+        devices = (self._shard_devices(shard_len) if self.mesh is not None
+                   else [jax.devices()[0]])
+        kw = dict(log2_te=self.log2_te, signed=self.kind in ("cs", "um"),
                   blk=self.blk, w_blk=self.w_blk, interpret=self.interpret,
-                  value_mode=self.value_mode)
-        if self.layout == "dense":
-            if len(packets) != 1:
-                raise ValueError("dense layout is per-epoch only; "
-                                 "window dispatch requires layout='ragged'")
-            keys, vals, ts = packets[0].densify(self.blk)
-            return FK.fleet_update(keys, vals, ts, params, **kw)
-        kw.update(n_levels=self.n_levels, with_mitigation=self.mitigation)
-        if self.group_by_n_sub:
-            del kw["n_sub_max"], kw["width_max"]
-            return dispatch_ragged_grouped(
-                params, packets, n_sub_max=n_sub_max, width_max=width_max,
-                **kw)
-        keys, vals, ts, block_frag = pack_csr(packets, self.blk)
-        return _launch_ragged(keys, vals, ts, params, block_frag, **kw)
-
-    # --- mesh-sharded dispatch (docs/sharding.md) ------------------------
-
-    def _shard_dispatch_blocks(self, params: np.ndarray,
-                               packets: Sequence[FleetPacket],
-                               n_sub_max: int, width_max: int):
-        """Yield ``(frag_lo, frag_hi, block)`` per non-empty shard, with
-        ``block`` the shard's ``(E, (hi-lo)*L, S, W)`` f32 counters.
-
-        Packets are routed at pack time (``FleetPacket.select`` of the
-        shard's contiguous fragment positions) and each shard runs the
-        ordinary grouped/flag-folding dispatch over its own rows only —
-        per-row counters are bit-identical to the single-device launch
-        by the same argument as ``dispatch_ragged_grouped``: a smaller
-        launch only changes *which* zero rows/columns are materialized,
-        never the hash arithmetic of a real row.
-        """
-        e_count = len(packets)
-        n_frags = len(self.frag_order)
-        L = self.n_levels
-        for lo, hi in self._shard_frag_bounds:
+                  value_mode=self.value_mode, n_levels=L,
+                  with_mitigation=self.mitigation)
+        flats, reads, parity = [], [], {}
+        for s, ((lo, hi), lay, dev) in enumerate(zip(bounds, layouts,
+                                                     devices)):
             if lo >= hi:
+                flats.append(jnp.zeros(shard_len, jnp.float32, device=dev))
                 continue
-            idx = np.arange(lo, hi)
-            rows = ((np.arange(e_count)[:, None] * n_frags
-                     + idx[None, :]).ravel()[:, None] * L
-                    + np.arange(L)[None, :]).ravel()
-            sub = [p.select(idx) for p in packets]
-            blk = np.asarray(self._dispatch(params[rows], sub,
-                                            n_sub_max, width_max),
-                             np.float32)
-            yield lo, hi, blk.reshape(e_count, (hi - lo) * L,
-                                      n_sub_max, width_max)
-
-    def _dispatch_mesh_host(self, params: np.ndarray,
-                            packets: Sequence[FleetPacket],
-                            n_sub_max: int, width_max: int) -> np.ndarray:
-        """Per-epoch mesh leg: shard-local dispatches concatenated back
-        to one host ``(n_rows, S, W)`` stack (``run_epoch`` is the
-        host-centric path — per-epoch records materialize immediately,
-        so there is nothing to keep sharded)."""
-        e_count = len(packets)
-        L = self.n_levels
-        rows_per_epoch = len(self.frag_order) * L
-        out = np.zeros((e_count, rows_per_epoch, n_sub_max, width_max),
-                       np.float32)
-        for lo, hi, blk in self._shard_dispatch_blocks(
-                params, packets, n_sub_max, width_max):
-            out[:, lo * L:hi * L] = blk
-        return out.reshape(e_count * rows_per_epoch, n_sub_max, width_max)
-
-    def _assemble_sharded(self, blocks: List[np.ndarray], e_count: int,
-                          n_sub_max: int, width_max: int):
-        """Commit per-shard blocks to their mesh devices as ONE global
-        row-sharded ``(E, R_pad, S, W)`` array (zero rows pad the last /
-        empty shards up to ``frags_per_shard``).  Built with
-        ``make_array_from_single_device_arrays`` so no global host
-        rectangle beyond the per-shard blocks is ever materialized."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        L = self.n_levels
-        rps = self._frags_per_shard * L
-        shape = (e_count, self.n_shards * rps, n_sub_max, width_max)
-        sharding = NamedSharding(self.mesh, P(None, "switch", None, None))
-        padded = []
-        for blk in blocks:
-            if blk.shape[1] != rps:
-                blk = np.pad(blk, ((0, 0), (0, rps - blk.shape[1]),
-                                   (0, 0), (0, 0)))
-            padded.append(np.ascontiguousarray(blk, np.float32))
-        arrays = []
-        for d, idx in sharding.addressable_devices_indices_map(
-                shape).items():
-            s = (idx[1].start or 0) // rps
-            arrays.append(jax.device_put(padded[s], d))
-        return jax.make_array_from_single_device_arrays(shape, sharding,
-                                                        arrays)
-
-    def _run_window_mesh(self, params: np.ndarray,
-                         packets: Sequence[FleetPacket],
-                         lost_sets: Sequence[set], n_arr: np.ndarray,
-                         e_count: int, n_sub_max: int, width_max: int):
-        """Mesh leg of ``run_window``: shard-local dispatch, with the
-        peak / §4.2 PEBs / XOR parity / lost-row zeroing all computed on
-        the per-shard blocks BEFORE the global sharded stack is
-        assembled — nothing row-global ever crosses a device boundary.
-        Returns ``(buf, pebs_all, parity_by_epoch, peak)``."""
-        L = self.n_levels
-        n_frags = len(self.frag_order)
-        rows_per_epoch = n_frags * L
-        blocks = [np.zeros((e_count, 0, n_sub_max, width_max), np.float32)
-                  for _ in range(self.n_shards)]
-        peak = 0.0
+            # One device: no device context, which would key every
+            # compiled program apart from the same program outside it.
+            on_dev = (contextlib.nullcontext() if self.mesh is None
+                      else jax.default_device(dev))
+            with obs.span("fleet.shard", shard=s, device=dev.id,
+                          rows=e_count * (hi - lo) * L) as sp, on_dev:
+                outs, _, h2d, n_packets = dispatch_ragged_grouped(
+                    params, packets, lay, frag_lo=lo, **kw)
+                widths = tuple(np.tile(widths_f[lo + g.frags], e_count)
+                               .astype(np.int32) for g in lay.groups)
+                flat, peak, pebs = _join_groups(
+                    tuple(outs), widths, kind=self.kind, n_levels=L,
+                    size=shard_len)
+                if self.parity_groups is not None:
+                    parity.update(self._group_parity(outs, lay, lo, hi))
+                lost = [(e, self._frag_pos[sw] - lo)
+                        for e, sws in enumerate(lost_sets) for sw in sws
+                        if lo <= self._frag_pos[sw] < hi]
+                if lost:
+                    flat = _zero_cells(flat, lay, lost, L)
+                sp.set_metadata(packets=n_packets, h2d_bytes=h2d)
+            flats.append(flat)
+            reads.append((lo, lay, peak, pebs))
+        with obs.span("fleet.sync"):
+            got = jax.device_get([(peak, pebs) for _, _, peak, pebs in reads])
         pebs_all = np.zeros((e_count, n_frags))
-        for lo, hi, blk in self._shard_dispatch_blocks(
-                params, packets, n_sub_max, width_max):
-            s = lo // self._frags_per_shard
-            peak = max(peak, float(np.abs(blk).max(initial=0.0)))
-            # §4.2 PEBs from the shard's level-0 rows (same formula as
-            # the single-device path, evaluated per shard block).
-            flat = blk.reshape(e_count * (hi - lo) * L,
-                               n_sub_max, width_max)
-            pebs_all[:, lo:hi] = np.asarray(equalize.peb_fleet_device(
-                flat[::L], np.tile(n_arr[lo:hi], e_count),
-                np.tile(self.widths[lo:hi], e_count),
-                self.kind)).reshape(e_count, hi - lo)
-            blocks[s] = blk
-        # XOR parity per (epoch, group) before zeroing lost rows; groups
-        # are shard-local (enforced at construction), so each reads one
-        # shard's block only.
-        parity_by_epoch = None
-        if self.parity_groups is not None:
-            per_group = []
-            for g in self.parity_groups:
-                s = int(g[0]) // self._frags_per_shard
-                lo = self._shard_frag_bounds[s][0]
-                acc = None
-                for i in g:
-                    j = int(i) - lo
-                    cell = blocks[s][:, j * L:(j + 1) * L].astype(np.int32)
-                    acc = cell if acc is None else acc ^ cell
-                per_group.append(acc)               # (E, L, S, W) int32
-            parity_by_epoch = [[pg[e] for pg in per_group]
-                               for e in range(e_count)]
-        for e, lost in enumerate(lost_sets):
-            for sw in lost:
-                i = self._frag_pos[sw]
-                s = i // self._frags_per_shard
-                j = i - self._shard_frag_bounds[s][0]
-                if not blocks[s].flags.writeable:
-                    # np.asarray of a device output is a read-only view
-                    blocks[s] = blocks[s].copy()
-                blocks[s][e, j * L:(j + 1) * L] = 0.0
-        out = self._assemble_sharded(blocks, e_count, n_sub_max, width_max)
-        buf = _WindowBuffer(
-            out, (e_count, self._frags_per_shard * self.n_shards * L,
-                  n_sub_max, width_max),
-            logical_rows=rows_per_epoch)
-        return buf, pebs_all, parity_by_epoch, peak
+        for (lo, lay, _, _), (_, pebs) in zip(reads, got):
+            at = 0
+            for g in lay.groups:
+                k = e_count * len(g.frags)
+                pebs_all[:, lo + g.frags] = pebs[at:at + k].reshape(
+                    e_count, len(g.frags))
+                at += k
+        peak = max(float(p) for p, _ in got)
+        if self.mesh is None:
+            return (_WindowBuffer(flats[0], layouts[0]), pebs_all,
+                    parity or None, peak)
+        base = np.concatenate([lay.base + s * shard_len
+                               for s, lay in enumerate(layouts)], axis=1)
+        layout = WindowLayout(
+            base, *(np.concatenate([getattr(lay, k) for lay in layouts])
+                    for k in ("stride", "ns", "widths")),
+            self.n_shards * shard_len)
+        sharding = NamedSharding(self.mesh, P("switch"))
+        shape = (self.n_shards * shard_len,)
+        by_block = {dev: flat for dev, flat in zip(devices, flats)}
+        arrays = [by_block[d] for d in
+                  sharding.addressable_devices_indices_map(shape)]
+        out = jax.make_array_from_single_device_arrays(shape, sharding,
+                                                       arrays)
+        buf = _WindowBuffer(out, layout, shard_len=shard_len,
+                            rows_per_shard=self._frags_per_shard * L)
+        return buf, pebs_all, parity or None, peak
+
+    def _shard_devices(self, shard_len: int) -> list:
+        """The device that holds each shard's block of a sharded window
+        buffer of ``shard_len`` counters a shard."""
+        sharding = NamedSharding(self.mesh, P("switch"))
+        where = sharding.addressable_devices_indices_map(
+            (self.n_shards * shard_len,))
+        by_block = {(idx[0].start or 0) // shard_len: d
+                    for d, idx in where.items()}
+        return [by_block[s] for s in range(self.n_shards)]
+
+    def _group_parity(self, outs, lay: WindowLayout, lo: int, hi: int):
+        """XOR parity of the parity groups whose members are fragments
+        ``[lo, hi)``, over the un-zeroed launches: one (E, L, n, w) int32
+        array per group, each member's cells zero-padded to the group's
+        largest n and width.  Exact integers below 2^24 make the
+        f32->int32 conversion lossless, and XOR (unlike a sum) can
+        neither overflow nor round; dead members' cells are exact zeros
+        and XOR away, so the parity equation holds for any liveness."""
+        L = self.n_levels
+        e_count = lay.base.shape[0]
+        where = {int(f): (k, j) for k, g in enumerate(lay.groups)
+                 for j, f in enumerate(g.frags)}
+        out = {}
+        for gi, members in enumerate(self.parity_groups):
+            if not lo <= int(members[0]) < hi:
+                continue
+            cells = []
+            for i in members:
+                k, j = where[int(i) - lo]
+                g = lay.groups[k]
+                width = int(lay.widths[(int(i) - lo) * L])
+                cells.append(outs[k].reshape(e_count, len(g.frags), L, g.n,
+                                             g.width)[:, j, :, :, :width])
+            n_p = max(c.shape[2] for c in cells)
+            w_p = max(c.shape[3] for c in cells)
+            acc = None
+            for c in cells:
+                c = jnp.pad(c, ((0, 0), (0, 0), (0, n_p - c.shape[2]),
+                                (0, w_p - c.shape[3]))).astype(jnp.int32)
+                acc = c if acc is None else acc ^ c
+            out[gi] = acc
+        return out
 
     def refresh_widths(self) -> None:
         """Recompute the cached width vectors after a resource-reclaim
@@ -980,22 +1085,30 @@ class FleetEpochRunner:
         L = self.n_levels
         params = build_params(self.fragments, epoch, ns, self.frag_order)
         n_arr = params[::L, PARAM_N_SUB].astype(np.int64)  # per fragment
-        n_sub_max = int(n_arr.max(initial=1))
-        width_max = int(self.widths.max(initial=4))
 
-        if self.mesh is None:
-            stacked_f32 = np.asarray(self._dispatch(params, [packet],
-                                                    n_sub_max, width_max))
+        if self.layout == "dense":
+            from ..kernels.sketch_update import fleet as FK
+
+            keys, vals, ts = packet.densify(self.blk)
+            out = np.asarray(FK.fleet_update(
+                keys, vals, ts, params, n_sub_max=int(n_arr.max(initial=1)),
+                width_max=int(self.widths.max(initial=4)),
+                log2_te=self.log2_te, signed=self.kind in ("cs", "um"),
+                blk=self.blk, w_blk=self.w_blk, interpret=self.interpret,
+                value_mode=self.value_mode))
+            peak = float(np.abs(out).max(initial=0.0))
+            n_rows, n_sub, width = out.shape
+            cells = FlatStack(out.astype(np.int64).reshape(-1),
+                              np.arange(n_rows) * n_sub * width,
+                              np.full(n_rows, width))
         else:
-            stacked_f32 = self._dispatch_mesh_host(params, [packet],
-                                                   n_sub_max, width_max)
-        self._check_output_peak(float(np.abs(stacked_f32).max(initial=0.0)))
-        stacked = stacked_f32.astype(np.int64)
+            # Per-epoch records are host records: the window dispatch of
+            # E = 1, read back whole.
+            buf, _, _, peak = self._dispatch_window(params, [packet],
+                                                    [set()])
+            cells = buf.host_epoch(0)
+        self._check_output_peak(peak)
 
-        # §4.2 PEBs come from level 0 for UnivMon (the ::L row slice is
-        # exactly the level-0 rows; a no-op view for cs/cms).
-        pebs_arr = equalize.peb_fleet(stacked[::L], n_arr, self.widths,
-                                      self.kind)
         recs: Dict[int, EpochRecords] = {}
         pebs: Dict[int, float] = {}
         for i, sw in enumerate(self.frag_order):
@@ -1003,13 +1116,14 @@ class FleetEpochRunner:
                 continue      # no record, no PEB — matches the loop path
             cfg = self.fragments[sw]
             n = int(n_arr[i])
-            counters = (stacked[i * L:(i + 1) * L, :n, :cfg.width].copy()
-                        if cfg.kind == "um"
-                        else stacked[i, :n, :cfg.width].copy())
+            start = int(cells.base[i * L])
+            w = int(cells.stride[i * L])
+            cell = (cells.flat[start:start + L * n * w]
+                    .reshape(L, n, w)[:, :, :cfg.width].copy())
             recs[sw] = EpochRecords(
-                cfg.frag_id, epoch, n, counters, cfg.kind,
-                cfg.mitigation, cfg.base_seed)
-            pebs[sw] = float(pebs_arr[i])
+                cfg.frag_id, epoch, n, cell if cfg.kind == "um" else cell[0],
+                cfg.kind, cfg.mitigation, cfg.base_seed)
+            pebs[sw] = equalize.peb_epoch(recs[sw])
         # A reprocessed epoch invalidates any window retention for it:
         # a stale resident buffer would silently answer queries with the
         # previous run's counters/seeds.
@@ -1025,7 +1139,7 @@ class FleetEpochRunner:
         else:
             self._row_live.pop(epoch, None)
         if self.keep_stacked:
-            self.stacked[epoch] = stacked
+            self.stacked[epoch] = cells
             self._params_log[epoch] = params
         else:
             self.stacked.pop(epoch, None)
@@ -1037,14 +1151,15 @@ class FleetEpochRunner:
                    dead_by_epoch: Optional[Sequence[Sequence[int]]] = None,
                    lost_by_epoch: Optional[Sequence[Sequence[int]]] = None,
                    ) -> Tuple[List[WindowRecords], List[Dict[int, float]]]:
-        """Epoch-window super-dispatch: E epochs x F fragments in ONE
-        kernel launch (E*F virtual param rows), ``ns`` frozen for the
-        window.
+        """Epoch-window super-dispatch: E epochs x F fragments in one
+        kernel launch per subepoch group (E*F virtual param rows), ``ns``
+        frozen for the window; on a mesh, per group and shard, each
+        shard on its own chip (``_dispatch_window``).
 
         Counters stay device-resident: only the overflow peak (one
-        scalar) and the (E*F,) PEB vector cross the host boundary here;
-        the full stack transfers lazily, once per window, when the query
-        plane first touches a ``WindowRecords``.
+        scalar) and the (E*F,) PEB vector cross the host boundary here,
+        in one read; the whole buffer transfers lazily, once per window,
+        when the query plane first touches a ``WindowRecords``.
 
         Churn plumbing (both optional, per-epoch switch-id sets):
         ``dead_by_epoch`` — switches holding no sketch memory during
@@ -1087,47 +1202,12 @@ class FleetEpochRunner:
                                  self.frag_order)
                     for e in range(e_count)])
                 n_arr = params[:rows_per_epoch:L, PARAM_N_SUB].astype(np.int64)
-                n_sub_max = int(params[:, PARAM_N_SUB].max(initial=1))
-                width_max = int(self.widths.max(initial=4))
 
-            if self.mesh is not None:
-                buf, pebs_all, parity_by_epoch, peak = self._run_window_mesh(
-                    params, packets, lost_sets, n_arr, e_count,
-                    n_sub_max, width_max)
-                self._check_output_peak(peak)
-            else:
-                out = self._dispatch(params, packets, n_sub_max, width_max)
-                peak = abs_peak(out) if out.size else 0.0
-                with obs.span("fleet.sync"):
-                    peak = float(peak)
-                self._check_output_peak(peak)
-                # §4.2 PEBs from the level-0 rows (::L is a no-op for
-                # cs/cms) — computed before lost cells are zeroed (their
-                # counters are genuine observations of epochs the switch did
-                # sketch).
-                pebs_dev = equalize.peb_fleet_device(
-                    out[::L], np.tile(n_arr, e_count),
-                    np.tile(self.widths, e_count), self.kind)
-                with obs.span("fleet.sync"):
-                    pebs_all = np.asarray(pebs_dev).reshape(e_count, n_frags)
-                # XOR parity per (epoch, group) over the un-zeroed stack:
-                # exact integers below 2^24 make the f32->int32 conversion
-                # lossless, and XOR (unlike a sum) can neither overflow nor
-                # round.
-                parity_by_epoch = None
-                if self.parity_groups is not None:
-                    parity_by_epoch = self._window_parity(
-                        out, e_count, rows_per_epoch, n_sub_max, width_max)
-                if any(lost_sets):
-                    rows = np.concatenate([
-                        np.arange(i * L, (i + 1) * L) + e * rows_per_epoch
-                        for e, lost in enumerate(lost_sets)
-                        for i in sorted(self._frag_pos[sw] for sw in lost)]
-                    ).astype(np.int32)
-                    out = out.at[jnp.asarray(rows)].set(0.0)
-
-                buf = _WindowBuffer(out, (e_count, rows_per_epoch, n_sub_max,
-                                          width_max))
+            buf, pebs_all, parity, peak = self._dispatch_window(
+                params, packets, lost_sets)
+            self._check_output_peak(peak)
+            sp.set_metadata(stack_bytes=buf.stack_bytes,
+                            counter_bytes=buf.counter_bytes)
             with obs.span("fleet.records"):
                 recs_list: List[WindowRecords] = []
                 pebs_list: List[Dict[int, float]] = []
@@ -1157,8 +1237,8 @@ class FleetEpochRunner:
                     self._lost.pop(ep, None)
                     self._parity.pop(ep, None)
                     self._unexported.pop(ep, None)
-                    if parity_by_epoch is not None:
-                        self._parity[ep] = parity_by_epoch[e]
+                    if parity is not None:
+                        self._parity[ep] = (parity, e)
                     invalid = dead_sets[e] | lost_sets[e]
                     if invalid:
                         live = np.ones(rows_per_epoch, bool)
@@ -1171,26 +1251,6 @@ class FleetEpochRunner:
                     else:
                         self._row_live.pop(ep, None)
             return recs_list, pebs_list
-
-    def _window_parity(self, out, e_count: int, rows_per_epoch: int,
-                       n_sub_max: int, width_max: int,
-                       ) -> List[List[np.ndarray]]:
-        """Per-epoch, per-group XOR parity over the group members' rows
-        of the (still possibly device-resident) window stack.  Returns
-        ``[epoch][group] -> (n_levels, n_sub_max, width_max)`` int32 on
-        host — total parity memory is one fragment-equivalent per group.
-        Dead members' rows are exact zeros and XOR away, so the parity
-        equation stays consistent for any liveness pattern."""
-        L = self.n_levels
-        a = out.reshape(e_count, rows_per_epoch, n_sub_max, width_max)
-        per_group = []
-        for g in self.parity_groups:
-            acc = None
-            for i in g:
-                cell = a[:, i * L:(i + 1) * L].astype(jnp.int32)
-                acc = cell if acc is None else acc ^ cell
-            per_group.append(np.asarray(acc))   # (E, L, S, W) int32
-        return [[pg[e] for pg in per_group] for e in range(e_count)]
 
     def point_query(self, epoch: int, keys: np.ndarray,
                     path: Optional[Sequence[int]] = None,
@@ -1220,14 +1280,14 @@ class FleetEpochRunner:
         return all(e in self._window_bufs
                    and self._window_bufs[e][0].resident for e in epochs)
 
-    def _host_stack(self, epoch: int) -> np.ndarray:
-        """Host counters for one retained epoch: the per-epoch
-        ``keep_stacked`` copy, or the epoch's slice of an
-        already-transferred window buffer."""
+    def _host_stack(self, epoch: int) -> FlatStack:
+        """Host counters of one retained epoch, as the host oracle reads
+        them: the per-epoch ``keep_stacked`` copy, or the epoch's cells
+        of an already-transferred window buffer."""
         stack = self.stacked.get(epoch)
         if stack is None:
             buf, e_idx = self._window_bufs[epoch]
-            stack = buf.host()[e_idx]
+            stack = buf.host_epoch(e_idx)
             self.stacked[epoch] = stack
         return stack
 
@@ -1247,16 +1307,15 @@ class FleetEpochRunner:
     # query through the ordinary ``failures="mask"`` machinery.
 
     def cell_counters(self, epoch: int, sw: int) -> np.ndarray:
-        """One (epoch, fragment) cell of the retained window stack as an
-        exact int32 copy — the export payload (lossless: counters are
-        exact integers below 2^24)."""
+        """One (epoch, fragment) cell of the retained window buffer as
+        an exact ``(n_levels, n, width)`` int32 copy — the export payload
+        (lossless: counters are exact integers below 2^24)."""
         if epoch not in self._window_bufs:
             raise KeyError(f"epoch {epoch} has no retained window stack")
         buf, e_idx = self._window_bufs[epoch]
         i = self._frag_pos[sw]
         L = self.n_levels
-        return (np.asarray(buf.epoch_view(e_idx)[i * L:(i + 1) * L])
-                .astype(np.int32))
+        return buf.cell(e_idx, i * L, (i + 1) * L).astype(np.int32)
 
     def mark_unexported(self, epoch: int, sws: Sequence[int]) -> None:
         """Hold (epoch, switch) cells back from the query plane: zero
@@ -1272,11 +1331,9 @@ class FleetEpochRunner:
             live = np.ones(len(self.frag_order) * L, bool)
             self._row_live[epoch] = live
         pend = self._unexported.setdefault(epoch, set())
-        _, _, n_sub_max, width_max = buf._shape
-        zeros = np.zeros((L, n_sub_max, width_max), np.int64)
         for sw in sws:
             i = self._frag_pos[sw]
-            buf.patch(e_idx, i * L, (i + 1) * L, zeros)
+            buf.patch(e_idx, i * L, (i + 1) * L, 0)
             live[i * L:(i + 1) * L] = False
             pend.add(i)
 
@@ -1347,21 +1404,21 @@ class FleetEpochRunner:
             buf, e_idx = self._window_bufs[e]
             live = self._row_live[e]
             lost = self._lost[e]
-            parity = self._parity[e]
-            stack_e = buf.epoch_view(e_idx)     # (R, S, W) host
+            parity, p_idx = self._parity[e]
             patches = []
             for sw in sws:
                 i = self._frag_pos[sw]
                 gi = self._group_of[i]
-                acc = parity[gi].copy()         # (L, S, W) int32
+                # (L, n, w) int32: the group's largest n and width
+                acc = np.array(jax.device_get(parity[gi][p_idx]))
                 for j in self.parity_groups[gi]:
                     if j != i:
-                        acc ^= np.asarray(
-                            stack_e[j * L:(j + 1) * L]).astype(np.int32)
-                patches.append((i, acc))
+                        c = buf.cell(e_idx, j * L, (j + 1) * L)
+                        acc[:, :c.shape[1], :c.shape[2]] ^= c.astype(np.int32)
+                c = buf.cell(e_idx, i * L, (i + 1) * L)
+                patches.append((i, acc[:, :c.shape[1], :c.shape[2]]))
             for i, counters in patches:
-                buf.patch(e_idx, i * L, (i + 1) * L,
-                          counters.astype(np.int64))
+                buf.patch(e_idx, i * L, (i + 1) * L, counters)
                 live[i * L:(i + 1) * L] = True
                 lost.discard(i)
                 recovered.setdefault(e, []).append(self.frag_order[i])
@@ -1392,7 +1449,7 @@ class FleetEpochRunner:
 
         Returns ``(device_groups, host_epochs)`` where each device
         group is ``(stack, epochs)`` with ``stack`` the still-resident
-        (possibly epoch-gathered) device array for those epochs.
+        buffer's ``FlatStack`` of those epochs' cells.
         """
         missing = [e for e in epochs
                    if e not in self.stacked and e not in self._window_bufs]
@@ -1409,14 +1466,9 @@ class FleetEpochRunner:
                 by_buf.setdefault(id(ent[0]), (ent[0], []))[1].append(e)
             else:
                 host_epochs.append(e)
-        device_groups = []
-        for buf, es in by_buf.values():
-            stack = buf.device()
-            idx = np.array([self._window_bufs[e][1] for e in es], np.int64)
-            if len(idx) != stack.shape[0] \
-                    or (idx != np.arange(len(idx))).any():
-                stack = stack[idx]          # device-side epoch gather
-            device_groups.append((stack, es))
+        device_groups = [
+            (buf.view([self._window_bufs[e][1] for e in es]), es)
+            for buf, es in by_buf.values()]
         return device_groups, host_epochs
 
     def _liveness_sels(self, epochs: Sequence[int],

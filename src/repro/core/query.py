@@ -132,19 +132,21 @@ def _query_epoch_fragment_merge(records, keys, kind, single_hop, level):
     return np.median(ests, axis=0)
 
 
-def fleet_query_epoch(stacked: np.ndarray, col_seeds: np.ndarray,
+def fleet_query_epoch(stacked, col_seeds: np.ndarray,
                       sign_seeds: np.ndarray, sub_seeds: np.ndarray,
                       ns: np.ndarray, widths: np.ndarray,
                       keys: np.ndarray, kind: str,
                       frag_sel: Optional[np.ndarray] = None,
                       mit: Optional[np.ndarray] = None,
                       single_hop: bool = False) -> np.ndarray:
-    """Batched epoch point-query over a fleet's stacked counters.
+    """Batched epoch point-query over a fleet's counters.
 
-    One vectorized pass over the (n_rows, n_sub_max, width_max) block
-    produced by the fleet kernel (rows are fragments, or fragment×level
-    pairs for UnivMon): every row's raw estimate for every key is
-    gathered at once (hashes broadcast over the row axis), scaled
+    ``stacked`` is one epoch's counters: an (n_rows, n_sub_max,
+    width_max) block, or a ``FlatStack`` whose ``base`` holds the (n_rows,)
+    cell offsets of the epoch (a window buffer's layout, ``repro.kernels.
+    sketch_query``).  One vectorized pass (rows are fragments, or
+    fragment×level pairs for UnivMon): every row's raw estimate for every
+    key is gathered at once (hashes broadcast over the row axis), scaled
     proportionally to the epoch (x n, §1), and merged across rows — min
     for Count-Min, median for Count Sketch / UnivMon levels.
     Semantically identical to ``query_epoch(..., merge="fragment")`` on
@@ -163,7 +165,14 @@ def fleet_query_epoch(stacked: np.ndarray, col_seeds: np.ndarray,
     keys must share single-hop status, which query_flows guarantees per
     path group).
     """
+    from ..kernels.sketch_query import as_flat
+
     keys = np.asarray(keys, dtype=np.uint32)
+    if isinstance(stacked, np.ndarray):
+        flat, base, stride = as_flat(stacked[None])
+        base = base[0]
+    else:
+        flat, base, stride = stacked
     if frag_sel is not None:
         frag_sel = np.asarray(frag_sel, bool)
         if not frag_sel.any():
@@ -171,7 +180,8 @@ def fleet_query_epoch(stacked: np.ndarray, col_seeds: np.ndarray,
                 "fleet_query_epoch: frag_sel selects no rows — an "
                 "all-masked merge has no survivor; drop the epoch "
                 "(blind-epoch extrapolation) or widen the selection")
-        stacked = stacked[frag_sel]
+        base = np.asarray(base)[frag_sel]
+        stride = np.asarray(stride)[frag_sel]
         col_seeds = np.asarray(col_seeds)[frag_sel]
         sign_seeds = np.asarray(sign_seeds)[frag_sel]
         sub_seeds = np.asarray(sub_seeds)[frag_sel]
@@ -179,18 +189,19 @@ def fleet_query_epoch(stacked: np.ndarray, col_seeds: np.ndarray,
         widths = np.asarray(widths)[frag_sel]
         if mit is not None:
             mit = np.asarray(mit, bool)[frag_sel]
-    if len(keys) == 0 or stacked.shape[0] == 0:
+    if len(keys) == 0 or len(base) == 0:
         return np.zeros(len(keys))
     ns = np.asarray(ns, np.int64)[:, None]            # (F, 1)
     widths = np.asarray(widths, np.int64)[:, None]
+    base = np.asarray(base, np.int64)[:, None]
+    stride = np.asarray(stride, np.int64)[:, None]
     k2 = keys[None, :]                                # (1, K)
     col = H.hash_mod(k2, np.asarray(col_seeds)[:, None], widths)   # (F, K)
     sub = H.hash_pow2(k2, np.asarray(sub_seeds)[:, None], ns)
-    rows = np.arange(stacked.shape[0])[:, None]
-    raw = stacked[rows, sub, col].astype(np.float64)
+    raw = flat[base + sub * stride + col].astype(np.float64)
     if single_hop and mit is not None and mit.any():
         sub2 = (sub + ns // 2) & (ns - 1)
-        raw2 = stacked[rows, sub2, col].astype(np.float64)
+        raw2 = flat[base + sub2 * stride + col].astype(np.float64)
         use = np.asarray(mit, bool)[:, None] & (ns >= 2)
         raw = np.where(use, 0.5 * (raw + raw2), raw)
     if kind in ("cs", "um"):
@@ -210,7 +221,8 @@ def fleet_query_window(stacked_by_epoch: Sequence[np.ndarray],
                        single_hop: bool = False) -> np.ndarray:
     """Window point-query over fleet stacks: O_Q = Sum(O) of per-epoch
     batched queries — the fleet twin of ``query_window`` with
-    ``merge="fragment"``.
+    ``merge="fragment"``.  Each epoch's counters are a block or a
+    one-epoch ``FlatStack``, as ``fleet_query_epoch`` takes them.
 
     ``params_by_epoch`` carries each epoch's ``(n_rows, N_PARAMS)``
     fleet parameter table (seeds are per-epoch, so the table differs

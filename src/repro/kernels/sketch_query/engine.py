@@ -1,11 +1,14 @@
 """Device-resident batched query plane (paper §4.3): gather + merge over a
 window's stacked counters, without the bulk device->host transfer.
 
-The fleet update path (``kernels/sketch_update/fleet.py``) leaves a whole
-epoch window's counters on device as one ``(E, F, n_sub_max, width_max)``
-f32 stack.  Until now, answering a single point query forced the entire
-stack across the host boundary (megabytes per window) so the numpy query
-plane could gather a handful of counters from it.  FPGA/switch sketch
+The fleet update path (``core/fleet.py``) leaves a whole epoch window's
+counters on device as one flat f32 buffer (``FlatStack``): each subepoch
+group's launch keeps its own ``(rows, n, width)`` extent, and the counter
+of (epoch ``e``, row ``r``, subepoch ``s``, column ``c``) is
+``flat[base[e, r] + s * stride[r] + c]``.  Until now, answering a single
+point query forced the entire stack across the host boundary (megabytes
+per window) so the numpy query plane could gather a handful of counters
+from it.  FPGA/switch sketch
 accelerators answer queries *next to the counters* for exactly this
 reason — the query is a tiny gather, the transfer is the whole sketch.
 
@@ -16,8 +19,8 @@ This module is the TPU twin: one jitted fused pass that
      ``repro.core.hashing`` — the hashing module is backend-polymorphic
      via its ``xp`` parameter, so the *same code* runs here under jnp);
   2. gathers each (epoch, fragment)'s raw estimate
-     ``stack[e, f, sub(e,f,k), col(e,f,k)]`` for all keys at once (one
-     XLA gather over the resident stack);
+     ``flat[base[e, f] + sub(e,f,k) * stride[f] + col(e,f,k)]`` for all
+     keys at once (one XLA gather over the resident buffer);
   3. applies the §4.3 fragment-merge per epoch — min across fragments for
      Count-Min, a masked median for Count Sketch (``frag_sel`` restricts
      the merge to the queried flows' on-path fragments, §4.3 Step 1);
@@ -63,7 +66,7 @@ at ``sub + n/2`` averaged on PARAM_MIT rows (``single_hop=True``).
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -86,30 +89,70 @@ KEY_BUCKET_MIN = 8
 KEY_CHUNK = 1 << 15
 
 
+#: Counters a flat window buffer may hold: every index into it is int32.
+MAX_FLAT = 2 ** 31 - 1
+
+
+class FlatStack(NamedTuple):
+    """A window's counters as one flat f32 buffer.
+
+    The counter of (epoch ``e``, row ``r``, subepoch ``s``, column ``c``)
+    is ``flat[base[e, r] + s * stride[r] + c]``: ``base`` is the (E, R)
+    int64 offset of each (epoch, row) cell, ``stride`` the (R,) counters
+    between a row's subepochs (its launch group's width, at least the
+    row's own width).  A rectangle ``(E, R, S, W)`` is the case of one
+    group (``as_flat``).  On a ``switch`` mesh ``flat`` is sharded over
+    the axis in equal blocks and ``base`` holds offsets into each row's
+    own shard block, with trailing pad rows (base 0).  A host oracle
+    takes one epoch's cells: ``base`` of shape (R,).
+    """
+
+    flat: object
+    base: np.ndarray
+    stride: np.ndarray
+
+
+def as_flat(stack) -> FlatStack:
+    """``stack`` as a ``FlatStack``: returned as it is, or an
+    ``(E, R, S, W)`` rectangle (numpy or jax) viewed flat."""
+    if isinstance(stack, FlatStack):
+        return stack
+    e_count, n_rows, n_sub, width = stack.shape
+    if e_count * n_rows * n_sub * width > MAX_FLAT:
+        raise ValueError(f"a window stack of {stack.shape} counters does "
+                         "not fit int32 offsets")
+    base = ((np.arange(e_count)[:, None] * n_rows + np.arange(n_rows))
+            * (n_sub * width))
+    return FlatStack(stack.reshape(-1), base,
+                     np.full(n_rows, width, np.int64))
+
+
 def key_bucket(n_keys: int) -> int:
     """Power-of-two key-batch bucket, floored at ``KEY_BUCKET_MIN``."""
     return max(KEY_BUCKET_MIN, 1 << max(int(n_keys) - 1, 0).bit_length())
 
 
-def _raw_at(stack, rows, col_seeds, sign_seeds, sub_seeds, ns, widths,
-            use, keys, *, signed: bool):
-    """The gather at given rows: ``rows`` and every per-row argument
-    broadcast to ``(E, X, K)`` (X fleet rows, or a key's path slots);
-    ``use`` is None (no §4.4 average) or the bool mask of the elements
-    that take it.  Returns the (E, X, K) raw estimates (signed,
-    §4.4-averaged, x n scaled)."""
+def _raw_at(flat, base, stride, col_seeds, sign_seeds, sub_seeds, ns,
+            widths, use, keys, *, signed: bool):
+    """The gather at given cells: ``base`` (the cells' offsets in the
+    flat buffer), ``stride`` and every per-row argument broadcast to
+    ``(E, X, K)`` (X fleet rows, or a key's path slots); ``use`` is None
+    (no §4.4 average) or the bool mask of the elements that take it.
+    Returns the (E, X, K) raw estimates (signed, §4.4-averaged, x n
+    scaled).  The index is int32: a window buffer holds fewer than 2^31
+    counters (``MAX_FLAT``, checked where buffers are built)."""
     k = keys[None, None, :]                               # (1, 1, K)
     col = H.hash_mod(k, col_seeds, widths, xp=jnp)        # (E, X, K)
     sub = H.hash_pow2(k, sub_seeds, ns, xp=jnp)
-    e_idx = jnp.arange(stack.shape[0])[:, None, None]
-    raw = stack[e_idx, rows, sub, col]                    # (E, X, K)
+    cell = base + col                                     # (E, X, K)
+    raw = flat[cell + sub * stride]
     if use is not None:
         # §4.4: single-hop flows carry a second subepoch record at
         # sub + n/2 on mitigation rows; average the two (counters are
         # exact f32 integers, so the /2 midpoint is within the same
         # rounding contract as the CS median midpoint).
         sub2 = (sub + (ns >> 1)) & (ns - 1)
-        raw2 = stack[e_idx, rows, sub2, col]
+        raw2 = flat[cell + sub2 * stride]
         raw = jnp.where(use, 0.5 * (raw + raw2), raw)
     if signed:
         raw = raw * H.hash_sign(k, sign_seeds, xp=jnp).astype(jnp.float32)
@@ -118,13 +161,14 @@ def _raw_at(stack, rows, col_seeds, sign_seeds, sub_seeds, ns, widths,
     return raw * ns.astype(jnp.float32)
 
 
-def _gather_raw(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
-                mit_rows, keys, *, signed: bool, mitigate: bool):
-    """Shared gather over every row: (E, R, S, W) stack + (K,) keys ->
-    (E, R, K) raw per-row estimates."""
+def _gather_raw(flat, base, stride, col_seeds, sign_seeds, sub_seeds, ns,
+                widths, mit_rows, keys, *, signed: bool, mitigate: bool):
+    """Shared gather over every row: a flat buffer with its (E, R) cell
+    offsets and (R,) strides + (K,) keys -> (E, R, K) raw per-row
+    estimates."""
     ns = ns[None, :, None]
     use = (mit_rows[None, :, None] & (ns >= 2)) if mitigate else None
-    return _raw_at(stack, jnp.arange(stack.shape[1])[None, :, None],
+    return _raw_at(flat, base[:, :, None], stride[None, :, None],
                    col_seeds[:, :, None], sign_seeds[:, :, None],
                    sub_seeds[:, :, None], ns, widths[None, :, None], use,
                    keys, signed=signed)
@@ -159,11 +203,14 @@ def _masked_merge(raw, frag_sel, *, kind: str):
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "mitigate"))
-def _gather_merge(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
-                  frag_sel, mit_rows, keys, *, kind: str, mitigate: bool):
-    """Fused device pass: (E, R, S, W) stack + (K,) keys -> (K,) window
+def _gather_merge(flat, base, stride, col_seeds, sign_seeds, sub_seeds, ns,
+                  widths, frag_sel, mit_rows, keys, *, kind: str,
+                  mitigate: bool):
+    """Fused device pass: a flat window buffer + (K,) keys -> (K,) window
     estimates (R = fleet rows; fragments, or fragment×level pairs).
 
+    ``base`` is the (E, R) int32 cell offsets and ``stride`` the (R,)
+    int32 subepoch strides (``FlatStack``);
     ``col_seeds``/``sign_seeds``/``sub_seeds`` are (E, R) uint32 (seeds
     are per-epoch); ``ns``/``widths`` are (R,) int32 (frozen across the
     window — the ``run_window`` contract); ``frag_sel`` is (R,) bool, or
@@ -172,13 +219,14 @@ def _gather_merge(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
     the compiled shape independent of the queried path.
     """
     sanitize.note_trace("sketch_query._gather_merge")
-    raw = _gather_raw(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
-                      mit_rows, keys, signed=kind in ("cs", "um"),
+    raw = _gather_raw(flat, base, stride, col_seeds, sign_seeds, sub_seeds,
+                      ns, widths, mit_rows, keys, signed=kind in ("cs", "um"),
                       mitigate=mitigate)
     return _masked_merge(raw, frag_sel, kind=kind).sum(axis=0)  # (K,)
 
 
-def _prep_window_params(stack, params_by_epoch, allow_row_pad: bool = False):
+def _prep_window_params(stack: FlatStack, params_by_epoch,
+                        allow_row_pad: bool = False):
     """Stack + frozen-ns validation shared by the window-query entry
     points.  Returns (params (E, R, N_PARAMS), ns, widths).
 
@@ -188,12 +236,13 @@ def _prep_window_params(stack, params_by_epoch, allow_row_pad: bool = False):
     """
     params = np.stack([np.asarray(p, np.int32) for p in params_by_epoch])
     e_count, n_rows = params.shape[:2]
+    cells = stack.base.shape
     if allow_row_pad:
-        assert stack.shape[0] == e_count and stack.shape[1] >= n_rows, \
-            f"stack {stack.shape} does not cover params ({e_count}, {n_rows})"
+        assert cells[0] == e_count and cells[1] >= n_rows, \
+            f"stack cells {cells} do not cover params ({e_count}, {n_rows})"
     else:
-        assert tuple(stack.shape[:2]) == (e_count, n_rows), \
-            f"stack {stack.shape} does not match params ({e_count}, {n_rows})"
+        assert tuple(cells) == (e_count, n_rows), \
+            f"stack cells {cells} do not match params ({e_count}, {n_rows})"
     ns = params[0, :, PARAM_N_SUB]
     widths = params[0, :, PARAM_WIDTH]
     assert (params[:, :, PARAM_N_SUB] == ns).all() and \
@@ -210,7 +259,8 @@ def fleet_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
     """Batched window point-query on a still-resident window stack.
 
     Args:
-      stack: ``(E, R, n_sub_max, width_max)`` f32 counter stack — a
+      stack: the window's counters: a ``FlatStack`` (a window buffer's
+        layout) or an ``(E, R, n_sub_max, width_max)`` f32 rectangle — a
         device array on TPU (the point: it never transfers), any
         jnp-compatible array on CPU.  R is the fleet's row count
         (fragments; fragment×level pairs for UnivMon).
@@ -230,8 +280,9 @@ def fleet_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
         rows (the queried flows are single-hop — uniform per path
         group).
       mesh: optional ``("switch",)`` device mesh.  When given, the stack
-        is treated as row-sharded over the switch axis (possibly with
-        trailing pad rows so rows divide the axis) and the merge runs as
+        is a ``FlatStack`` sharded over the switch axis, each row's cells
+        in its own shard's block (with trailing pad rows so rows divide
+        the axis), and the merge runs as
         a ``shard_map``: each shard gathers its own rows' raw estimates
         locally and ``all_gather``s only the ``(E, R, K)`` estimate
         slices — never the counter shards — into the same masked
@@ -245,6 +296,8 @@ def fleet_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
     with obs.span("query.prep"):
         keys = np.asarray(keys, dtype=np.uint32)
         n_keys = len(keys)
+        stack = (as_flat(stack) if mesh is None
+                 else _as_sharded_flat(stack, mesh.shape["switch"], 1))
         params, ns, widths = _prep_window_params(
             stack, params_by_epoch, allow_row_pad=mesh is not None)
         n_rows = params.shape[1]
@@ -268,14 +321,17 @@ def fleet_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
         keys_pad = np.zeros(kb, np.uint32)
         keys_pad[:n_keys] = keys
         if mesh is None:
-            host_args = (params[:, :, PARAM_COL_SEED].astype(np.uint32),
+            host_args = (stack.base.astype(np.int32),
+                         stack.stride.astype(np.int32),
+                         params[:, :, PARAM_COL_SEED].astype(np.uint32),
                          params[:, :, PARAM_SIGN_SEED].astype(np.uint32),
                          params[:, :, PARAM_SUB_SEED].astype(np.uint32),
                          ns.astype(np.int32), widths.astype(np.int32),
                          frag_sel, mit_rows, keys_pad)
-            # a device-resident stack crosses nothing
+            # a device-resident buffer crosses nothing
             h2d = sum(a.nbytes for a in host_args) + (
-                stack.nbytes if isinstance(stack, np.ndarray) else 0)
+                stack.flat.nbytes if isinstance(stack.flat, np.ndarray)
+                else 0)
     if mesh is not None:
         est = _sharded_window_query(mesh, stack, params, ns, widths, sel2,
                                     mit_rows, keys_pad, kind=kind,
@@ -290,7 +346,8 @@ def fleet_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
     with sanitize.transfer_guard():
         with obs.span("query.launch", h2d_bytes=h2d, keys=n_keys):
             out = _gather_merge(
-                jnp.asarray(stack), *(jnp.asarray(a) for a in host_args),
+                jnp.asarray(stack.flat),
+                *(jnp.asarray(a) for a in host_args),
                 kind=kind, mitigate=mitigate)
         # KB floats across the boundary — the only counters-derived
         # bytes that ever leave the device on this path
@@ -332,21 +389,21 @@ def _slot_merge(raw, valid, *, kind: str):
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "mitigate"))
-def _gather_merge_rows(stack, row_tab, path_rows, path_hop, keys, path_id,
+def _gather_merge_rows(flat, row_tab, path_rows, path_hop, keys, path_id,
                        *, kind: str, mitigate: bool):
-    """Fused device pass over each key's own on-path rows: (E, R, S, W)
-    stack + (K,) keys of many paths -> (K,) window estimates.
+    """Fused device pass over each key's own on-path rows: a flat window
+    buffer + (K,) keys of many paths -> (K,) window estimates.
 
-    ``row_tab`` is the (R, 3E + 4) uint32 per-row table (``_row_table``);
+    ``row_tab`` is the (R, 4E + 4) uint32 per-row table (``_row_table``);
     ``path_rows`` the (P, L) int32 fleet rows of each path, -1 in unused
     slots; ``path_hop`` the (P,) bool single-hop flag of each path;
-    ``path_id`` the (K,) int32 path of each key.  Each key's rows, seeds,
-    ``n`` and width come from one row gather of its path's (L, columns)
-    block, so the stack gather reads (E, L, K) counters, L the longest
-    path, never the (E, R, K) of every row.
+    ``path_id`` the (K,) int32 path of each key.  Each key's cells,
+    seeds, ``n`` and width come from one row gather of its path's (L,
+    columns) block, so the buffer gather reads (E, L, K) counters, L the
+    longest path, never the (E, R, K) of every row.
     """
     sanitize.note_trace("sketch_query._gather_merge_rows")
-    e_count = stack.shape[0]
+    e_count = (row_tab.shape[1] - 4) // 4
     n_paths, n_slots = path_rows.shape
     valid = path_rows >= 0
     ptab = jnp.concatenate(
@@ -357,26 +414,25 @@ def _gather_merge_rows(stack, row_tab, path_rows, path_hop, keys, path_id,
     n_cols = ptab.shape[-1]
     per_key = ptab.reshape(n_paths, n_slots * n_cols)[path_id]
     cols = per_key.T.reshape(n_slots, n_cols, -1).transpose(1, 0, 2)
-    col_seeds, sign_seeds, sub_seeds = (
-        cols[i * e_count:(i + 1) * e_count] for i in range(3))  # (E, L, K)
-    ns, widths, mit, rows, valid, hop = (
-        cols[3 * e_count + i] for i in range(6))          # (L, K) each
-    ns = ns.astype(jnp.int32)[None]
-    use = ((mit != 0) & (hop != 0))[None] & (ns >= 2) if mitigate else None
-    raw = _raw_at(stack, rows.astype(jnp.int32)[None], col_seeds,
-                  sign_seeds, sub_seeds, ns, widths.astype(jnp.int32)[None],
-                  use, keys, signed=kind in ("cs", "um"))
-    return _slot_merge(raw, valid != 0, kind=kind).sum(axis=0)  # (K,)
+    col_seeds, sign_seeds, sub_seeds, base = (
+        cols[i * e_count:(i + 1) * e_count] for i in range(4))  # (E, L, K)
+    ns, widths, mit, stride, valid, hop = (
+        cols[4 * e_count + i].astype(jnp.int32)[None]
+        for i in range(6))                                # (1, L, K) each
+    use = (mit != 0) & (hop != 0) & (ns >= 2) if mitigate else None
+    raw = _raw_at(flat, base.astype(jnp.int32), stride, col_seeds,
+                  sign_seeds, sub_seeds, ns, widths, use, keys,
+                  signed=kind in ("cs", "um"))
+    return _slot_merge(raw, valid[0] != 0, kind=kind).sum(axis=0)  # (K,)
 
 
-def _row_table(params, ns, widths) -> np.ndarray:
-    """(R, 3E + 4) uint32 table of one window stack's rows: the E column,
-    sign and subepoch seeds, then ``n``, width, the §4.4 mitigation flag
-    and the row's own index."""
-    e_count, n_rows = params.shape[:2]
+def _row_table(params, ns, widths, stack: FlatStack) -> np.ndarray:
+    """(R, 4E + 4) uint32 table of one window buffer's rows: the E
+    column, sign and subepoch seeds and the E cell offsets, then ``n``,
+    width, the §4.4 mitigation flag and the subepoch stride."""
     per_epoch = [params[:, :, c].T for c in (PARAM_COL_SEED, PARAM_SIGN_SEED,
-                                             PARAM_SUB_SEED)]
-    per_row = [ns, widths, params[0, :, PARAM_MIT] != 0, np.arange(n_rows)]
+                                             PARAM_SUB_SEED)] + [stack.base.T]
+    per_row = [ns, widths, params[0, :, PARAM_MIT] != 0, stack.stride]
     return np.concatenate(per_epoch + [np.stack(per_row, axis=1)],
                           axis=1).astype(np.uint32)
 
@@ -444,13 +500,14 @@ def fleet_window_query_paths(stacks, keys: np.ndarray,
         hop_pad[:n_paths] = hop
         plans = []
         for stack, params_by_epoch in stacks:
+            stack = as_flat(stack)
             params, ns, widths = _prep_window_params(stack, params_by_epoch)
             if path_rows.max() >= params.shape[1]:
                 raise ValueError(
                     f"fleet_window_query_paths: path rows exceed the "
                     f"stack's {params.shape[1]} rows")
             mit_rows = params[0, :, PARAM_MIT] != 0
-            plans.append((stack, _row_table(params, ns, widths),
+            plans.append((stack.flat, _row_table(params, ns, widths, stack),
                           bool(hop.any()) and bool(mit_rows.any())))
     outs = []
     keys_dev: list = [None] * n_chunks
@@ -459,13 +516,16 @@ def fleet_window_query_paths(stacks, keys: np.ndarray,
     # Explicit crossings only (jnp.asarray in, one jax.device_get out),
     # as in fleet_window_query_device.
     with sanitize.transfer_guard():
-        for stack, row_tab, mitigate in plans:
+        for flat, row_tab, mitigate in plans:
             tab_dev = None
             for c in range(n_chunks):
                 real = min(chunk, n_keys - c * chunk)
                 with obs.span("query.launch", keys=real,
                               paths=n_paths) as sp:
-                    sent = [stack] if isinstance(stack, np.ndarray) else []
+                    sent = []
+                    if isinstance(flat, np.ndarray):
+                        sent.append(flat)
+                        flat = jnp.asarray(flat)
                     if paths_dev is None:
                         sent += [rows_pad, hop_pad]
                         paths_dev = (jnp.asarray(rows_pad),
@@ -479,7 +539,7 @@ def fleet_window_query_paths(stacks, keys: np.ndarray,
                         keys_dev[c] = jnp.asarray(keys_pad[part])
                         pid_dev[c] = jnp.asarray(pid_pad[part])
                     outs.append(_gather_merge_rows(
-                        jnp.asarray(stack), tab_dev, *paths_dev,
+                        flat, tab_dev, *paths_dev,
                         keys_dev[c], pid_dev[c], kind=kind,
                         mitigate=mitigate))
                     sp.set_metadata(h2d_bytes=sum(a.nbytes for a in sent))
@@ -493,10 +553,10 @@ def fleet_window_query_paths(stacks, keys: np.ndarray,
 
 
 @functools.partial(jax.jit, static_argnames=("n_levels",))
-def _gather_merge_um(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
-                     frag_sel, keys, *, n_levels: int):
-    """All-levels UnivMon pass: (E, F*L, S, W) stack + (K,) keys ->
-    (L, K) per-level window estimates.
+def _gather_merge_um(flat, base, stride, col_seeds, sign_seeds, sub_seeds,
+                     ns, widths, frag_sel, keys, *, n_levels: int):
+    """All-levels UnivMon pass: a flat window buffer of F*L rows + (K,)
+    keys -> (L, K) per-level window estimates.
 
     One gather covers every (epoch, fragment, level) row at once —
     the per-level seed mixing already happened at param-build time
@@ -506,10 +566,10 @@ def _gather_merge_um(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
     on-path mask), and the window sum is O_Q = Sum(O) per level.
     """
     sanitize.note_trace("sketch_query._gather_merge_um")
-    e_count, n_rows = stack.shape[:2]
+    e_count, n_rows = base.shape
     n_frags = n_rows // n_levels
-    raw = _gather_raw(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
-                      None, keys, signed=True, mitigate=False)
+    raw = _gather_raw(flat, base, stride, col_seeds, sign_seeds, sub_seeds,
+                      ns, widths, None, keys, signed=True, mitigate=False)
     # (E, F, L, K) -> merge over fragments per level: move L into the
     # epoch axis so the shared (axis-1) masked median applies unchanged.
     raw = (raw.reshape(e_count, n_frags, n_levels, -1)
@@ -531,8 +591,9 @@ def um_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
     batch in ONE batched device call (the §6.2 G-sum inputs).
 
     Args:
-      stack: ``(E, F * n_levels, n_sub_max, width_max)`` still-resident
-        window stack (virtual level rows, fragment-major).
+      stack: the still-resident window's ``FlatStack`` (or its
+        ``(E, F * n_levels, n_sub_max, width_max)`` rectangle) of virtual
+        level rows, fragment-major.
       params_by_epoch: E host ``(F * n_levels, N_PARAMS)`` tables with
         per-level mixed seeds (``core.fleet.build_params``).
       keys: (K,) uint32 key batch.
@@ -549,6 +610,8 @@ def um_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
     """
     keys = np.asarray(keys, dtype=np.uint32)
     n_keys = len(keys)
+    stack = (as_flat(stack) if mesh is None
+             else _as_sharded_flat(stack, mesh.shape["switch"], n_levels))
     params, ns, widths = _prep_window_params(stack, params_by_epoch,
                                              allow_row_pad=mesh is not None)
     n_rows = params.shape[1]
@@ -579,7 +642,8 @@ def um_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
     # out, host-side slicing.
     with sanitize.transfer_guard():
         out = _gather_merge_um(
-            jnp.asarray(stack),
+            jnp.asarray(stack.flat), jnp.asarray(stack.base.astype(np.int32)),
+            jnp.asarray(stack.stride.astype(np.int32)),
             jnp.asarray(params[:, :, PARAM_COL_SEED].astype(np.uint32)),
             jnp.asarray(params[:, :, PARAM_SIGN_SEED].astype(np.uint32)),
             jnp.asarray(params[:, :, PARAM_SUB_SEED].astype(np.uint32)),
@@ -593,13 +657,15 @@ def um_window_query_device(stack, params_by_epoch: Sequence[np.ndarray],
 
 # --- cross-device sharded merge (the "switch" mesh axis) -------------------
 #
-# The fleet runner can shard a window stack's rows over a 1-D ("switch",)
+# The fleet runner can shard a window buffer's rows over a 1-D ("switch",)
 # device mesh (fragments are the shard unit — a fragment's n_levels
 # virtual rows never split; trailing *pad fragments* make the row count
-# divide the axis).  The merge below is the cross-device twin of
-# `_gather_merge`: every shard runs `_gather_raw` on its LOCAL rows only,
-# then `all_gather`s the tiny (E, R_local, K) raw per-row estimate slices
-# — never the (E, R_local, S, W) counter shards — so the full-row masked
+# divide the axis): each shard's cells lie in its own block of the flat
+# buffer, at offsets local to that block.  The merge below is the
+# cross-device twin of `_gather_merge`: every shard runs `_gather_raw` on
+# its LOCAL rows and block only, then `all_gather`s the tiny (E, R_local,
+# K) raw per-row estimate slices — never the counter blocks — so the
+# full-row masked
 # min/median merge (and nothing else) is replicated.  The gather is
 # elementwise per row and `all_gather(tiled=True)` concatenates shard
 # blocks in exactly the single-device row order, so the merged estimates
@@ -614,6 +680,25 @@ def shard_padded_rows(n_rows: int, n_shards: int, n_levels: int = 1) -> int:
     assert rem == 0, (n_rows, n_levels)
     f_pad = -(-n_frags // int(n_shards)) * int(n_shards)
     return f_pad * int(n_levels)
+
+
+def _as_sharded_flat(stack, n_shards: int, n_levels: int) -> FlatStack:
+    """``stack`` as a ``FlatStack`` for a ``switch`` mesh: returned as it
+    is, or an ``(E, R, S, W)`` rectangle with its rows padded to
+    ``shard_padded_rows`` and laid out as one equal block per shard (the
+    shard's rows of every epoch), at offsets local to the block."""
+    if isinstance(stack, FlatStack):
+        return stack
+    e_count, n_rows, n_sub, width = stack.shape
+    r_pad = shard_padded_rows(n_rows, n_shards, n_levels)
+    xp = np if isinstance(stack, np.ndarray) else jnp
+    stack = xp.pad(stack, ((0, 0), (0, r_pad - n_rows), (0, 0), (0, 0)))
+    rps = r_pad // n_shards
+    flat = (stack.reshape(e_count, n_shards, rps, n_sub * width)
+            .transpose(1, 0, 2, 3).reshape(-1))
+    base = ((np.arange(e_count)[:, None] * rps + np.arange(r_pad) % rps)
+            * (n_sub * width))
+    return FlatStack(flat, base, np.full(r_pad, width, np.int64))
 
 
 def _pad_rows(a, r_pad: int, fill, axis: int = -1):
@@ -636,11 +721,11 @@ def _sharded_gather_merge(mesh, kind: str, mitigate: bool, n_rows: int):
     row = P(None, "switch")
     per_row = P("switch")
 
-    def body(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
-             frag_sel, mit_rows, keys):
+    def body(flat, base, stride, col_seeds, sign_seeds, sub_seeds, ns,
+             widths, frag_sel, mit_rows, keys):
         sanitize.note_trace("sketch_query._sharded_gather_merge")
-        raw = _gather_raw(stack, col_seeds, sign_seeds, sub_seeds, ns,
-                          widths, mit_rows, keys,
+        raw = _gather_raw(flat, base, stride, col_seeds, sign_seeds,
+                          sub_seeds, ns, widths, mit_rows, keys,
                           signed=kind in ("cs", "um"), mitigate=mitigate)
         # Only the (E, R_local, K) raw estimates cross devices.
         raw = jax.lax.all_gather(raw, "switch", axis=1, tiled=True)
@@ -649,7 +734,7 @@ def _sharded_gather_merge(mesh, kind: str, mitigate: bool, n_rows: int):
 
     return jax.jit(jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(None, "switch", None, None), row, row, row,
+        in_specs=(per_row, row, per_row, row, row, row,
                   per_row, per_row, P(), per_row, P()),
         out_specs=P(), check_vma=False))
 
@@ -660,17 +745,8 @@ def _sharded_window_query(mesh, stack, params, ns, widths, sel2, mit_rows,
     columns to the stack's padded row count, commit every input to the
     mesh explicitly (legal under the armed transfer guard), run the
     shard_map merge, fetch the (KB,) estimates."""
-    n_shards = mesh.shape["switch"]
     e_count, n_rows = params.shape[:2]
-    want = shard_padded_rows(n_rows, n_shards)
-    if int(stack.shape[1]) < want:
-        # unpadded (host) caller: zero rows shard like any other pad
-        stack = _pad_rows(stack, want, 0.0, axis=1)
-    r_pad = int(stack.shape[1])
-    if r_pad % n_shards or r_pad < n_rows:
-        raise ValueError(
-            f"sharded stack rows {r_pad} do not cover {n_rows} param rows "
-            f"in multiples of the switch axis ({n_shards})")
+    r_pad = _sharded_rows(mesh, stack, n_rows, 1)
     col = _pad_rows(params[:, :, PARAM_COL_SEED].astype(np.uint32), r_pad, 0)
     sign = _pad_rows(params[:, :, PARAM_SIGN_SEED].astype(np.uint32), r_pad, 0)
     sub = _pad_rows(params[:, :, PARAM_SUB_SEED].astype(np.uint32), r_pad, 0)
@@ -687,13 +763,37 @@ def _sharded_window_query(mesh, stack, params, ns, widths, sel2, mit_rows,
     fn = _sharded_gather_merge(mesh, kind, bool(mitigate), n_rows)
     with sanitize.transfer_guard():
         out = fn(
-            jax.device_put(jnp.asarray(stack),
-                           NamedSharding(mesh, P(None, "switch", None, None))),
+            *_sharded_cells(mesh, stack),
             jax.device_put(col, row_sh), jax.device_put(sign, row_sh),
             jax.device_put(sub, row_sh), jax.device_put(ns_p, per_row_sh),
             jax.device_put(w_p, per_row_sh), jax.device_put(sel_full, rep),
             jax.device_put(mit_p, per_row_sh), jax.device_put(keys_pad, rep))
         return jax.device_get(out)
+
+
+def _sharded_rows(mesh, stack: FlatStack, n_rows: int, n_levels: int) -> int:
+    """The padded row count of a sharded window buffer's cells, checked
+    to cover the ``n_rows`` param rows in level-aligned multiples of the
+    switch axis."""
+    n_shards = mesh.shape["switch"]
+    r_pad = int(stack.base.shape[1])
+    if (r_pad < shard_padded_rows(n_rows, n_shards, n_levels)
+            or r_pad % n_shards or r_pad % n_levels):
+        raise ValueError(
+            f"sharded buffer rows {r_pad} do not cover {n_rows} param rows "
+            f"in level-aligned multiples of the switch axis ({n_shards} "
+            f"shards, {n_levels} levels)")
+    return r_pad
+
+
+def _sharded_cells(mesh, stack: FlatStack):
+    """The buffer's flat counters, cell offsets and strides committed to
+    the mesh: the counters stay where they are when already sharded."""
+    return (jax.device_put(stack.flat, NamedSharding(mesh, P("switch"))),
+            jax.device_put(stack.base.astype(np.int32),
+                           NamedSharding(mesh, P(None, "switch"))),
+            jax.device_put(stack.stride.astype(np.int32),
+                           NamedSharding(mesh, P("switch"))))
 
 
 @functools.lru_cache(maxsize=None)
@@ -703,13 +803,14 @@ def _sharded_gather_merge_um(mesh, n_levels: int, n_rows: int):
     row = P(None, "switch")
     per_row = P("switch")
 
-    def body(stack, col_seeds, sign_seeds, sub_seeds, ns, widths,
-             frag_sel, keys):
+    def body(flat, base, stride, col_seeds, sign_seeds, sub_seeds, ns,
+             widths, frag_sel, keys):
         sanitize.note_trace("sketch_query._sharded_gather_merge_um")
-        e_count = stack.shape[0]
+        e_count = base.shape[0]
         n_frags = n_rows // n_levels
-        raw = _gather_raw(stack, col_seeds, sign_seeds, sub_seeds, ns,
-                          widths, None, keys, signed=True, mitigate=False)
+        raw = _gather_raw(flat, base, stride, col_seeds, sign_seeds,
+                          sub_seeds, ns, widths, None, keys, signed=True,
+                          mitigate=False)
         raw = jax.lax.all_gather(raw, "switch", axis=1, tiled=True)
         raw = (raw[:, :n_rows]
                .reshape(e_count, n_frags, n_levels, -1)
@@ -721,7 +822,7 @@ def _sharded_gather_merge_um(mesh, n_levels: int, n_rows: int):
 
     return jax.jit(jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(None, "switch", None, None), row, row, row,
+        in_specs=(per_row, row, per_row, row, row, row,
                   per_row, per_row, P(), P()),
         out_specs=P(), check_vma=False))
 
@@ -729,17 +830,8 @@ def _sharded_gather_merge_um(mesh, n_levels: int, n_rows: int):
 def _sharded_um_query(mesh, stack, params, ns, widths, sel2, keys_pad, *,
                       n_levels: int):
     """Mesh leg of ``um_window_query_device``."""
-    n_shards = mesh.shape["switch"]
     e_count, n_rows = params.shape[:2]
-    want = shard_padded_rows(n_rows, n_shards, n_levels)
-    if int(stack.shape[1]) < want:
-        stack = _pad_rows(stack, want, 0.0, axis=1)
-    r_pad = int(stack.shape[1])
-    if r_pad % n_shards or r_pad < n_rows or r_pad % n_levels:
-        raise ValueError(
-            f"sharded um stack rows {r_pad} do not cover {n_rows} param "
-            f"rows in level-aligned multiples of the switch axis "
-            f"({n_shards} shards, {n_levels} levels)")
+    r_pad = _sharded_rows(mesh, stack, n_rows, n_levels)
     col = _pad_rows(params[:, :, PARAM_COL_SEED].astype(np.uint32), r_pad, 0)
     sign = _pad_rows(params[:, :, PARAM_SIGN_SEED].astype(np.uint32), r_pad, 0)
     sub = _pad_rows(params[:, :, PARAM_SUB_SEED].astype(np.uint32), r_pad, 0)
@@ -754,8 +846,7 @@ def _sharded_um_query(mesh, stack, params, ns, widths, sel2, keys_pad, *,
     fn = _sharded_gather_merge_um(mesh, int(n_levels), n_rows)
     with sanitize.transfer_guard():
         out = fn(
-            jax.device_put(jnp.asarray(stack),
-                           NamedSharding(mesh, P(None, "switch", None, None))),
+            *_sharded_cells(mesh, stack),
             jax.device_put(col, row_sh), jax.device_put(sign, row_sh),
             jax.device_put(sub, row_sh), jax.device_put(ns_p, per_row_sh),
             jax.device_put(w_p, per_row_sh), jax.device_put(sel_full, rep),

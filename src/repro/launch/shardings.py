@@ -189,43 +189,6 @@ def decode_state_specs(cfg, batch: int, mesh, *, seq_shard: bool = False):
     return MDL.DecodeState(tuple(caches), P())
 
 
-# --- fragment-fleet specs (the "switch" mesh axis) -------------------------
-#
-# The fleet's unit of sharding is the *row* of the param table: fragments,
-# epochs, and UnivMon levels are all rows, and a fragment's rows (its
-# n_levels virtual level rows, across every epoch of a window) always live
-# on one shard.  docs/sharding.md has the layout and bit-identity argument.
-
-#: (E, rows_per_epoch, n_sub_max, width_max) window stacks: rows over
-#: "switch", everything else local.
-FLEET_STACK_SPEC = P(None, "switch", None, None)
-
-#: (E, rows_per_epoch) per-row param columns (seeds, ns, widths) as used by
-#: the device query plane.
-FLEET_ROW_SPEC = P(None, "switch")
-
-#: Flat CSR packet segments: packets are routed to the owning shard at
-#: ``pack_csr`` time (each shard packs its own fragments' streams), so the
-#: per-shard segments are *local by construction*; this spec describes the
-#: equal-blocks concatenation when one global segment is materialized.
-FLEET_CSR_SPEC = P("switch")
-
-
-def fleet_stack_sharding(mesh) -> NamedSharding:
-    """NamedSharding for a (E, rows_per_epoch, S, W) window stack."""
-    return NamedSharding(mesh, _filter(mesh, FLEET_STACK_SPEC))
-
-
-def fleet_row_sharding(mesh) -> NamedSharding:
-    """NamedSharding for (E, rows_per_epoch) per-row param columns."""
-    return NamedSharding(mesh, _filter(mesh, FLEET_ROW_SPEC))
-
-
-def fleet_csr_sharding(mesh) -> NamedSharding:
-    """NamedSharding for an equal-blocks global CSR packet segment."""
-    return NamedSharding(mesh, _filter(mesh, FLEET_CSR_SPEC))
-
-
 def tree_shardings(spec_tree, mesh):
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s), spec_tree,

@@ -274,16 +274,16 @@ def test_ragged_default_geometry_matches_dense(signed):
 
 
 def test_grouped_dispatch_matches_single_launch():
-    """dispatch_ragged_grouped (the production default: one launch per
-    distinct n_sub, zero subepoch-row padding) is bit-identical to the
-    single-launch ragged path — per epoch and across a frozen-ns
-    window."""
+    """dispatch_ragged_grouped (the production path: one launch per
+    distinct n_sub, zero subepoch-row padding, each group at its own
+    extent in the window buffer's layout) is bit-identical to the
+    single-launch ragged rectangle — per epoch and across a frozen-ns
+    window — and drives the same system trajectory as the loop."""
     from repro.core.fleet import dispatch_ragged_grouped
 
     _, _, _, params, widths, nsubs = _fleet_inputs(5, 700)
     blk = 128
-    kw = dict(n_sub_max=16, width_max=1000, log2_te=LOG2_TE, signed=True,
-              interpret=True)
+    kw = dict(log2_te=LOG2_TE, signed=True, interpret=True)
     pkts = [_ragged_packet([700, 3, 0, 130, 257], seed=4),
             _ragged_packet([31, 257, 700, 0, 65], seed=7)]
     # window: rows are (epoch, fragment) pairs with per-epoch seeds
@@ -292,17 +292,28 @@ def test_grouped_dispatch_matches_single_launch():
     fkeys, fvals, fts, block_frag = pack_csr(pkts, blk)
     single = np.asarray(FK.fleet_update_ragged(
         jnp.asarray(fkeys), jnp.asarray(fvals), jnp.asarray(fts),
-        jnp.asarray(params_w), jnp.asarray(block_frag), blk=blk, **kw))
-    grouped = np.asarray(dispatch_ragged_grouped(
-        params_w, pkts, blk=blk, **kw))
-    np.testing.assert_array_equal(grouped, single)
-    # runner-level: grouping on/off drives the same system trajectory
+        jnp.asarray(params_w), jnp.asarray(block_frag), blk=blk,
+        n_sub_max=16, width_max=1000, **kw))
+    outs, layout, _, _ = dispatch_ragged_grouped(params_w, pkts, blk=blk,
+                                                 **kw)
+    assert [o.shape for o in outs] == [
+        (2 * len(g.frags), g.n, g.width) for g in layout.groups]
+    flat = np.concatenate([np.asarray(o).ravel() for o in outs])
+    assert len(flat) == layout.size
+    for e in range(2):
+        for f in range(5):
+            n, w = nsubs[f], widths[f]
+            start, shape = layout.cell(e, f, f + 1)
+            cell = flat[start:start + np.prod(shape)].reshape(shape)[0]
+            np.testing.assert_array_equal(cell[:, :w],
+                                          single[e * 5 + f, :n, :w])
+            assert not single[e * 5 + f, n:].any()
+            assert not cell[:, w:].any()
+    # runner-level: the grouped fleet drives the loop's trajectory
     wl, rep, mems = _small_workload(n_epochs=2)
     a = DiSketchSystem(mems, "cms", rho_target=4.0, log2_te=wl.log2_te,
                        backend="fleet", fleet_kwargs=FLEET_KW)
-    b = DiSketchSystem(mems, "cms", rho_target=4.0, log2_te=wl.log2_te,
-                       backend="fleet",
-                       fleet_kwargs=dict(group_by_n_sub=False, **FLEET_KW))
+    b = DiSketchSystem(mems, "cms", rho_target=4.0, log2_te=wl.log2_te)
     rep.run(a)
     rep.run(b)
     assert a.ns == b.ns

@@ -52,6 +52,15 @@ def _pair(kind, n_dev, **kw):
     return _system(kind, None, **kw), _system(kind, mesh, **kw)
 
 
+def _assert_same_cells(ref, sh, epochs=EPOCHS):
+    """Every (epoch, fragment) cell of the two fleets is bit-identical
+    (their window buffers lay the cells out at other offsets)."""
+    for e in epochs:
+        for sw in ref.fleet.frag_order:
+            assert np.array_equal(ref.fleet.cell_counters(e, sw),
+                                  sh.fleet.cell_counters(e, sw))
+
+
 def _run_both(ref, sh, e_count=3, **kw):
     for s in (ref, sh):
         s.run_window(0, [_streams(e) for e in range(e_count)], **kw)
@@ -74,9 +83,7 @@ def test_counters_and_queries_bit_identical(kind, n_dev, multidevice):
     a1 = ref.query_flows(KEYS, [(3,)] * len(KEYS), EPOCHS, merge="fragment")
     b1 = sh.query_flows(KEYS, [(3,)] * len(KEYS), EPOCHS, merge="fragment")
     assert np.array_equal(a1, b1)
-    for e in EPOCHS:
-        assert np.array_equal(ref.fleet._host_stack(e),
-                              sh.fleet._host_stack(e))
+    _assert_same_cells(ref, sh)
 
 
 @pytest.mark.parametrize("n_dev", [4, 8])
@@ -95,9 +102,7 @@ def test_um_levels_and_entropy_bit_identical(n_dev, multidevice):
     fa = ref.query_flows(KEYS, paths, EPOCHS, merge="fragment")
     fb = sh.query_flows(KEYS, paths, EPOCHS, merge="fragment")
     assert np.array_equal(fa, fb)
-    for e in EPOCHS:
-        assert np.array_equal(ref.fleet._host_stack(e),
-                              sh.fleet._host_stack(e))
+    _assert_same_cells(ref, sh)
 
 
 def test_churn_mask_parity_and_blind_raise(multidevice):
@@ -134,9 +139,7 @@ def test_parity_recovery_shard_local(multidevice):
     a = ref.query_flows(KEYS, [PATH] * len(KEYS), EPOCHS, merge="fragment")
     b = sh.query_flows(KEYS, [PATH] * len(KEYS), EPOCHS, merge="fragment")
     assert np.array_equal(a, b)
-    for e in EPOCHS:
-        assert np.array_equal(ref.fleet._host_stack(e),
-                              sh.fleet._host_stack(e))
+    _assert_same_cells(ref, sh)
 
 
 def test_parity_group_spanning_shards_rejected(multidevice):
@@ -168,3 +171,118 @@ def test_mesh_requires_fleet_backend_and_switch_axis(multidevice):
     with pytest.raises(ValueError, match="switch"):
         FleetEpochRunner(_system("cms", None).fragments, 12,
                          mesh=jax.make_mesh((2,), ("data",)))
+
+
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+def test_shard_launches_run_on_own_device(n_dev, multidevice, monkeypatch):
+    """Each shard's grouped launches run on the device that holds its
+    block of the window buffer, not on the default device."""
+    import repro.core.fleet as fleet_mod
+    from repro.kernels.sketch_update import fleet as FK
+
+    launched = []
+    launch = FK.fleet_update_ragged
+
+    def recording(*a, **kw):
+        out = launch(*a, **kw)
+        launched.append(next(iter(out.devices())))
+        return out
+
+    monkeypatch.setattr(FK, "fleet_update_ragged", recording)
+    sh = _system("cs", make_switch_mesh(n_dev))
+    spans = []
+    span = fleet_mod.obs.span
+
+    def recording_span(name, **counts):
+        if name == "fleet.shard":
+            spans.append(counts)
+        return span(name, **counts)
+
+    monkeypatch.setattr(fleet_mod.obs, "span", recording_span)
+    sh.run_window(0, [_streams(e) for e in range(3)])
+    fleet = sh.fleet
+    buf = fleet._window_bufs[0][0]
+    by_block = {(idx[0].start or 0) // buf.shard_len: d for d, idx in
+                buf.device().sharding.addressable_devices_indices_map(
+                    buf.device().shape).items()}
+    want = [by_block[s["shard"]] for s in spans]
+    assert [s["device"] for s in spans] == [d.id for d in want]
+    assert len(set(want)) == len(spans)      # one device per shard
+    # every group launch of a shard ran on that shard's device
+    assert set(launched) == set(want) and len(launched) >= len(spans)
+    for shard in buf.device().addressable_shards:
+        assert shard.data.shape == (buf.shard_len,)
+
+
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+def test_run_window_reads_only_peaks_and_pebs(n_dev, multidevice,
+                                              monkeypatch):
+    """run_window on a mesh moves no counters to the host: the only
+    device-to-host read is one ``jax.device_get`` of every shard's peak
+    and PEBs, and no device array is converted implicitly."""
+    import jax
+    from jax._src.array import ArrayImpl
+
+    sh = _system("cms", make_switch_mesh(n_dev))
+    streams = [_streams(e) for e in range(3)]
+    allowed, reads = [False], []
+    to_np, buffer = ArrayImpl.__array__, ArrayImpl.__buffer__
+    value = ArrayImpl._value
+    device_get = jax.device_get
+
+    def check():
+        assert allowed[0], "implicit device-to-host transfer"
+
+    def guarded_array(self, *a, **kw):
+        check()
+        return to_np(self, *a, **kw)
+
+    def guarded_buffer(self, *a, **kw):
+        check()
+        return buffer(self, *a, **kw)
+
+    def read(x):
+        allowed[0] = True
+        try:
+            got = device_get(x)
+        finally:
+            allowed[0] = False
+        reads.append(sum(np.size(a) for a in jax.tree.leaves(got)))
+        return got
+
+    monkeypatch.setattr(ArrayImpl, "__array__", guarded_array)
+    monkeypatch.setattr(ArrayImpl, "__buffer__", guarded_buffer)
+    monkeypatch.setattr(ArrayImpl, "_value", property(
+        lambda self: check() or value.fget(self)))
+    monkeypatch.setattr(jax, "device_get", read)
+    with jax.transfer_guard_device_to_host("disallow"):
+        sh.run_window(0, streams)
+    monkeypatch.undo()
+    live = sum(lo < hi for lo, hi in sh.fleet._shard_frag_bounds)
+    # one read: a peak per non-empty shard and a PEB per (epoch, switch)
+    assert reads == [live + 3 * N_SW]
+    assert sh.fleet._window_bufs[0][0]._host is None
+
+
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+def test_one_shard_span_per_nonempty_shard(n_dev, multidevice, monkeypatch):
+    """``repro.fleet.shard`` wraps each non-empty shard's launches, once
+    a window, with its rows and packets."""
+    import repro.core.fleet as fleet_mod
+
+    spans = []
+    span = fleet_mod.obs.span
+
+    def recording_span(name, **counts):
+        if name == "fleet.shard":
+            spans.append(counts)
+        return span(name, **counts)
+
+    monkeypatch.setattr(fleet_mod.obs, "span", recording_span)
+    sh = _system("cs", make_switch_mesh(n_dev))
+    for e0 in (0, 2):
+        sh.run_window(e0, [_streams(e) for e in (e0, e0 + 1)])
+    bounds = [(s, lo, hi) for s, (lo, hi)
+              in enumerate(sh.fleet._shard_frag_bounds) if lo < hi]
+    assert [(c["shard"], c["rows"]) for c in spans] == [
+        (s, 2 * (hi - lo)) for s, lo, hi in bounds] * 2

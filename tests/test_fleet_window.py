@@ -171,8 +171,103 @@ def test_peb_fleet_device_matches_host():
     for f in range(6):
         stacked[f, ns[f]:, :] = 0
         stacked[f, :, widths[f]:] = 0
+    # the window join reads them off each subepoch group's launch, at
+    # the group's own (n, widest width) extent, in group order
+    from repro.core.fleet import _join_groups
+
+    groups = [np.flatnonzero(ns == n) for n in np.unique(ns)]
+    outs = tuple(stacked[g, :ns[g[0]], :widths[g].max()].astype(np.float32)
+                 for g in groups)
     for kind in ("cs", "cms"):
         host = equalize.peb_fleet(stacked, ns, widths, kind)
-        dev = np.asarray(equalize.peb_fleet_device(
-            stacked.astype(np.float32), ns, widths, kind))
-        np.testing.assert_allclose(dev, host, rtol=1e-5)
+        flat, peak, dev = _join_groups(
+            outs, tuple(widths[g].astype(np.int32) for g in groups),
+            kind=kind, n_levels=1, size=sum(o.size for o in outs) + 7)
+        np.testing.assert_allclose(np.asarray(dev), host[np.concatenate(groups)],
+                                   rtol=1e-5)
+        assert float(peak) == np.abs(stacked).max()
+        assert flat.shape == (sum(o.size for o in outs) + 7,)
+
+
+# --- the window buffer's layout: each n_sub group at its own extent -----
+
+#: Skewed widths (64 to 6000 counters) and, per window, forced subepoch
+#: counts that give groups of every n in {1, 2, 4, 8, 16}.
+LAYOUT_WIDTHS = [6000, 64, 1500, 300, 3000, 700]
+LAYOUT_NS = [[1, 2, 4, 8, 16, 1], [16, 8, 1, 2, 4, 2]]
+LAYOUT_LEVELS = {"cs": 1, "cms": 1, "um": 4}
+
+
+def _layout_streams(e):
+    out = {}
+    for sw in range(len(LAYOUT_WIDTHS)):
+        r = np.random.default_rng(1000 * e + sw)
+        n = 300 + 200 * sw
+        out[sw] = SwitchStream(
+            r.integers(0, 5000, n).astype(np.uint32),
+            r.integers(1, 4, n).astype(np.int64),
+            ((e << LOG2_TE) + r.integers(0, 1 << LOG2_TE, n)).astype(np.int64),
+            single_hop=r.random(n) < 0.3)
+    return out
+
+
+def _layout_system(kind):
+    levels = LAYOUT_LEVELS[kind]
+    mems = {sw: 4 * w * levels for sw, w in enumerate(LAYOUT_WIDTHS)}
+    s = DiSketchSystem(mems, kind, rho_target=4.0, log2_te=LOG2_TE,
+                       backend="fleet", mitigation=kind == "cs",
+                       fleet_kwargs=FLEET_KW,
+                       **({"n_levels": levels} if kind == "um" else {}))
+    for w, ns in enumerate(LAYOUT_NS):
+        s.ns = dict(enumerate(ns))
+        s.run_window(2 * w, [_layout_streams(e) for e in (2 * w, 2 * w + 1)])
+    return s
+
+
+@pytest.mark.parametrize("kind", ["cs", "cms", "um"])
+def test_layout_cells_match_loop(kind):
+    """Every (epoch, fragment) cell of the group-extent window buffer is
+    bit-identical to the per-switch loop's record at the same n."""
+    from repro.core.fragment import process_epoch
+
+    s = _layout_system(kind)
+    for w, ns in enumerate(LAYOUT_NS):
+        for e in (2 * w, 2 * w + 1):
+            streams = _layout_streams(e)
+            for sw, n in enumerate(ns):
+                st = streams[sw]
+                rec = process_epoch(s.fragments[sw], e, n, st.keys,
+                                    st.values, st.ts, e << LOG2_TE, LOG2_TE,
+                                    single_hop=st.single_hop)
+                want = rec.counters if kind == "um" else rec.counters[None]
+                got = s.fleet.cell_counters(e, sw)
+                assert got.shape == want.shape
+                np.testing.assert_array_equal(got, want)
+                np.testing.assert_array_equal(s.records[e][sw].counters,
+                                              rec.counters)
+
+
+@pytest.mark.parametrize("kind", ["cs", "um"])
+def test_layout_resident_bytes_are_group_extents(kind):
+    """A window's buffer holds each n_sub group's (rows, n, width)
+    rectangle and nothing more: strictly below E·F·n_max·w_max, and the
+    runner's figures are the sums over its resident windows."""
+    s = _layout_system(kind)
+    levels = LAYOUT_LEVELS[kind]
+    widths = np.array([s.fragments[sw].width
+                       for sw in range(len(LAYOUT_WIDTHS))])
+    stack = counters = 0
+    for w, ns in enumerate(LAYOUT_NS):
+        ns = np.array(ns)
+        buf = s.fleet._window_bufs[2 * w][0]
+        want = 4 * 2 * levels * sum(int((ns == n).sum()) * n
+                                    * widths[ns == n].max()
+                                    for n in np.unique(ns))
+        assert buf.stack_bytes == want == 4 * buf.device().size
+        assert want < 4 * 2 * len(ns) * levels * ns.max() * widths.max()
+        assert buf.counter_bytes == 4 * 2 * levels * int((ns * widths).sum())
+        stack += want
+        counters += buf.counter_bytes
+    assert (s.fleet.stack_bytes, s.fleet.counter_bytes) == (stack, counters)
+    s.records[0][0]                     # the first window leaves the device
+    assert s.fleet.stack_bytes == stack - s.fleet._window_bufs[0][0].stack_bytes

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.core.disketch import DiSketchSystem
+from repro.kernels.sketch_update.fleet import PARAM_N_SUB
 from repro.net.simulator import Replayer
 from repro.net.traffic import cov_list, linear_path_workload
 
@@ -21,9 +22,10 @@ PARENT = {
     "repro.system.run_window": None,
     "repro.fleet.run_window": "repro.system.run_window",
     "repro.fleet.prepare": "repro.fleet.run_window",
-    "repro.fleet.select": "repro.fleet.run_window",
-    "repro.fleet.pack_csr": "repro.fleet.run_window",
-    "repro.fleet.launch": "repro.fleet.run_window",
+    "repro.fleet.shard": "repro.fleet.run_window",
+    "repro.fleet.select": "repro.fleet.shard",
+    "repro.fleet.pack_csr": "repro.fleet.shard",
+    "repro.fleet.launch": "repro.fleet.shard",
     "repro.fleet.sync": "repro.fleet.run_window",
     "repro.fleet.records": "repro.fleet.run_window",
     "repro.query.flows": None,
@@ -90,8 +92,14 @@ def traced(tmp_path_factory):
         sum(len(rep.epoch_packet(e, order).keys)
             for e in range(w0, w0 + WINDOW))
         for w0 in range(0, N_EPOCHS, WINDOW)]
+    # each window's frozen n per fragment, and the fragments' widths
+    window_ns = [system.fleet._params_log[w0][:, PARAM_N_SUB]
+                 for w0 in range(0, N_EPOCHS, WINDOW)]
     return dict(spans=_spans(logdir), est=est, paths=len(set(paths)),
-                n_keys=len(keys), obs_per_window=obs_per_window)
+                n_keys=len(keys), obs_per_window=obs_per_window,
+                window_ns=window_ns, widths=np.array(widths),
+                fleet_bytes=(system.fleet.stack_bytes,
+                             system.fleet.counter_bytes))
 
 
 def _named(spans, name):
@@ -118,11 +126,33 @@ def test_window_spans(traced):
         {"epoch0": e0, "epochs": WINDOW} for e0 in range(0, N_EPOCHS, WINDOW)]
     fleet = _named(spans, "repro.fleet.run_window")
     assert len(fleet) == len(windows)
-    for f, want in zip(fleet, traced["obs_per_window"]):
-        assert f["stats"] == {"epochs": WINDOW, "rows": WINDOW * N_HOPS}
+    widths = traced["widths"]
+    totals = np.zeros(2, np.int64)
+    for f, want, ns in zip(fleet, traced["obs_per_window"],
+                           traced["window_ns"]):
+        # the buffer holds each n_sub group at its own (rows, n, width)
+        # extent: under the fleet-wide rectangle, over the counters
+        stack = 4 * WINDOW * sum(int((ns == n).sum()) * n
+                                 * widths[ns == n].max()
+                                 for n in np.unique(ns))
+        counters = 4 * WINDOW * int((ns * widths).sum())
+        assert counters <= stack <= 4 * WINDOW * N_HOPS * ns.max() \
+            * widths.max()
+        assert f["stats"] == {"epochs": WINDOW, "rows": WINDOW * N_HOPS,
+                              "stack_bytes": stack,
+                              "counter_bytes": counters}
+        totals += (stack, counters)
         inner = _inside(spans, f)
-        assert len(_named(inner, "repro.fleet.sync")) == 2
+        # one read a window: the peak and the PEBs together
+        assert len(_named(inner, "repro.fleet.sync")) == 1
         assert len(_named(inner, "repro.fleet.records")) == 1
+        shard, = _named(inner, "repro.fleet.shard")
+        assert shard["stats"]["shard"] == 0
+        assert shard["stats"]["rows"] == WINDOW * N_HOPS
+        assert shard["stats"]["packets"] == want
+        assert shard["stats"]["h2d_bytes"] == sum(
+            s["stats"]["h2d_bytes"]
+            for s in _named(inner, "repro.fleet.launch"))
         packs = _named(inner, "repro.fleet.pack_csr")
         assert packs
         for p in packs:
@@ -134,6 +164,8 @@ def test_window_spans(traced):
         launches = _named(inner, "repro.fleet.launch")
         assert len(launches) >= len(packs)
         assert all(s["stats"]["h2d_bytes"] > 0 for s in launches)
+    # the runner's figures: every window is still resident
+    assert tuple(totals) == traced["fleet_bytes"]
 
 
 def test_epoch_packet_spans(traced):
@@ -170,11 +202,11 @@ def test_query_spans(traced):
     assert [s["stats"]["keys"] for s in launches] == [n_keys] * stacks
     assert all(s["stats"]["paths"] == n_paths for s in launches)
     # resident stacks cross nothing: the first launch sends the padded
-    # path table, keys and key paths; each stack its (R, 3E + 4) rows
+    # path table, keys and key paths; each stack its (R, 4E + 4) rows
     kb = max(8, 1 << (n_keys - 1).bit_length())
     pb = max(8, 1 << (n_paths - 1).bit_length())
     n_slots = N_HOPS                      # the longest path: every hop
-    row_tab = 4 * N_HOPS * (3 * WINDOW + 4)
+    row_tab = 4 * N_HOPS * (4 * WINDOW + 4)
     assert [s["stats"]["h2d_bytes"] for s in launches] == (
         [pb * (4 * n_slots + 1) + row_tab + 2 * 4 * kb]
         + [row_tab] * (stacks - 1))

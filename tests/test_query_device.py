@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro.core import query as Q
-from repro.core.disketch import DiSketchSystem
+from repro.core.disketch import DiSketchSystem, SwitchStream
 from repro.kernels.sketch_query import (KEY_BUCKET_MIN, KEY_CHUNK,
                                         fleet_window_query_device,
                                         fleet_window_query_paths,
@@ -66,12 +66,11 @@ def test_device_matches_host_oracle(kind, path):
     assert buf._host is None and buf.resident
 
     # numpy oracle on the *same* counters (forces the transfer now)
-    host = buf.host()
     frag_sel = None
     if path is not None:
         frag_sel = np.array([sw in set(path)
                              for sw in sysw.fleet.frag_order])
-    ref = Q.fleet_query_window([host[e] for e in epochs],
+    ref = Q.fleet_query_window([buf.host_epoch(e) for e in epochs],
                                [sysw.fleet._params_log[e] for e in epochs],
                                sysw.fleet.widths, keys, kind,
                                frag_sel=frag_sel)
@@ -399,3 +398,96 @@ def test_churned_request_falls_back_per_path():
                                              single_hop=len(p) == 1,
                                              failures="oblivious")
         assert np.array_equal(a, b)
+
+
+# --- every query route over the group-extent window buffer ---------------
+
+LAYOUT_WIDTHS = [6000, 64, 1500, 300, 3000, 700]
+LAYOUT_NS = [[1, 2, 4, 8, 16, 1], [16, 8, 1, 2, 4, 2]]
+LAYOUT_PATHS = [(0, 1, 2, 3, 4, 5), (1,), (2, 3), (0, 2, 4), (5,),
+                (4, 1, 0), (1, 2, 3, 4)]
+
+
+def _layout_streams(e):
+    out = {}
+    for sw in range(len(LAYOUT_WIDTHS)):
+        r = np.random.default_rng(1000 * e + sw)
+        n = 300 + 200 * sw
+        out[sw] = SwitchStream(
+            r.integers(0, 5000, n).astype(np.uint32),
+            r.integers(1, 4, n).astype(np.int64),
+            ((e << LOG2_TE) + r.integers(0, 1 << LOG2_TE, n)).astype(np.int64),
+            single_hop=r.random(n) < 0.3)
+    return out
+
+
+def _layout_system(kind, churn=False):
+    """Skewed widths, n forced over {1, 2, 4, 8, 16} in each of two
+    2-epoch windows; ``churn``: switch 2 fails mid-window 0 (its epoch 0
+    cell is lost, parity-recoverable in group [0, 1, 2])."""
+    levels = 4 if kind == "um" else 1
+    mems = {sw: 4 * w * levels for sw, w in enumerate(LAYOUT_WIDTHS)}
+    kw = {"parity_groups": [[0, 1, 2], [3, 4, 5]]} if churn else {}
+    s = DiSketchSystem(mems, kind, rho_target=4.0, log2_te=LOG2_TE,
+                       backend="fleet", mitigation=True,
+                       fleet_kwargs=dict(FLEET_KW, **kw),
+                       **({"n_levels": levels} if kind == "um" else {}))
+    for w, ns in enumerate(LAYOUT_NS):
+        s.ns = dict(enumerate(ns))
+        ev = None
+        if churn and w == 0:
+            ev = [(), [SimpleNamespace(kind="fail", switch=2, factor=1.0)]]
+        s.run_window(2 * w, [_layout_streams(e) for e in (2 * w, 2 * w + 1)],
+                     events_by_epoch=ev)
+    return s
+
+
+@pytest.mark.parametrize("route", ["batched", "per_path", "um", "churned"])
+def test_layout_query_routes_match_host_oracle(route):
+    """Over windows whose groups span n = 1..16 at skewed widths, every
+    device query route equals its host oracle on the same counters bit
+    for bit: the batched request (against the per-record merge), the
+    per-path window query and the UnivMon levels (against the numpy
+    fleet oracle on the transferred buffers), and a churned request with
+    parity recovery (device-resident patch against host patch)."""
+    kind = {"um": "um", "per_path": "cs"}.get(route, "cs")
+    churn = route == "churned"
+    dev, host = _layout_system(kind, churn), _layout_system(kind, churn)
+    epochs = [0, 1, 2, 3]
+    for e in epochs:                     # the oracle's counters on host
+        host.records[e][0]
+    assert not host.fleet.has_device_window(epochs)
+    assert dev.fleet.has_device_window(epochs)
+    keys = (np.arange(300, dtype=np.uint32) * np.uint32(2654435761)) \
+        ^ np.uint32(0x5BD1E995)
+    paths = [LAYOUT_PATHS[i % len(LAYOUT_PATHS)] for i in range(len(keys))]
+    if churn:
+        assert dev.fleet.recoverable() == host.fleet.recoverable() \
+            == {0: [2]}
+    if route in ("batched", "churned"):
+        failures = "recover" if churn else "mask"
+        got = dev.query_flows(keys, paths, epochs, merge="fragment",
+                              failures=failures)
+        want = host.query_flows(keys, paths, epochs, merge="fragment",
+                                failures=failures)
+        if churn:
+            assert dev.fleet.recoverable() == host.fleet.recoverable() == {}
+            for sw in range(len(LAYOUT_WIDTHS)):
+                np.testing.assert_array_equal(
+                    dev.fleet.cell_counters(0, sw),
+                    host.fleet.cell_counters(0, sw))
+    elif route == "per_path":
+        got = np.concatenate([dev.fleet.window_query(
+            epochs, keys, path=p, single_hop=len(p) == 1)
+            for p in LAYOUT_PATHS])
+        want = np.concatenate([host.fleet.window_query(
+            epochs, keys, path=p, single_hop=len(p) == 1)
+            for p in LAYOUT_PATHS])
+    else:
+        got = np.stack([dev.fleet.um_level_window_query(epochs, keys, path=p)
+                        for p in LAYOUT_PATHS])
+        want = np.stack([host.fleet.um_level_window_query(epochs, keys,
+                                                          path=p)
+                         for p in LAYOUT_PATHS])
+    assert dev.fleet._window_bufs[0][0].resident
+    assert np.array_equal(got, want)

@@ -145,7 +145,11 @@ def test_single_fragment_and_dense_kernels_compile(one_chip, mode):
 
 
 def _query_args(sharding, rows, n_sub, width):
-    return (_sds((E, rows, n_sub, width), jnp.float32, sharding),
+    """A window buffer of ``rows`` rows at (n_sub, width) each, its cell
+    offsets and strides, then the per-row parameters."""
+    return (_sds((E * rows * n_sub * width,), jnp.float32, sharding),
+            _sds((E, rows), jnp.int32, sharding),
+            _sds((rows,), jnp.int32, sharding),
             _sds((E, rows), jnp.uint32, sharding),
             _sds((E, rows), jnp.uint32, sharding),
             _sds((E, rows), jnp.uint32, sharding),
@@ -176,8 +180,8 @@ def test_query_rows_merge_compiles(one_chip, kind, mitigate):
     rows = F * 16 if kind == "um" else F
     width = UM_WIDTH if kind == "um" else WIDE_WIDTH
     compiled = _gather_merge_rows.lower(
-        _sds((E, rows, 2, width), jnp.float32, one_chip),
-        _sds((rows, 3 * E + 4), jnp.uint32, one_chip),
+        _sds((E * rows * 2 * width,), jnp.float32, one_chip),
+        _sds((rows, 4 * E + 4), jnp.uint32, one_chip),
         _sds((256, 5), jnp.int32, one_chip),
         _sds((256,), jnp.bool_, one_chip),
         _sds((KEY_CHUNK,), jnp.uint32, one_chip),
@@ -205,13 +209,13 @@ def test_sharded_merge_compiles_on_4_chips(switch_mesh):
 
     n_rows = 245
     r_pad = shard_padded_rows(n_rows, 4)
-    stack = NamedSharding(switch_mesh, P(None, "switch", None, None))
     row = NamedSharding(switch_mesh, P(None, "switch"))
     per_row = NamedSharding(switch_mesh, P("switch"))
     rep = NamedSharding(switch_mesh, P())
     fn = _sharded_gather_merge(switch_mesh, "cs", False, n_rows)
     compiled = fn.lower(
-        _sds((E, r_pad, 16, 4096), jnp.float32, stack),
+        _sds((E * r_pad * 16 * 4096,), jnp.float32, per_row),
+        _sds((E, r_pad), jnp.int32, row), _sds((r_pad,), jnp.int32, per_row),
         _sds((E, r_pad), jnp.uint32, row), _sds((E, r_pad), jnp.uint32, row),
         _sds((E, r_pad), jnp.uint32, row),
         _sds((r_pad,), jnp.int32, per_row), _sds((r_pad,), jnp.int32, per_row),
@@ -220,3 +224,60 @@ def test_sharded_merge_compiles_on_4_chips(switch_mesh):
     text = compiled.as_text()
     assert "all-gather" in text
     assert np.prod(compiled.input_shardings[0][0].mesh.devices.shape) == 4
+
+
+#: A FatTree(16) window at the 128 KiB point (320 switches, widths to
+#: 183,544): window 1's n_sub groups on one shard of four, as
+#: (fragments, n, group width), and the whole window's buffer on one chip.
+FT16_SHARD_GROUPS = [(55, 1, 183544), (11, 2, 96000), (9, 4, 61000),
+                     (4, 8, 31000), (1, 16, 7311)]
+FT16_WINDOW_COUNTERS = 595_865_248
+
+
+def test_flat_gather_compiles_at_ft16_window(one_chip):
+    """The batched gather/merge over one flat FatTree(16) window buffer
+    of ~596M counters: int32 offsets, no (E, F, n_max, w_max) operand."""
+    from repro.kernels.sketch_query.engine import (KEY_CHUNK,
+                                                   _gather_merge_rows)
+
+    compiled = _gather_merge_rows.lower(
+        _sds((FT16_WINDOW_COUNTERS,), jnp.float32, one_chip),
+        _sds((320, 4 * E + 4), jnp.uint32, one_chip),
+        _sds((4096, 5), jnp.int32, one_chip),
+        _sds((4096,), jnp.bool_, one_chip),
+        _sds((KEY_CHUNK,), jnp.uint32, one_chip),
+        _sds((KEY_CHUNK,), jnp.int32, one_chip),
+        kind="cs", mitigate=True).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_shard_grouped_launch_compiles_on_its_chip(topo):
+    """A shard's grouped launches and the program that joins them into
+    its flat buffer (with the peak and PEBs), placed on the fourth chip
+    of a v5e:2x2: the per-shard update leg of a 4-chip mesh."""
+    from repro.core.fleet import _join_groups
+
+    chip = SingleDeviceSharding(topo.devices[3])
+    outs, widths = [], []
+    for frags, n_sub, width in FT16_SHARD_GROUPS:
+        rows = E * frags
+        _, w_blk = select_geometry(width, n_sub, "count")
+        w_blk = min(w_blk, pow2_width_cap(width))
+        tiles = 512 * CSR_BLK // LANE
+        launch = FK._fleet_update_ragged_jit.lower(
+            _sds((tiles, LANE), jnp.uint32, chip),
+            _sds((tiles, LANE), jnp.float32, chip),
+            _sds((tiles, LANE), jnp.uint32, chip),
+            _sds((rows, FK.N_PARAMS), jnp.int32, chip),
+            _sds((512,), jnp.int32, chip), n_sub_max=n_sub,
+            width_max=width, padded_width=width + (-width) % w_blk,
+            log2_te=16, signed=True, blk=CSR_BLK, w_blk=w_blk,
+            value_mode="count", n_levels=1, with_mitigation=True,
+            interpret=False).compile()
+        assert "tpu_custom_call" in launch.as_text()
+        outs.append(_sds((rows, n_sub, width), jnp.float32, chip))
+        widths.append(_sds((rows,), jnp.int32, chip))
+    size = sum(E * f * n * w for f, n, w in FT16_SHARD_GROUPS) + 4096
+    join = _join_groups.lower(tuple(outs), tuple(widths), kind="cs",
+                              n_levels=1, size=size).compile()
+    assert join.output_shardings[0].device_set == {topo.devices[3]}

@@ -260,11 +260,10 @@ def test_um_device_level_query_matches_host_oracle(path):
     buf = sysw.fleet._window_bufs[0][0]
     assert buf._host is None and buf.resident   # no bulk transfer
 
-    host = buf.host()                           # force it for the oracle
-    ref = np.zeros_like(got)
+    ref = np.zeros_like(got)                    # the oracle forces it
     for level in range(N_LEVELS):
         ref[level] = Q.fleet_query_window(
-            [host[e] for e in epochs],
+            [buf.host_epoch(e) for e in epochs],
             [sysw.fleet._params_log[e] for e in epochs],
             sysw.fleet.row_widths, keys, "um",
             frag_sel=sysw.fleet._row_sel(path, level))

@@ -228,9 +228,11 @@ def _check_sharded(findings: List[Finding]) -> None:
     """Sharded-fleet contracts (docs/sharding.md), device-free.
 
     The device-mesh runner dispatches each shard through the ordinary
-    ragged fleet wrapper over the shard's own rows, with the *global*
-    ``(n_sub_max, width_max)`` geometry — so the single-device vmem /
-    pow2 contracts must keep holding at every shard row count, and the
+    ragged fleet wrapper over the shard's own rows, one launch per
+    subepoch group at that group's own ``(n, width)``, never beyond the
+    *global* ``(n_sub_max, width_max)`` checked here — so the
+    single-device vmem / pow2 contracts must keep holding at every
+    shard row count, and the
     row padding must stay shard-divisible and level-aligned (a level
     block split across shards would break the all_gather row order the
     bit-identity argument rests on).  All checks run via eval_shape /
@@ -263,9 +265,9 @@ def _check_sharded(findings: List[Finding]) -> None:
                     f"{n_levels}) = {r_pad} is not a level-aligned "
                     "multiple of the shard count covering every row"))
                 continue
-            # Per-shard dispatch geometry: global (n_sub_max,
-            # width_max) at the shard's row count must still be
-            # MXU-aligned and inside the vmem budget.
+            # Per-shard dispatch geometry, at most the global
+            # (n_sub_max, width_max) at the shard's row count, must
+            # still be MXU-aligned and inside the vmem budget.
             blk, w_blk = select_geometry(width_max, n_sub_max, "f32")
             w_blk = min(w_blk, pow2_width_cap(width_max))
             if blk % 128 or w_blk % LANE or (w_blk & (w_blk - 1)):
